@@ -77,6 +77,7 @@ from repro.core import SparsifyConfig                          # noqa: E402
 from repro.models import get_model                             # noqa: E402
 from repro.models.sparse_serving import (densify_params,       # noqa: E402
                                          sparsify_for_serving)
+from repro.runtime.compile_cache import use_compile_cache     # noqa: E402
 from repro.runtime.metrics import format_summary, summarize    # noqa: E402
 from repro.serving import (QueueFull, ServingEngine,           # noqa: E402
                            SpeculativeConfig, TraceRequest,
@@ -799,6 +800,7 @@ def summary_block(sections: dict) -> dict:
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-paper")
     ap.add_argument("--smoke", action="store_true",

@@ -24,46 +24,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .nm_spmm import _decompress_tile
-from .outlier_spmm import OUTLIER_M, _decompress_outlier_tile
+from .nm_spmm import _decompress_tile_t, k_tile, resolve_interpret
+from .outlier_spmm import OUTLIER_M, _decompress_outlier_tile_t
 
 
-def _kernel(x_ref, v_ref, meta_ref, ov_ref, ometa_ref, o_ref, acc_ref,
-            *, n, m, o_n, n_k):
+def _kernel(x_ref, v_ref, meta_ref, ov_ref, ometa_ref, *refs, n, m, o_n, n_k):
+    """One (b, out, k) grid step.  An int8 variant passes a [1, bO] per-
+    out-row scale that dequantizes the N:M tile in-register; outliers stay
+    exact bf16 and are added AFTER the scale — only the N:M stream is
+    quantized (models/sparse_serving.py keeps outliers exact)."""
+    if len(refs) == 3:
+        s_ref, o_ref, acc_ref = refs
+    else:
+        s_ref, (o_ref, acc_ref) = None, refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _decompress_tile(v_ref[...], meta_ref[...], n, m, jnp.float32)
-    w += _decompress_outlier_tile(ov_ref[...], ometa_ref[...], o_n, jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(k == n_k - 1)
-    def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _kernel_int8(x_ref, v_ref, meta_ref, ov_ref, ometa_ref, s_ref, o_ref,
-                 acc_ref, *, n, m, o_n, n_k):
-    """int8 N:M values dequantized in-register by the per-out-row scale;
-    outliers stay exact bf16 and are added AFTER the scale — only the N:M
-    stream is quantized (models/sparse_serving.py keeps outliers exact)."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    w = _decompress_tile(v_ref[...], meta_ref[...], n, m, jnp.float32)
-    w = w * s_ref[...]                                 # [bO, bK] * [bO, 1]
-    w += _decompress_outlier_tile(ov_ref[...], ometa_ref[...], o_n, jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    w = _decompress_tile_t(v_ref[...], meta_ref[...], n, m)   # [bK, bO]
+    if s_ref is not None:
+        w = w * s_ref[...]
+    w += _decompress_outlier_tile_t(ov_ref, ometa_ref, o_n)
+    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -77,18 +62,20 @@ def fused_sparse_linear(x: jax.Array, nm_values: jax.Array, nm_meta: jax.Array,
                         n: int, m: int, o_n: int,
                         scale: jax.Array | None = None,
                         block_b: int = 128, block_o: int = 128,
-                        block_k: int = 512, interpret: bool = True) -> jax.Array:
+                        block_k: int | None = None,
+                        interpret: bool | None = None) -> jax.Array:
     """x: [b, in]; nm_values: [out, in*n//m]; nm_meta: [out, in//m] int32;
     o_values: [out, in//256, o_n]; o_meta: [out, in//256, o_n//4] int32.
     ``scale`` [out] f32 dequantizes int8 nm_values in-register (outliers are
-    stored exact and added unscaled); None for bf16 values."""
+    stored exact and added unscaled); None for bf16 values.  Tiles and
+    ``interpret`` default as in ``nm_spmm``."""
     b, kdim = x.shape
     out = nm_values.shape[0]
     assert kdim % OUTLIER_M == 0 and kdim % m == 0
 
     bb = min(block_b, b)
     bo = min(block_o, out)
-    bk = min(max(block_k, OUTLIER_M), kdim)
+    bk = max(k_tile(kdim, block_k), OUTLIER_M)
     assert b % bb == 0 and out % bo == 0 and kdim % bk == 0 and bk % OUTLIER_M == 0
     n_k = kdim // bk
     nc = bk // OUTLIER_M
@@ -102,19 +89,16 @@ def fused_sparse_linear(x: jax.Array, nm_values: jax.Array, nm_meta: jax.Array,
         pl.BlockSpec((bo, nc, o_n // 4), lambda i, j, k: (j, k, 0)),
     ]
     operands = [x, nm_values, nm_meta, o_values, o_meta]
-    if scale is None:
-        kernel = functools.partial(_kernel, n=n, m=m, o_n=o_n, n_k=n_k)
-    else:
+    if scale is not None:
         assert scale.shape == (out,)
-        kernel = functools.partial(_kernel_int8, n=n, m=m, o_n=o_n, n_k=n_k)
-        in_specs.append(pl.BlockSpec((bo, 1), lambda i, j, k: (j, 0)))
-        operands.append(scale.astype(jnp.float32).reshape(out, 1))
+        in_specs.append(pl.BlockSpec((1, bo), lambda i, j, k: (0, j)))
+        operands.append(scale.astype(jnp.float32).reshape(1, out))
     return pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, n=n, m=m, o_n=o_n, n_k=n_k),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bb, bo), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bb, bo), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

@@ -2,8 +2,8 @@
 
 ``sparse_matmul(x, SparsifiedLinear)``-style APIs used by models/serving.
 Backend selection:
-  - "pallas"     : pl.pallas_call, interpret=True on CPU (validation),
-                   compiled on real TPU.
+  - "pallas"     : pl.pallas_call, compiled on a TPU backend and run in
+                   interpret mode elsewhere (decided when traced).
   - "reference"  : pure-jnp oracle (ref.py) — portable, used inside pjit'd
                    full-model graphs where the dry-run lowers to HLO (XLA
                    then fuses the decompression einsum itself).
@@ -28,11 +28,6 @@ from .outlier_spmm import outlier_spmm, pack_outlier_meta
 from .fused_sparse_linear import fused_sparse_linear
 
 _DEFAULT_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "reference")
-_ON_TPU = jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not _ON_TPU
 
 
 def nm_matmul(x: jax.Array, packed: PackedNM, backend: str | None = None,
@@ -41,7 +36,7 @@ def nm_matmul(x: jax.Array, packed: PackedNM, backend: str | None = None,
     backend = backend or _DEFAULT_BACKEND
     if backend == "pallas":
         return nm_spmm(x, packed.values, packed.packed_metadata(),
-                       n=packed.n, m=packed.m, interpret=_interpret(), **tiles)
+                       n=packed.n, m=packed.m, **tiles)
     return ref.nm_spmm_ref(x, packed.values, packed.indices, packed.m)
 
 
@@ -51,7 +46,7 @@ def outlier_matmul(x: jax.Array, outliers: StructuredOutliers,
     backend = backend or _DEFAULT_BACKEND
     if backend == "pallas":
         return outlier_spmm(x, outliers.values, pack_outlier_meta(outliers.indices),
-                            n=outliers.n, interpret=_interpret(), **tiles)
+                            n=outliers.n, **tiles)
     return ref.outlier_spmm_ref(x, outliers.values, outliers.indices, outliers.m)
 
 
@@ -68,8 +63,7 @@ def sparse_linear_apply(x: jax.Array, packed: PackedNM,
         y = fused_sparse_linear(
             x2, packed.values, packed.packed_metadata(),
             outliers.values, pack_outlier_meta(outliers.indices),
-            n=packed.n, m=packed.m, o_n=outliers.n,
-            interpret=_interpret(), **tiles)
+            n=packed.n, m=packed.m, o_n=outliers.n, **tiles)
     else:
         y = ref.fused_sparse_linear_ref(
             x2, packed.values, packed.indices, packed.m,

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .nm_spmm import k_tile, resolve_interpret
+
 OUTLIER_M = 256
 
 
@@ -41,15 +43,23 @@ def unpack_outlier_meta(meta: jax.Array, n: int) -> jax.Array:
     return idx.reshape(*meta.shape[:-1], n)
 
 
-def _decompress_outlier_tile(values, meta, n: int, out_dtype):
-    """values [bO, nc, n], meta [bO, nc, n//4] -> dense [bO, nc*256]."""
-    bo, nc, _ = values.shape
-    idx = unpack_outlier_meta(meta, n)                          # [bO, nc, n]
-    j = jax.lax.iota(jnp.int32, OUTLIER_M)
-    onehot = idx[:, :, :, None] == j[None, None, None, :]      # [bO, nc, n, 256]
-    dense = jnp.sum(jnp.where(onehot, values.astype(jnp.float32)[..., None], 0.0),
-                    axis=2)
-    return dense.reshape(bo, nc * OUTLIER_M).astype(out_dtype)
+def _decompress_outlier_tile_t(v_ref, meta_ref, n: int):
+    """values [bO, nc, n], meta [bO, nc, n//4] refs -> dense^T [nc*256, bO]
+    f32, built transposed like ``nm_spmm._decompress_tile_t``: one
+    256-row group per outlier block, each kept value broadcast down its
+    group's sublanes and selected where its 8-bit index matches the row."""
+    nc = v_ref.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (OUTLIER_M, 1), 0)
+    groups = []
+    for c in range(nc):
+        vals = v_ref[:, c, :].astype(jnp.float32).T             # [n, bO]
+        meta = meta_ref[:, c, :].T                              # [n//4, bO]
+        dense = jnp.zeros((OUTLIER_M, vals.shape[1]), jnp.float32)
+        for k in range(n):
+            idx = (meta[k // 4:k // 4 + 1, :] >> (8 * (k % 4))) & 0xFF
+            dense += jnp.where(idx == rows, vals[k:k + 1, :], 0.0)
+        groups.append(dense)
+    return jnp.concatenate(groups, axis=0)
 
 
 def _kernel(x_ref, v_ref, meta_ref, o_ref, acc_ref, *, n, n_k):
@@ -59,10 +69,9 @@ def _kernel(x_ref, v_ref, meta_ref, o_ref, acc_ref, *, n, n_k):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _decompress_outlier_tile(v_ref[...], meta_ref[...], n, jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    w = _decompress_outlier_tile_t(v_ref, meta_ref, n)           # [bK, bO]
+    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -73,7 +82,8 @@ def _kernel(x_ref, v_ref, meta_ref, o_ref, acc_ref, *, n, n_k):
                                              "block_k", "interpret"))
 def outlier_spmm(x: jax.Array, values: jax.Array, meta: jax.Array, *,
                  n: int, block_b: int = 128, block_o: int = 128,
-                 block_k: int = 512, interpret: bool = True) -> jax.Array:
+                 block_k: int | None = None,
+                 interpret: bool | None = None) -> jax.Array:
     """y[b, out] = x[b, in] @ decompress_outliers^T."""
     b, kdim = x.shape
     out, nb, npk = values.shape[0], values.shape[1], meta.shape[2]
@@ -81,7 +91,7 @@ def outlier_spmm(x: jax.Array, values: jax.Array, meta: jax.Array, *,
 
     bb = min(block_b, b)
     bo = min(block_o, out)
-    bk = min(max(block_k, OUTLIER_M), kdim)
+    bk = max(k_tile(kdim, block_k), OUTLIER_M)
     assert b % bb == 0 and out % bo == 0 and kdim % bk == 0 and bk % OUTLIER_M == 0
     n_k = kdim // bk
     nc = bk // OUTLIER_M
@@ -98,5 +108,5 @@ def outlier_spmm(x: jax.Array, values: jax.Array, meta: jax.Array, *,
         out_specs=pl.BlockSpec((bb, bo), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bb, bo), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, values, meta)

@@ -7,20 +7,26 @@ before any jax import to get 512 placeholder CPU devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: sharding propagates from the
+    placed inputs (GSPMD), which is what every caller here relies on.
+    ``make_mesh`` defaults to Explicit axes, under which ops such as the
+    embedding gather refuse operands of differing shardings."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    # NOTE: no axis_types kwarg — jax.sharding.AxisType doesn't exist on the
-    # pinned JAX, and Auto (what these meshes want) is the default where it
-    # does, so the bare call is correct on every supported version.
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke/bench runs (same axis names)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str | None) -> tuple[int, int] | None:
@@ -59,7 +65,7 @@ def make_serving_mesh(spec: str | None):
             f"mesh {d}x{m} needs {d * m} devices but only {n_dev} are "
             f"visible (CPU: set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={d * m} before the first jax import)")
-    return jax.make_mesh((d, m), ("data", "model"))
+    return _auto_mesh((d, m), ("data", "model"))
 
 
 def make_replica_meshes(spec: str | None, n_replicas: int) -> list:
@@ -74,12 +80,14 @@ def make_replica_meshes(spec: str | None, n_replicas: int) -> list:
 
     if n_replicas < 1:
         raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
+    devices = jax.devices()
     dm = parse_mesh_spec(spec)
     if dm is None:
-        return [None] * n_replicas
+        if n_replicas == 1 or len(devices) < n_replicas:
+            return [None] * n_replicas
+        dm = (1, 1)
     d, m = dm
     per = d * m
-    devices = jax.devices()
     if per * n_replicas > len(devices):
         raise ValueError(
             f"{n_replicas} replicas of a {d}x{m} mesh need "
@@ -87,11 +95,5 @@ def make_replica_meshes(spec: str | None, n_replicas: int) -> list:
             f"visible (CPU: set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={per * n_replicas} before the first jax import)")
     return [Mesh(np.asarray(devices[i * per:(i + 1) * per]).reshape(d, m),
-                 ("data", "model"))
+                 ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             for i in range(n_replicas)]
-
-
-# TPU v5e hardware constants (roofline):
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link

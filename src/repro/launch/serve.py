@@ -2,8 +2,10 @@
 
 The sparse path is the paper's deployment story: linear weights are replaced
 by their 8:16 (+N:256 outlier) compressed form at load time
-(models/sparse_serving.py); on TPU the fused Pallas kernel streams compressed
-weights, on CPU the reference decompress path runs (same numerics).
+(models/sparse_serving.py).  Every backend serves them through
+``sparse_apply``, which rebuilds each dense weight from the compressed
+buffers by a one-hot einsum inside the step; the Pallas kernels in
+``kernels/`` are not on the served path yet.
 
 Modes:
   default      continuous-batching engine (serving/) for every zoo family —
@@ -51,7 +53,8 @@ prefix-cache hit potential, load, and session affinity
 baselines), with work-stealing rebalance between steps.  Each replica
 gets its own ``--slots``/``--n-blocks`` pool; with ``--mesh`` the spec
 is PER REPLICA and replicas take disjoint device slices
-(``make_replica_meshes``).  Token streams are identical to a single
+(``make_replica_meshes``); without it each replica gets a device of its
+own when there are at least N.  Token streams are identical to a single
 engine serving the same requests.
 
 ``--trace-out trace.json`` turns on the observability substrate
@@ -60,9 +63,9 @@ request lifecycle, engine step, jitted call and preemption (load the file
 in ui.perfetto.dev), plus a Prometheus counter snapshot written next to
 it.  Without the flag the engine runs with the no-op NULL_TRACER.
 
-Example (CPU):
-  PYTHONPATH=src python -m repro.launch.serve --arch llama-paper-smoke \
-      --batch 4 --prompt-len 32 --gen 16 --sparse
+Example (CPU, the reduced smoke preset of the default arch):
+  PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
+      --smoke-arch --batch 4 --prompt-len 32 --gen 16 --sparse
 """
 from __future__ import annotations
 
@@ -75,13 +78,16 @@ import jax.numpy as jnp
 from ..configs import get, get_smoke
 from ..models import get_model, grow_caches
 from ..core import SparsifyConfig
+from ..runtime.compile_cache import use_compile_cache
 
 
 def build_params(cfg, args):
-    """Init the model (optionally deploying compressed sparse weights)."""
+    """Init the model (optionally deploying compressed sparse weights).
+    Returns (zoo, params, key, sparsify report or None)."""
     zoo = get_model(cfg)
     key = jax.random.PRNGKey(args.seed)
     params = zoo.init(key)
+    report = None
     if args.sparse:
         from ..models.sparse_serving import sparsify_for_serving
         scfg = SparsifyConfig(weight_pattern=args.weight_pattern,
@@ -92,7 +98,7 @@ def build_params(cfg, args):
               f"bytes {report['dense_bytes']/2**20:.1f}MiB -> "
               f"{report['compressed_bytes']/2**20:.1f}MiB "
               f"({report['ratio']:.3f}x)")
-    return zoo, params, key
+    return zoo, params, key, report
 
 
 def run_oneshot(cfg, zoo, params, key, args):
@@ -309,9 +315,10 @@ def run_trace(cfg, params, args):
     return res
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI; ``chip_smoke.py`` builds its runs from it too."""
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama-paper-smoke")
+    ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--smoke-arch", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -385,6 +392,12 @@ def main(argv=None):
                     help="write a Chrome/Perfetto trace_event JSON of the "
                          "run here (load in ui.perfetto.dev); a Prometheus "
                          "counter snapshot lands next to it")
+    return ap
+
+
+def main(argv=None):
+    use_compile_cache()
+    ap = build_parser()
     args = ap.parse_args(argv)
 
     from ..serving import SUPPORTED_FAMILIES
@@ -403,7 +416,7 @@ def main(argv=None):
         ap.error("--trace carries token prompts only; the enc-dec family "
                  "needs per-request encoder features")
 
-    zoo, params, key = build_params(cfg, args)
+    zoo, params, key, _ = build_params(cfg, args)
 
     if args.trace is not None:
         return run_trace(cfg, params, args)

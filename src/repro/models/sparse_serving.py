@@ -8,9 +8,11 @@ reads compressed bytes from HBM:
   8:16 + 16:256 outliers, bf16:   1.30 B/elem  vs dense 2 B/elem (1.54x)
 
 ``layers.linear`` dispatches on this type, so every model in the zoo serves
-sparse without code changes.  On TPU the fused Pallas kernel consumes the
-packed buffers directly; the portable path unpacks metadata with bit ops and
-decompresses via one-hot matmul (XLA fuses it; numerics identical — tested).
+sparse without code changes.  The served path is ``sparse_apply`` on every
+backend, the TPU included: it unpacks the metadata with bit ops and rebuilds
+the dense weight by a one-hot einsum inside the step.  The fused Pallas
+kernel (``sparse_apply_pallas``) computes the same product from the packed
+buffers and is tested against it, but nothing serves through it yet.
 """
 from __future__ import annotations
 
@@ -105,12 +107,11 @@ def sparse_apply_pallas(sw: SparseWeight, x: jax.Array) -> jax.Array:
     x2 = x.reshape(-1, sw.in_dim)
     if sw.o_values is None:
         y = nm_spmm(x2, sw.nm_values, sw.nm_meta, n=sw.n, m=sw.m,
-                    scale=scale, interpret=jax.default_backend() != "tpu")
+                    scale=scale)
     else:
         y = fused_sparse_linear(x2, sw.nm_values, sw.nm_meta, sw.o_values,
                                 sw.o_meta, n=sw.n, m=sw.m, o_n=sw.o_n,
-                                scale=scale,
-                                interpret=jax.default_backend() != "tpu")
+                                scale=scale)
     return y.reshape(*lead, -1)
 
 
@@ -219,8 +220,12 @@ def sparsify_for_serving(params, scfg: SparsifyConfig, stats_by_name=None,
         st = (stats_by_name or {}).get(name)
         conv = partial(_to_sparse_weight, scfg=leaf_cfg, quantize=quantize)
         if leaf.ndim == 3:
-            sw = jax.vmap(lambda w: conv(w, stats=None))(leaf) if st is None \
-                else jax.vmap(conv)(leaf, st)
+            # one layer at a time, so the scoring and packing temporaries
+            # held beside the dense weights are one layer's, not those of
+            # the whole [L, out, in] stack
+            sw = jax.lax.map(lambda w: conv(w, stats=None), leaf) \
+                if st is None else jax.lax.map(
+                    lambda a: conv(a[0], stats=a[1]), (leaf, st))
         else:
             sw = conv(leaf, stats=st)
         n_sp += 1

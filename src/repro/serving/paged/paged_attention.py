@@ -20,11 +20,11 @@ Three implementations behind one entry point:
   gather, so this is also the portable CPU/GPU path.
 - ``pallas``: a TPU kernel (interpret-mode fallback off-TPU) that never
   materializes the gathered view.  The block table rides in as a
-  scalar-prefetch operand, the grid is ``(B, nb)`` with blocks innermost,
-  and each step DMAs exactly one physical KV block — the index map reads
-  ``block_tables[b, j]`` — accumulating flash-style (running max / sum /
-  weighted value in VMEM scratch) over ALL S queries of the row at once.
-  HBM traffic is therefore proportional to the tokens a request has
+  scalar-prefetch operand, the grid is ``(B, query tiles, nb)`` with
+  blocks innermost, and each step DMAs exactly one physical KV block —
+  the index map reads ``block_tables[b, j]`` — accumulating flash-style
+  (running max / sum / weighted value in VMEM scratch) over one query
+  tile of the row (``_q_tile``).  HBM traffic is therefore proportional to the tokens a request has
   actually written, not to a reserved ``max_len``.
 - ``pallas`` head-tiled: same kernel with an extra grid axis over KV-head
   tiles, for models whose full [S, H, hd] q/accumulator tiles would
@@ -47,12 +47,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...kernels.nm_spmm import resolve_interpret
+
 _NEG_INF = -1e30
 
 # head-tiled kernel kicks in at this many q-head * head-dim lanes; chosen
 # so a [S, H, hd] f32 accumulator tile stays well under VMEM at serving
 # chunk sizes (S <= a few hundred)
 _HEAD_TILE_THRESHOLD = 4096
+
+# query x q-head rows of one grid step's q/out/accumulator tiles: 4096
+# rows of hd=128 keep them, double-buffered, inside the 16 MiB of scoped
+# VMEM on a v5e
+_Q_ROWS = 4096
 
 
 def _default_backend() -> str:
@@ -119,8 +126,28 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, cursor,
 # pallas kernel
 # --------------------------------------------------------------------------
 
+def _q_tile(S: int, ht: int) -> int:
+    """Queries per grid tile for ``ht`` q heads per tile: the whole chunk
+    when it fits ``_Q_ROWS``, else the largest power of two within it that
+    divides S, so the [S, Ht, hd] q/out tiles and the f32 accumulator stay
+    inside scoped VMEM at any chunk size."""
+    cap = 1 << max(_Q_ROWS // ht, 1).bit_length() - 1
+    return S if S <= cap else math.gcd(S, cap)
+
+
+def _select_heads(x, first, count: int):
+    """``x[:, first:first + count]`` for a [bs, KV, ...] tile whose start
+    ``first`` is a traced grid index: a masked sum per head, because Mosaic
+    only slices packed (bf16/int8) sublanes at multiples of the tiling."""
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 2)
+    g = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(g == first + i, x, 0.0), axis=1, keepdims=True)
+         for i in range(count)], axis=1)
+
+
 def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
-                       bs, nb, n_rep, window, head_tiled, quantized):
+                       bs, nb, n_rep, window, head_tile, quantized):
     if quantized:
         # int8 arenas: per-position scale tiles ride the same block-table
         # index map as the KV tiles and dequantize in-register, inside the
@@ -128,11 +155,11 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
-        ks_ref = vs_ref = None
-    if head_tiled:
-        b, j = pl.program_id(0), pl.program_id(2)
+    b = pl.program_id(0)
+    if head_tile is not None:
+        h, qi, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     else:
-        b, j = pl.program_id(0), pl.program_id(1)
+        qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -142,17 +169,22 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
 
     S, hd = q_ref.shape[1], q_ref.shape[-1]
     qf = q_ref[0].astype(jnp.float32) / math.sqrt(hd)         # [S, Ht, hd]
-    k = k_ref[0].astype(jnp.float32)                          # [bs, KVt, hd]
+    k = k_ref[0].astype(jnp.float32)                          # [bs, KV, hd]
     v = v_ref[0].astype(jnp.float32)
     if quantized:
-        k = k * ks_ref[0][..., None]                          # [bs, KVt, 1]
+        k = k * ks_ref[0][..., None]                          # [bs, KV, 1]
         v = v * vs_ref[0][..., None]
+    if head_tile is not None:
+        # the DMA'd block holds every KV head (a narrower block is not
+        # tile-aligned); keep this grid step's head_tile of them
+        k = _select_heads(k, h * head_tile, head_tile)        # [bs, KVt, hd]
+        v = _select_heads(v, h * head_tile, head_tile)
     if n_rep > 1:
         k = jnp.repeat(k, n_rep, axis=1)                      # [bs, Ht, hd]
         v = jnp.repeat(v, n_rep, axis=1)
     s = jnp.einsum("qhd,thd->hqt", qf, k)                     # [Ht, S, bs]
 
-    qpos = cur_ref[b] + jax.lax.iota(jnp.int32, S)            # [S]
+    qpos = cur_ref[b] + qi * S + jax.lax.iota(jnp.int32, S)   # [S]
     idx = j * bs + jax.lax.iota(jnp.int32, bs)                # [bs]
     valid = idx[None, :] <= qpos[:, None]                     # [S, bs]
     if window is not None:
@@ -179,16 +211,20 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
                    static_argnames=("window", "interpret", "head_tile"))
 def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
                            *, window: int | None = None,
-                           interpret: bool = True,
+                           interpret: bool | None = None,
                            head_tile: int | None = None,
                            k_scale=None, v_scale=None) -> jax.Array:
     """Same contract as ``paged_attention_ref``; one grid step per
-    (row[, head tile], block), KV blocks DMA'd by table lookup via scalar
-    prefetch.  ``head_tile`` = KV heads per grid tile (None: all heads in
-    one tile) — the large-H*hd variant walks head tiles as a middle grid
-    axis so q/accumulator tiles stay VMEM-sized.  ``k_scale``/``v_scale``
-    [n_blocks, bs, KV] ride as two extra operands whose index map is the
-    same block-table lookup; the kernel dequantizes in-register."""
+    (row[, head tile], query tile, block), KV blocks DMA'd by table lookup
+    via scalar prefetch.  ``head_tile`` = KV heads per grid tile (None:
+    all heads in one tile) — the large-H*hd variant walks head tiles as a
+    grid axis so q/accumulator tiles stay VMEM-sized; every step still
+    DMAs whole blocks of all KV heads and keeps its tile's heads in
+    VMEM.  Long chunks walk query tiles (``_q_tile``).
+    ``k_scale``/``v_scale`` [n_blocks, bs, KV] ride as two extra operands
+    whose index map is the same block-table lookup; the kernel
+    dequantizes in-register.  ``interpret=None`` compiles on a TPU backend
+    and interprets elsewhere."""
     B, S, H, hd = q.shape
     n_blocks, bs, KV, _ = k_arena.shape
     nb = block_tables.shape[1]
@@ -198,30 +234,27 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
     if head_tile is not None and (KV % head_tile or head_tile >= KV):
         raise ValueError(f"head_tile {head_tile} must divide and be "
                          f"smaller than KV={KV}")
-    kvt = head_tile if head_tile is not None else KV
-    ht = kvt * n_rep
+    ht = (head_tile or KV) * n_rep
+    st = _q_tile(S, ht)
     kern = functools.partial(_paged_attn_kernel, bs=bs, nb=nb, n_rep=n_rep,
-                             window=window,
-                             head_tiled=head_tile is not None,
+                             window=window, head_tile=head_tile,
                              quantized=quantized)
     if head_tile is None:
-        grid = (B, nb)
-        q_spec = pl.BlockSpec((1, S, H, hd), lambda b, j, bt, cu: (b, 0, 0, 0))
+        grid = (B, S // st, nb)
+        q_spec = pl.BlockSpec((1, st, H, hd),
+                              lambda b, i, j, bt, cu: (b, i, 0, 0))
         kv_spec = pl.BlockSpec((1, bs, KV, hd),
-                               lambda b, j, bt, cu: (bt[b, j], 0, 0, 0))
+                               lambda b, i, j, bt, cu: (bt[b, j], 0, 0, 0))
         sc_spec = pl.BlockSpec((1, bs, KV),
-                               lambda b, j, bt, cu: (bt[b, j], 0, 0))
-        o_spec = pl.BlockSpec((1, S, H, hd), lambda b, j, bt, cu: (b, 0, 0, 0))
+                               lambda b, i, j, bt, cu: (bt[b, j], 0, 0))
     else:
-        grid = (B, KV // kvt, nb)
-        q_spec = pl.BlockSpec((1, S, ht, hd),
-                              lambda b, h, j, bt, cu: (b, 0, h, 0))
-        kv_spec = pl.BlockSpec((1, bs, kvt, hd),
-                               lambda b, h, j, bt, cu: (bt[b, j], 0, h, 0))
-        sc_spec = pl.BlockSpec((1, bs, kvt),
-                               lambda b, h, j, bt, cu: (bt[b, j], 0, h))
-        o_spec = pl.BlockSpec((1, S, ht, hd),
-                              lambda b, h, j, bt, cu: (b, 0, h, 0))
+        grid = (B, KV // head_tile, S // st, nb)
+        q_spec = pl.BlockSpec((1, st, ht, hd),
+                              lambda b, h, i, j, bt, cu: (b, i, h, 0))
+        kv_spec = pl.BlockSpec((1, bs, KV, hd),
+                               lambda b, h, i, j, bt, cu: (bt[b, j], 0, 0, 0))
+        sc_spec = pl.BlockSpec((1, bs, KV),
+                               lambda b, h, i, j, bt, cu: (bt[b, j], 0, 0))
 
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_arena, v_arena]
@@ -232,18 +265,18 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
         num_scalar_prefetch=2,                  # block tables, cursors
         grid=grid,
         in_specs=in_specs,
-        out_specs=o_spec,
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((ht, S), jnp.float32),     # running max
-            pltpu.VMEM((ht, S), jnp.float32),     # running sum
-            pltpu.VMEM((ht, S, hd), jnp.float32),  # weighted-value acc
+            pltpu.VMEM((ht, st), jnp.float32),     # running max
+            pltpu.VMEM((ht, st), jnp.float32),     # running sum
+            pltpu.VMEM((ht, st, hd), jnp.float32),  # weighted-value acc
         ],
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32), cursor.astype(jnp.int32),
       *operands)
     return out
@@ -263,7 +296,6 @@ def paged_attention(q, k_arena, v_arena, block_tables, cursor, *,
         KV = k_arena.shape[2]
         return paged_attention_pallas(
             q, k_arena, v_arena, block_tables, cursor, window=window,
-            interpret=jax.default_backend() != "tpu",
             head_tile=_head_tile(H, KV, hd),
             k_scale=k_scale, v_scale=v_scale)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, cursor,

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro import configs
 from repro.core import SparsifyConfig
@@ -292,6 +293,7 @@ def test_mesh_long_prompt_unified_identical(kv_layout):
         assert all(r.status is Status.FINISHED for r in reqs)
         return [r.tokens for r in reqs]
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ref = run(None, 4 * T)                  # single-device, one-shot
     assert run(mesh, 16) == ref             # sharded, chunked

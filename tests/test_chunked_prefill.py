@@ -20,6 +20,7 @@ import dataclasses
 import random
 
 import jax
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -335,6 +336,7 @@ def test_mesh_chunked_token_identical(which, kv_layout):
         assert all(r.status is Status.FINISHED for r in reqs)
         return [r.tokens for r in reqs]
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ref = run(None, 4 * 40)                 # single-device, one-shot
     assert run(mesh, 8) == ref              # sharded, 3 chunks per prompt

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -313,7 +313,8 @@ needs8 = pytest.mark.skipif(
 def mesh():
     if len(jax.devices()) < 8:
         return None
-    return jax.make_mesh((1, 8), ("data", "model"))
+    return jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 @needs8

@@ -256,7 +256,9 @@ def test_replica_set_validates_shapes(dense_params):
 # --------------------------------------------------------------------------
 
 def test_make_replica_meshes_default_and_bounds():
-    assert make_replica_meshes(None, 3) == [None, None, None]
+    n = len(jax.devices())
+    assert make_replica_meshes(None, n + 1) == [None] * (n + 1)
+    assert make_replica_meshes(None, 1) == [None]
     with pytest.raises(ValueError, match="n_replicas"):
         make_replica_meshes(None, 0)
     with pytest.raises(ValueError, match="devices"):
@@ -266,10 +268,11 @@ def test_make_replica_meshes_default_and_bounds():
 @pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="needs >= 2 devices for disjoint slices")
 def test_make_replica_meshes_disjoint_slices():
-    meshes = make_replica_meshes("1x1", 2)
-    d0 = set(meshes[0].devices.flat)
-    d1 = set(meshes[1].devices.flat)
-    assert d0 and d1 and not (d0 & d1)
+    for spec in ("1x1", None):
+        meshes = make_replica_meshes(spec, 2)
+        d0 = set(meshes[0].devices.flat)
+        d1 = set(meshes[1].devices.flat)
+        assert d0 and d1 and not (d0 & d1)
 
 
 # --------------------------------------------------------------------------

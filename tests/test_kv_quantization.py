@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro import configs
 from repro.core import SparsifyConfig
@@ -345,5 +346,6 @@ def test_mesh_int8_token_identical(kv_layout):
         assert all(r.status is Status.FINISHED for r in reqs)
         return [r.tokens for r in reqs]
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     assert run(mesh) == run(None)

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
 from repro import configs
 from repro.core import SparsifyConfig
@@ -46,7 +46,8 @@ needs8 = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 8), ("data", "model"))
+    return jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 @pytest.fixture(scope="module")
@@ -182,7 +183,8 @@ def test_param_shardings_sparse_alignment_on_mesh(dense_params, sparse_params):
     """In-dim (fsdp) sharding of compressed leaves only on block-aligned
     boundaries — checked at the NamedSharding level on a 2x4 mesh."""
     from repro.parallel.sharding import sparse_weight_shardings
-    mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh24 = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     sw = next(l for l in jax.tree.leaves(
         sparse_params, is_leaf=lambda x: isinstance(x, SparseWeight))
         if isinstance(l, SparseWeight))
@@ -213,10 +215,12 @@ def test_inactive_placement_is_identity():
 
 
 def test_placement_validates_mesh_and_cfg():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with pytest.raises(ValueError, match="cfg"):
         ServingPlacement(mesh)                    # mesh without cfg
-    bad = jax.make_mesh((1,), ("replica",))
+    bad = jax.make_mesh((1,), ("replica",),
+                        axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="model"):
         ServingPlacement(bad, CFG)
 
@@ -225,9 +229,41 @@ def test_placement_validates_mesh_and_cfg():
 def test_placement_rejects_data_parallel_mesh(dense_params):
     """Only model-axis TP is placed today; a data>1 mesh would run fully
     redundant replicas and skew per-device throughput accounting."""
-    mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh24 = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with pytest.raises(ValueError, match="size 1"):
         ServingEngine(CFG, dense_params, n_slots=2, max_len=32, mesh=mesh24)
+
+
+@pytest.mark.parametrize("kv_layout", ["slot", "paged"])
+def test_serving_mesh_1x1_token_identical(kv_layout, dense_params):
+    """``make_serving_mesh("1x1")`` on one device: the placement path
+    (explicit shardings on every jitted step over an Auto-axis mesh)
+    serves the meshless engine's tokens.  The 8-device parity tests skip
+    on one device; this one runs everywhere."""
+    from repro.launch.mesh import make_serving_mesh
+    mesh = make_serving_mesh("1x1")
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+    prompts = _prompts(3, 12)
+    kw = dict(n_slots=4, max_len=32, kv_layout=kv_layout, block_size=BS)
+    _, ref = _run(dense_params, prompts, GEN, **kw)
+    engine, out = _run(dense_params, prompts, GEN, mesh=mesh, **kw)
+    assert engine.placement.active
+    assert engine.stats()["placement"]["devices"] == 1
+    assert out == ref
+
+
+def test_serve_cli_mesh_1x1(monkeypatch, tmp_path):
+    """``--mesh 1x1`` serves on one CPU device, token-identical to the
+    CLI without a mesh."""
+    from repro.launch.serve import main
+    # a set variable keeps main() from pointing this process's compile
+    # cache at the repository
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    argv = ["--arch", "internlm2-1.8b", "--smoke-arch", "--batch", "2",
+            "--prompt-len", "12", "--gen", "3"]
+    np.testing.assert_array_equal(np.asarray(main(argv + ["--mesh", "1x1"])),
+                                  np.asarray(main(argv)))
 
 
 def test_engine_without_mesh_unchanged(dense_params):

@@ -271,6 +271,32 @@ def test_paged_attention_pallas_head_tiled_matches_ref(S):
                                head_tile=3)
 
 
+@pytest.mark.parametrize("window", [None, 10])
+@pytest.mark.parametrize("head_tile", [None, 2])
+def test_paged_attention_pallas_query_tiled_matches_ref(head_tile, window,
+                                                        monkeypatch):
+    """Chunks longer than one query tile walk query tiles, each at its own
+    position offset; forced to 2-query tiles here by shrinking the row
+    budget (the chip's budget splits 512-token chunks)."""
+    import importlib
+    pa = importlib.import_module("repro.serving.paged.paged_attention")
+    monkeypatch.setattr(pa, "_Q_ROWS", 8)
+    q, ka, va, bt, cursor = _attn_case(S=8, H=4, KV=4)
+    assert pa._q_tile(8, 4 if head_tile is None else 2) < 8
+    ref = paged_attention_ref(q, ka, va, bt, cursor, window=window)
+    pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
+                                 interpret=True, head_tile=head_tile)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_query_tile_sizes():
+    from repro.serving.paged.paged_attention import _q_tile
+    assert _q_tile(1, 32) == 1 and _q_tile(64, 16) == 64
+    assert _q_tile(512, 16) == 256 and _q_tile(512, 32) == 128
+    assert _q_tile(384, 16) == 128 and _q_tile(4096, 8) == 512
+
+
 def test_head_tile_autoselect_threshold(monkeypatch):
     """Dispatch picks the head-tiled kernel above the H*hd threshold and
     honors the REPRO_PAGED_HEAD_TILE override."""

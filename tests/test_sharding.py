@@ -94,7 +94,7 @@ class TestSparseWeightSpecs:
         # 512 % (4*16) == 0 but 512 % (4*256) != 0: fine without outliers,
         # rejected with them (a shard edge would cut a 256-wide group)
         no_outliers = sparse_weight_specs(mesh, _sw(4, 512))
-        assert no_outliers.nm_values[-1] == ("data",)
+        assert no_outliers.nm_values[-1] == "data"
         with_outliers = sparse_weight_specs(mesh, _sw(4, 512, o_n=16))
         assert with_outliers.nm_values[-1] is None
         assert with_outliers.o_values == P("model", None, None)

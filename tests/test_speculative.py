@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro import configs
 from repro.core import SparsifyConfig
@@ -193,7 +194,8 @@ def test_mesh_parity():
     engine — draft params co-resident under the same placement."""
     params = get_model(MESH_CFG).init(jax.random.PRNGKey(0))
     other = get_model(MESH_CFG).init(jax.random.PRNGKey(7))
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     prompts = _prompts(3, 16)
     draft = SpeculativeConfig(k=3, method="model", params=other,
                               cfg=MESH_CFG)
