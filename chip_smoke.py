@@ -9,7 +9,9 @@ then ``submit``/``run``), with random weights made from ``--seed``:
      a plain forward of the same prompt (``zoo.prefill``);
   B  8:16 + 16:256 compressed weights (``sparsify_for_serving``), paged KV
      through the compiled Pallas paged-attention kernel, against the same
-     compressed params served with slot KV (XLA attention);
+     compressed params served with slot KV (XLA attention), at the chunk
+     step and at every decode step on identical inputs; a control plants a
+     one-block attention fault, which must fail the same bound;
   C  the ``nm_spmm`` and ``fused_sparse_linear`` kernels, compiled, at the
      ``w_up`` shape, bf16 and int8 values, against ``sparse_apply``.
 
@@ -17,7 +19,8 @@ then ``submit``/``run``), with random weights made from ``--seed``:
 
   D  the model under ``--mesh 1x4`` (8 KV heads over 4 chips) against the
      single-device engine, and ``--replicas 4`` (one chip per replica
-     behind the prefix router) against one engine.
+     behind the prefix router) against one engine, both at the chunk step
+     and at every decode step on identical inputs.
 
 Each phase prints one line with its numbers.  Any failure exits non-zero
 before the last line, which is one JSON object naming the device JAX ran
@@ -29,7 +32,9 @@ on.  One process, no children: the chip belongs to this process.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
+import importlib
 import json
 import sys
 import time
@@ -52,12 +57,15 @@ from repro.runtime.compile_cache import use_compile_cache      # noqa: E402
 from repro.serving import SamplingParams, Status               # noqa: E402
 
 # Logit tolerance of phases A, B and D: max |got - want| <= LOGIT_TOL *
-# max |want| over every prompt's vocabulary row.  Both sides are bf16 end to
+# max |want| over every compared vocabulary row.  Both sides are bf16 end to
 # end (weights, activations, the logits themselves) and the two programs
 # reduce in different orders (blocked online softmax against one softmax,
-# different fusions), so each of the 24 layers adds rounding of up to 2^-8
-# of the residual stream.  A wrong mask, position or cache row moves the
-# logits by their own size, twenty times this bound.
+# other fusions, other batch sizes), which no ulp count bounds over 24
+# layers, so the bound is set from measurement: on a v5e every comparison
+# of this script lands at 0.013-0.017, and the bound leaves three times
+# that.  It is not loose enough to hide a fault: phase B's control plants a
+# one-block attention fault and fails unless it exceeds the bound (at
+# internlm2 widths with 2 layers on the CPU it measures 0.26).
 LOGIT_TOL = 0.05
 # Kernel tolerance of phase C, on the same measure: both sides read the
 # same stored values and round the output to bf16 once (2^-8); the int8
@@ -116,10 +124,12 @@ def _prompts(p: Preset, seed: int) -> np.ndarray:
         key, (p.n_requests, p.prompt_len), 0, p.cfg.vocab, jnp.int32))
 
 
-def _record_last_logits(engine, out: dict) -> None:
+def _record_logits(engine, chunk: dict, decode: dict) -> None:
     """Wrap the engine's compiled chunk step (the program
-    ``serving/families.py`` builds) so that ``out[request_id]`` holds the
-    f32 logits at the last position of the chunk that ends each prompt."""
+    ``serving/families.py`` builds) so that ``chunk[request_id]`` holds the
+    f32 logits at the last position of the chunk that ends each prompt, and
+    its decode step so that ``decode[request_id]`` lists the request's f32
+    logits of each decode step, on the host."""
     adapter = engine.adapter
     step = adapter.step_chunk
 
@@ -128,10 +138,66 @@ def _record_last_logits(engine, out: dict) -> None:
         n = len(rows)
         last = logits[jnp.arange(n), n_new[:n] - 1].astype(jnp.float32)
         for i, slot in enumerate(rows):
-            out[engine.running[slot].request_id] = last[i]
+            chunk[engine.running[slot].request_id] = last[i]
+        return logits
+
+    step_decode = adapter.step_decode
+
+    def recording_decode(tokens, active):
+        logits = step_decode(tokens, active)
+        rows = np.asarray(logits[jnp.asarray(active), 0], np.float32)
+        for row, slot in zip(rows, active):
+            decode.setdefault(engine.running[slot].request_id, []).append(row)
         return logits
 
     adapter.step_chunk = recording
+    adapter.step_decode = recording_decode
+
+
+@dataclasses.dataclass
+class Served:
+    """One engine's or fleet's greedy run of every prompt: per request its
+    tokens, the chunk-step logits at its prompt's last position ([n, V]),
+    and the logits of each decode step (per request [gen - 1, V])."""
+    tokens: list
+    chunk: np.ndarray
+    decode: list
+    wall: float
+
+
+def _serve_recorded(target, p: Preset, prompts) -> Served:
+    """``_serve`` with every logit row recorded; ``target`` is one engine
+    or a fleet, whose every replica is recorded."""
+    chunk: dict = {}
+    decode: dict = {}
+    for engine in getattr(target, "replicas", [target]):
+        _record_logits(engine, chunk, decode)
+    reqs, wall = _serve(target, p, prompts)
+    steps = [decode.get(r.request_id, []) for r in reqs]
+    if any(len(d) != p.gen - 1 for d in steps):
+        raise SmokeFailure(f"decode steps per request "
+                           f"{[len(d) for d in steps]}, expected {p.gen - 1}")
+    return Served([r.tokens for r in reqs], _by_request(reqs, chunk),
+                  [np.stack(d) for d in steps], wall)
+
+
+@contextlib.contextmanager
+def _attention_cursor_shifted(by: int):
+    """Plant a fault for the tolerance's control: while active, paged
+    attention traced into a new engine reads every row's cursor ``by``
+    positions early, so each query misses its ``by`` latest keys (its own
+    included).  KV writes are untouched."""
+    mod = importlib.import_module("repro.serving.paged.paged_attention")
+    real = mod.paged_attention
+
+    def shifted(q, k, v, block_tables, cursor, **kw):
+        return real(q, k, v, block_tables, cursor - by, **kw)
+
+    mod.paged_attention = shifted
+    try:
+        yield
+    finally:
+        mod.paged_attention = real
 
 
 def _serve(target, p: Preset, prompts) -> tuple[list, float]:
@@ -150,9 +216,9 @@ def _serve(target, p: Preset, prompts) -> tuple[list, float]:
     return reqs, wall
 
 
-def _compare(got, want, tol: float, what: str) -> dict:
+def _compare(got, want, tol: float, what: str, check: bool = True) -> dict:
     """Rows of ``got`` against ``want`` ([n, V] each) on the stated
-    measure; raises SmokeFailure beyond ``tol``."""
+    measure; raises SmokeFailure beyond ``tol`` when ``check``."""
     a = np.asarray(got, np.float32)
     b = np.asarray(want, np.float32)
     if a.shape != b.shape:
@@ -163,9 +229,59 @@ def _compare(got, want, tol: float, what: str) -> dict:
            "rel_err": err / max(scale, 1e-30), "tol": tol,
            "argmax_agree": int(np.sum(a.argmax(-1) == b.argmax(-1))),
            "rows": int(a.shape[0])}
-    if not (np.isfinite(a).all() and res["rel_err"] <= tol):
+    if check and not (np.isfinite(a).all() and res["rel_err"] <= tol):
         raise SmokeFailure(f"{what}: {res}")
     return res
+
+
+def _compare_served(got: Served, want: Served, tol: float, what: str,
+                    check: bool = True) -> dict:
+    """Every logit row the two runs computed from identical inputs: each
+    request's chunk step, and each decode step whose input tokens (all the
+    request emitted before it) agree.  Where greedy streams part, the top-2
+    gap of ``want``'s logits at the parting token, beside that row's
+    max |got - want|: argmax can only flip where the gap is within twice
+    the error.  With ``check``, at least one decode row must compare."""
+    rows_got, rows_want = [got.chunk], [want.chunk]
+    n_decode, parts = 0, []
+    for i, (tg, tw) in enumerate(zip(got.tokens, want.tokens)):
+        same = 0
+        while same < len(tg) and tg[same] == tw[same]:
+            same += 1
+        # decode step t consumes tokens[:t + 1] and emits token t + 1
+        k = min(same, len(tg) - 1)
+        rows_got.append(got.decode[i][:k])
+        rows_want.append(want.decode[i][:k])
+        n_decode += k
+        if same < len(tg):
+            w = want.chunk[i] if same == 0 else want.decode[i][same - 1]
+            g = got.chunk[i] if same == 0 else got.decode[i][same - 1]
+            top2 = np.sort(w)[-2:]
+            parts.append({"token": same, "gap": float(top2[1] - top2[0]),
+                          "max_abs_err": float(np.max(np.abs(g - w)))})
+    res = _compare(np.concatenate(rows_got), np.concatenate(rows_want), tol,
+                   what, check)
+    if check and n_decode == 0:
+        raise SmokeFailure(f"{what}: no decode step ran on identical inputs")
+    res.update(decode_rows=n_decode, parts=parts,
+               streams_identical=len(got.tokens) - len(parts))
+    return res
+
+
+def _fmt_served(c: dict) -> str:
+    """One ``_compare_served`` result: the rows, the bound, and where the
+    greedy streams part."""
+    n = c["streams_identical"] + len(c["parts"])
+    line = (f"over {c['rows'] - c['decode_rows']} chunk + {c['decode_rows']} "
+            f"decode rows: {_fmt(c)}; {c['streams_identical']}/{n} token "
+            f"streams identical")
+    if c["parts"]:
+        line += (", streams part at tokens "
+                 + str([q["token"] for q in c["parts"]]) + " where top-2 gap "
+                 + str([round(q["gap"], 4) for q in c["parts"]])
+                 + " vs max|d| "
+                 + str([round(q["max_abs_err"], 4) for q in c["parts"]]))
+    return line
 
 
 def _by_request(reqs, logits: dict) -> np.ndarray:
@@ -203,14 +319,13 @@ def phase_dense(p: Preset, seed: int = 0) -> dict:
     zoo, params, _, _ = serve.build_params(cfg, args)
     engine, _ = serve._build_target(cfg, params, args,
                                     max_len=p.prompt_len + p.gen)
-    logits: dict = {}
-    _record_last_logits(engine, logits)
     prompts = _prompts(p, seed)
-    reqs, wall = _serve(engine, p, prompts)
-    got = _by_request(reqs, logits)
+    got = _serve_recorded(engine, p, prompts)
+    wall = got.wall
     ref, _ = jax.jit(zoo.prefill)(params, {"tokens": jnp.asarray(prompts)})
-    cmp = _compare(got, ref, LOGIT_TOL, "A engine chunk vs zoo.prefill")
-    res = {"requests": len(reqs), "tokens": sum(len(r.tokens) for r in reqs),
+    cmp = _compare(got.chunk, ref, LOGIT_TOL, "A engine chunk vs zoo.prefill")
+    res = {"requests": len(got.tokens),
+           "tokens": sum(len(t) for t in got.tokens),
            "wall_s_with_compile": wall, "steps": engine.stats()["n_steps"],
            "logits": cmp, "peak_bytes_in_use": _peak_bytes()}
     print(f"phase A dense/slot: {res['requests']} requests x "
@@ -223,7 +338,9 @@ def phase_dense(p: Preset, seed: int = 0) -> dict:
 
 def phase_sparse(p: Preset, seed: int = 0, expect_kernel: bool = True) -> dict:
     """B: 8:16 + 16:256 compressed weights, paged KV through the Pallas
-    kernel, against the same params with slot KV."""
+    kernel, against the same params with slot KV, at the chunk step and at
+    every decode step; then the control: the same paged engine with a
+    planted one-block attention fault must fail the bound."""
     cfg = p.cfg
     paged_args = _serve_args(p, seed, "--sparse", "--kv-layout", "paged")
     slot_args = _serve_args(p, seed, "--sparse", "--kv-layout", "slot")
@@ -232,9 +349,7 @@ def phase_sparse(p: Preset, seed: int = 0, expect_kernel: bool = True) -> dict:
     max_len = p.prompt_len + p.gen
 
     paged, _ = serve._build_target(cfg, params, paged_args, max_len=max_len)
-    got: dict = {}
-    _record_last_logits(paged, got)
-    reqs, wall = _serve(paged, p, prompts)
+    got = _serve_recorded(paged, p, prompts)
     a = paged.adapter
     tokens = jnp.zeros((paged.pool.n_slots, 1), jnp.int32)
     kernel = _lowered_has_kernel(a._decode_fn.lower(
@@ -243,30 +358,42 @@ def phase_sparse(p: Preset, seed: int = 0, expect_kernel: bool = True) -> dict:
     if expect_kernel and not kernel:
         raise SmokeFailure("B: the paged decode step does not call the "
                            "compiled Pallas kernel")
-    paged_logits = _by_request(reqs, got)
-    paged_tokens = [r.tokens for r in reqs]
     del paged, a
 
     slot, _ = serve._build_target(cfg, params, slot_args, max_len=max_len)
-    want: dict = {}
-    _record_last_logits(slot, want)
-    reqs, _ = _serve(slot, p, prompts)
-    cmp = _compare(paged_logits, _by_request(reqs, want), LOGIT_TOL,
-                   "B paged kernel vs slot XLA")
-    same = sum(t == r.tokens for t, r in zip(paged_tokens, reqs))
+    want = _serve_recorded(slot, p, prompts)
+    del slot
+    cmp = _compare_served(got, want, LOGIT_TOL, "B paged kernel vs slot XLA")
+
+    with _attention_cursor_shifted(paged_args.block_size):
+        faulty, _ = serve._build_target(cfg, params, paged_args,
+                                        max_len=max_len)
+        bad = _serve_recorded(faulty, p, prompts)
+    del faulty
+    control = _compare_served(bad, want, LOGIT_TOL, "B control", check=False)
+    if control["rel_err"] <= LOGIT_TOL:
+        raise SmokeFailure(f"B control: attention one block early stays "
+                           f"within LOGIT_TOL: {control}")
+
     res = {"dense_bytes": report["dense_bytes"],
            "compressed_bytes": report["compressed_bytes"],
            "ratio": report["ratio"],
            "matrices": report["n_layers_sparsified"],
-           "wall_s_with_compile": wall, "kernel_compiled": kernel,
-           "logits": cmp, "token_streams_identical": same,
+           "wall_s_with_compile": got.wall, "kernel_compiled": kernel,
+           "logits": cmp, "control": control,
+           "token_streams_identical": cmp["streams_identical"],
            "peak_bytes_in_use": _peak_bytes()}
     print(f"phase B 8:16+16:256/paged: {res['matrices']} matrices "
           f"{res['dense_bytes']} -> {res['compressed_bytes']} B "
           f"({res['ratio']:.4f}); paged kernel compiled={kernel}; "
-          f"{wall:.1f}s wall incl. compile; paged vs slot logits: "
-          f"{_fmt(cmp)}; {same}/{len(reqs)} token streams identical; "
-          f"peak {res['peak_bytes_in_use']} B", flush=True)
+          f"{got.wall:.1f}s wall incl. compile; paged vs slot logits "
+          f"{_fmt_served(cmp)}; peak {res['peak_bytes_in_use']} B",
+          flush=True)
+    print(f"phase B control, paged attention one block "
+          f"({paged_args.block_size} positions) early: rel "
+          f"{control['rel_err']:.4g} > {LOGIT_TOL} (max|d|="
+          f"{control['max_abs_err']:.4g} over {control['rows']} rows), "
+          f"caught", flush=True)
     return res
 
 
@@ -322,7 +449,8 @@ def phase_kernels(p: Preset, seed: int = 0,
 
 def phase_four_chip(p: Preset, seed: int = 0) -> dict:
     """D: the 1x4 mesh and four one-chip replicas, each against one
-    single-device engine in this process."""
+    single-device engine in this process, at the chunk step and at every
+    decode step on identical inputs."""
     cfg = p.cfg
     prompts = _prompts(p, seed)
     max_len = p.prompt_len + p.gen
@@ -330,49 +458,36 @@ def phase_four_chip(p: Preset, seed: int = 0) -> dict:
     _, params, _, _ = serve.build_params(cfg, args)
 
     single, _ = serve._build_target(cfg, params, args, max_len=max_len)
-    want: dict = {}
-    _record_last_logits(single, want)
-    reqs, _ = _serve(single, p, prompts)
-    ref = _by_request(reqs, want)
-    ref_tokens = [r.tokens for r in reqs]
+    want = _serve_recorded(single, p, prompts)
     del single
 
     mesh_args = _serve_args(p, seed, "--mesh", "1x4")
     meshed, _ = serve._build_target(cfg, params, mesh_args, max_len=max_len)
-    got: dict = {}
-    _record_last_logits(meshed, got)
-    reqs, mesh_wall = _serve(meshed, p, prompts)
+    got = _serve_recorded(meshed, p, prompts)
     k_sharding = meshed.pool.k.sharding
-    mesh_cmp = _compare(_by_request(reqs, got), ref, LOGIT_TOL,
-                        "D mesh 1x4 vs single device")
-    mesh_same = sum(t == r.tokens for t, r in zip(ref_tokens, reqs))
+    mesh_cmp = _compare_served(got, want, LOGIT_TOL,
+                               "D mesh 1x4 vs single device")
     mesh_devices = sorted(d.id for d in k_sharding.device_set)
     del meshed
     print(f"phase D mesh 1x4: KV arena {k_sharding.spec} over devices "
-          f"{mesh_devices}, {mesh_wall:.1f}s wall incl. compile; logits vs "
-          f"single device: {_fmt(mesh_cmp)}; {mesh_same}/{len(reqs)} token "
-          f"streams identical", flush=True)
+          f"{mesh_devices}, {got.wall:.1f}s wall incl. compile; logits vs "
+          f"single device {_fmt_served(mesh_cmp)}", flush=True)
 
     fleet_args = _serve_args(p, seed, "--replicas", "4")
     fleet, _ = serve._build_target(cfg, params, fleet_args, max_len=max_len)
-    got = {}
-    for engine in fleet.replicas:
-        _record_last_logits(engine, got)
-    reqs, fleet_wall = _serve(fleet, p, prompts)
+    got = _serve_recorded(fleet, p, prompts)
     placed = [sorted({d.id for leaf in jax.tree.leaves(e.params)
                       for d in leaf.devices()}) for e in fleet.replicas]
     if len({tuple(d) for d in placed}) != len(placed) \
             or any(len(d) != 1 for d in placed):
         raise SmokeFailure(f"D: replicas are not on distinct single "
                            f"devices: {placed}")
-    fleet_cmp = _compare(_by_request(reqs, got), ref, LOGIT_TOL,
-                         "D 4 replicas vs single engine")
-    fleet_same = sum(t == r.tokens for t, r in zip(ref_tokens, reqs))
+    fleet_cmp = _compare_served(got, want, LOGIT_TOL,
+                                "D 4 replicas vs single engine")
     served = [e.stats()["n_finished"] for e in fleet.replicas]
     print(f"phase D 4 replicas: params on devices {placed}, requests per "
-          f"replica {served}, {fleet_wall:.1f}s wall incl. compile; logits "
-          f"vs one engine: {_fmt(fleet_cmp)}; {fleet_same}/{len(reqs)} "
-          f"token streams identical", flush=True)
+          f"replica {served}, {got.wall:.1f}s wall incl. compile; logits "
+          f"vs one engine {_fmt_served(fleet_cmp)}", flush=True)
     return {"mesh": mesh_cmp, "mesh_devices": mesh_devices,
             "replica_devices": placed, "replicas": fleet_cmp,
             "requests_per_replica": served}

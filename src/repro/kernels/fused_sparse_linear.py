@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .nm_spmm import _decompress_tile_t, k_tile, resolve_interpret
+from . import resolve_interpret
+from .nm_spmm import _decompress_tile_t, k_tile
 from .outlier_spmm import OUTLIER_M, _decompress_outlier_tile_t
 
 

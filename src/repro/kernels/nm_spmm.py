@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 
 def _decompress_tile_t(values, meta, n: int, m: int):
     """values [bO, nc*n], meta [bO, nc] int32 -> dense^T [nc*m, bO] f32.
@@ -49,15 +51,6 @@ def _decompress_tile_t(values, meta, n: int, m: int):
         idx = ((meta_t >> (4 * k)) & 0xF)[:, None, :]          # [nc, 1, bO]
         dense += jnp.where(idx == j, vals[:, k:k + 1, :], 0.0)
     return dense.reshape(nc * m, bo)
-
-
-def resolve_interpret(interpret: bool | None) -> bool:
-    """Kernels compile with Mosaic on a TPU backend and run in interpret
-    mode elsewhere, decided when the kernel is traced; an explicit bool
-    wins (the AOT tests compile for a described TPU from a CPU host)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def k_tile(kdim: int, block_k: int | None) -> int:

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .nm_spmm import k_tile, resolve_interpret
+from . import resolve_interpret
+from .nm_spmm import k_tile
 
 OUTLIER_M = 256
 

@@ -44,8 +44,8 @@ Two KV layouts behind one API (``kv_layout=``):
            through per-request block tables (``PagedPoolView``) and
            attended via the chunk-capable paged-attention kernel
            (serving/paged/paged_attention.py — jnp reference off-TPU,
-           Pallas online-softmax over block tables on TPU, head-tiled
-           automatically for large H*hd).  Block allocation is
+           Pallas online-softmax over block tables on TPU, walking
+           query tiles for long chunks).  Block allocation is
            chunk-aware — a half-prefilled prompt holds only the blocks
            its cursor has filled.  Identical prefixes share blocks
            read-only (prefix cache); decode or prefill pressure preempts

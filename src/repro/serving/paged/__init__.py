@@ -7,7 +7,7 @@ engine (``ServingEngine(..., kv_layout="paged")``).
   prefix_cache.py    hash-chained full-block prefix sharing (LRU evict)
   paged_attention.py in-place attention over block tables (decode AND
                      prefill chunks): jnp reference + Pallas
-                     scalar-prefetch kernel, head-tiled for large H*hd
+                     scalar-prefetch kernel over query tiles
                      (interpret off-TPU)
   pool.py            PagedKVPool — the cache-pool-protocol facade — and
                      PagedPoolView, what attend_over_pool sees of it

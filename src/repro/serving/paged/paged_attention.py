@@ -11,7 +11,7 @@ alone hides this step's not-yet-visible writes, stale tokens of previous
 block occupants, and trash-block padding.  S=1 with ``cursor = pos``
 reproduces the old decode contract (visible count ``pos + 1``).
 
-Three implementations behind one entry point:
+Two implementations behind one entry point:
 
 - ``reference``: gather the row's blocks into a contiguous
   ``[B, nb*block_size, KV, hd]`` view with ``arena[block_tables]`` and run
@@ -24,13 +24,11 @@ Three implementations behind one entry point:
   blocks innermost, and each step DMAs exactly one physical KV block —
   the index map reads ``block_tables[b, j]`` — accumulating flash-style
   (running max / sum / weighted value in VMEM scratch) over one query
-  tile of the row (``_q_tile``).  HBM traffic is therefore proportional to the tokens a request has
-  actually written, not to a reserved ``max_len``.
-- ``pallas`` head-tiled: same kernel with an extra grid axis over KV-head
-  tiles, for models whose full [S, H, hd] q/accumulator tiles would
-  pressure VMEM (large H*hd).  Selected automatically when
-  ``H * hd >= _HEAD_TILE_THRESHOLD`` (env ``REPRO_PAGED_HEAD_TILE``
-  forces a tile width; 0 disables).
+  tile of the row.  Query tiles (``_q_tile``) cap the q/out/accumulator
+  tiles at ``_Q_ROWS`` query-head rows, so every model width and chunk
+  size fits scoped VMEM with the same kernel.  HBM traffic is therefore
+  proportional to the tokens a request has actually written, not to a
+  reserved ``max_len``.
 
 All paths mask with a finite ``-1e30`` (exp underflows to exactly 0.0
 against any real row max), so masked positions contribute exactly nothing
@@ -47,14 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...kernels.nm_spmm import resolve_interpret
+from ...kernels import resolve_interpret
 
 _NEG_INF = -1e30
-
-# head-tiled kernel kicks in at this many q-head * head-dim lanes; chosen
-# so a [S, H, hd] f32 accumulator tile stays well under VMEM at serving
-# chunk sizes (S <= a few hundred)
-_HEAD_TILE_THRESHOLD = 4096
 
 # query x q-head rows of one grid step's q/out/accumulator tiles: 4096
 # rows of hd=128 keep them, double-buffered, inside the 16 MiB of scoped
@@ -69,26 +62,6 @@ def _default_backend() -> str:
     # interpret-mode Pallas is a Python loop over the grid — fine for
     # validation, far too slow to serve from on CPU
     return "pallas" if jax.default_backend() == "tpu" else "reference"
-
-
-def _head_tile(H: int, KV: int, hd: int) -> int | None:
-    """KV heads per kernel tile, or None for the untiled kernel.  The
-    ``REPRO_PAGED_HEAD_TILE`` override falls back to untiled (None) when
-    the requested tile cannot legally tile this model's KV heads, so one
-    fleet-wide knob never crashes a smaller model's serving path."""
-    env = os.environ.get("REPRO_PAGED_HEAD_TILE")
-    if env is not None:
-        t = int(env)
-        if t <= 0 or t >= KV or KV % t:
-            return None
-        return t
-    if H * hd < _HEAD_TILE_THRESHOLD:
-        return None
-    n_rep = H // KV
-    per_tile = max(_HEAD_TILE_THRESHOLD // (2 * n_rep * hd), 1)
-    while KV % per_tile:
-        per_tile -= 1
-    return per_tile if per_tile < KV else None
 
 
 # --------------------------------------------------------------------------
@@ -126,28 +99,17 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, cursor,
 # pallas kernel
 # --------------------------------------------------------------------------
 
-def _q_tile(S: int, ht: int) -> int:
-    """Queries per grid tile for ``ht`` q heads per tile: the whole chunk
-    when it fits ``_Q_ROWS``, else the largest power of two within it that
-    divides S, so the [S, Ht, hd] q/out tiles and the f32 accumulator stay
-    inside scoped VMEM at any chunk size."""
-    cap = 1 << max(_Q_ROWS // ht, 1).bit_length() - 1
+def _q_tile(S: int, H: int) -> int:
+    """Queries per grid tile for ``H`` q heads: the whole chunk when it
+    fits ``_Q_ROWS``, else the largest power of two within it that divides
+    S, so the [S, H, hd] q/out tiles and the f32 accumulator stay inside
+    scoped VMEM at any chunk size."""
+    cap = 1 << max(_Q_ROWS // H, 1).bit_length() - 1
     return S if S <= cap else math.gcd(S, cap)
 
 
-def _select_heads(x, first, count: int):
-    """``x[:, first:first + count]`` for a [bs, KV, ...] tile whose start
-    ``first`` is a traced grid index: a masked sum per head, because Mosaic
-    only slices packed (bf16/int8) sublanes at multiples of the tiling."""
-    shape = (1, x.shape[1]) + (1,) * (x.ndim - 2)
-    g = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return jnp.concatenate(
-        [jnp.sum(jnp.where(g == first + i, x, 0.0), axis=1, keepdims=True)
-         for i in range(count)], axis=1)
-
-
 def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
-                       bs, nb, n_rep, window, head_tile, quantized):
+                       bs, nb, n_rep, window, quantized):
     if quantized:
         # int8 arenas: per-position scale tiles ride the same block-table
         # index map as the KV tiles and dequantize in-register, inside the
@@ -155,11 +117,7 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(0)
-    if head_tile is not None:
-        h, qi, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    else:
-        qi, j = pl.program_id(1), pl.program_id(2)
+    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -168,21 +126,16 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     S, hd = q_ref.shape[1], q_ref.shape[-1]
-    qf = q_ref[0].astype(jnp.float32) / math.sqrt(hd)         # [S, Ht, hd]
+    qf = q_ref[0].astype(jnp.float32) / math.sqrt(hd)         # [S, H, hd]
     k = k_ref[0].astype(jnp.float32)                          # [bs, KV, hd]
     v = v_ref[0].astype(jnp.float32)
     if quantized:
         k = k * ks_ref[0][..., None]                          # [bs, KV, 1]
         v = v * vs_ref[0][..., None]
-    if head_tile is not None:
-        # the DMA'd block holds every KV head (a narrower block is not
-        # tile-aligned); keep this grid step's head_tile of them
-        k = _select_heads(k, h * head_tile, head_tile)        # [bs, KVt, hd]
-        v = _select_heads(v, h * head_tile, head_tile)
     if n_rep > 1:
-        k = jnp.repeat(k, n_rep, axis=1)                      # [bs, Ht, hd]
+        k = jnp.repeat(k, n_rep, axis=1)                      # [bs, H, hd]
         v = jnp.repeat(v, n_rep, axis=1)
-    s = jnp.einsum("qhd,thd->hqt", qf, k)                     # [Ht, S, bs]
+    s = jnp.einsum("qhd,thd->hqt", qf, k)                     # [H, S, bs]
 
     qpos = cur_ref[b] + qi * S + jax.lax.iota(jnp.int32, S)   # [S]
     idx = j * bs + jax.lax.iota(jnp.int32, bs)                # [bs]
@@ -191,10 +144,10 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
         valid &= idx[None, :] > qpos[:, None] - window
     s = jnp.where(valid[None], s, _NEG_INF)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]                   # [Ht, S]
+    m_prev, l_prev = m_ref[...], l_ref[...]                   # [H, S]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[..., None])                         # [Ht, S, bs]
+    p = jnp.exp(s - m_new[..., None])                         # [H, S, bs]
     m_ref[...] = m_new
     l_ref[...] = l_prev * alpha + jnp.sum(p, axis=2)
     acc_ref[...] = (acc_ref[...] * alpha[..., None]
@@ -202,25 +155,19 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(j == nb - 1)
     def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)                # [Ht, S]
-        out = acc_ref[...] / denom[..., None]                 # [Ht, S, hd]
+        denom = jnp.maximum(l_ref[...], 1e-30)                # [H, S]
+        out = acc_ref[...] / denom[..., None]                 # [H, S, hd]
         o_ref[...] = out.transpose(1, 0, 2)[None].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "interpret", "head_tile"))
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
                            *, window: int | None = None,
                            interpret: bool | None = None,
-                           head_tile: int | None = None,
                            k_scale=None, v_scale=None) -> jax.Array:
     """Same contract as ``paged_attention_ref``; one grid step per
-    (row[, head tile], query tile, block), KV blocks DMA'd by table lookup
-    via scalar prefetch.  ``head_tile`` = KV heads per grid tile (None:
-    all heads in one tile) — the large-H*hd variant walks head tiles as a
-    grid axis so q/accumulator tiles stay VMEM-sized; every step still
-    DMAs whole blocks of all KV heads and keeps its tile's heads in
-    VMEM.  Long chunks walk query tiles (``_q_tile``).
+    (row, query tile, block), KV blocks DMA'd by table lookup via scalar
+    prefetch.  Long chunks walk query tiles (``_q_tile``).
     ``k_scale``/``v_scale`` [n_blocks, bs, KV] ride as two extra operands
     whose index map is the same block-table lookup; the kernel
     dequantizes in-register.  ``interpret=None`` compiles on a TPU backend
@@ -228,33 +175,18 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
     B, S, H, hd = q.shape
     n_blocks, bs, KV, _ = k_arena.shape
     nb = block_tables.shape[1]
-    n_rep = H // KV
     quantized = k_scale is not None
 
-    if head_tile is not None and (KV % head_tile or head_tile >= KV):
-        raise ValueError(f"head_tile {head_tile} must divide and be "
-                         f"smaller than KV={KV}")
-    ht = (head_tile or KV) * n_rep
-    st = _q_tile(S, ht)
-    kern = functools.partial(_paged_attn_kernel, bs=bs, nb=nb, n_rep=n_rep,
-                             window=window, head_tile=head_tile,
+    st = _q_tile(S, H)
+    kern = functools.partial(_paged_attn_kernel, bs=bs, nb=nb,
+                             n_rep=H // KV, window=window,
                              quantized=quantized)
-    if head_tile is None:
-        grid = (B, S // st, nb)
-        q_spec = pl.BlockSpec((1, st, H, hd),
-                              lambda b, i, j, bt, cu: (b, i, 0, 0))
-        kv_spec = pl.BlockSpec((1, bs, KV, hd),
-                               lambda b, i, j, bt, cu: (bt[b, j], 0, 0, 0))
-        sc_spec = pl.BlockSpec((1, bs, KV),
-                               lambda b, i, j, bt, cu: (bt[b, j], 0, 0))
-    else:
-        grid = (B, KV // head_tile, S // st, nb)
-        q_spec = pl.BlockSpec((1, st, ht, hd),
-                              lambda b, h, i, j, bt, cu: (b, i, h, 0))
-        kv_spec = pl.BlockSpec((1, bs, KV, hd),
-                               lambda b, h, i, j, bt, cu: (bt[b, j], 0, 0, 0))
-        sc_spec = pl.BlockSpec((1, bs, KV),
-                               lambda b, h, i, j, bt, cu: (bt[b, j], 0, 0))
+    q_spec = pl.BlockSpec((1, st, H, hd),
+                          lambda b, i, j, bt, cu: (b, i, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, KV, hd),
+                           lambda b, i, j, bt, cu: (bt[b, j], 0, 0, 0))
+    sc_spec = pl.BlockSpec((1, bs, KV),
+                           lambda b, i, j, bt, cu: (bt[b, j], 0, 0))
 
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_arena, v_arena]
@@ -263,13 +195,13 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                  # block tables, cursors
-        grid=grid,
+        grid=(B, S // st, nb),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((ht, st), jnp.float32),     # running max
-            pltpu.VMEM((ht, st), jnp.float32),     # running sum
-            pltpu.VMEM((ht, st, hd), jnp.float32),  # weighted-value acc
+            pltpu.VMEM((H, st), jnp.float32),      # running max
+            pltpu.VMEM((H, st), jnp.float32),      # running sum
+            pltpu.VMEM((H, st, hd), jnp.float32),  # weighted-value acc
         ],
     )
     out = pl.pallas_call(
@@ -292,11 +224,8 @@ def paged_attention(q, k_arena, v_arena, block_tables, cursor, *,
                     k_scale=None, v_scale=None) -> jax.Array:
     backend = backend or _default_backend()
     if backend == "pallas":
-        H, hd = q.shape[2], q.shape[3]
-        KV = k_arena.shape[2]
         return paged_attention_pallas(
             q, k_arena, v_arena, block_tables, cursor, window=window,
-            head_tile=_head_tile(H, KV, hd),
             k_scale=k_scale, v_scale=v_scale)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, cursor,
                                window=window, k_scale=k_scale,

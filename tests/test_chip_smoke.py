@@ -49,6 +49,9 @@ def test_phase_sparse_runs_the_paged_kernel(cs, preset, monkeypatch):
     assert res["kernel_compiled"] is False        # interpreted on the CPU
     assert 0 < res["compressed_bytes"] < res["dense_bytes"]
     assert res["logits"]["rel_err"] <= cs.LOGIT_TOL
+    # decode steps are compared too, and the planted fault is caught
+    assert res["logits"]["decode_rows"] > 0
+    assert res["control"]["rel_err"] > cs.LOGIT_TOL
 
 
 def test_phase_kernels(cs, preset):
@@ -77,10 +80,13 @@ def test_phase_four_chip_on_virtual_devices():
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     code = ("import json, chip_smoke as cs; "
             "r = cs.phase_four_chip(cs.smoke_preset()); "
-            "print(json.dumps(r['replica_devices']))")
+            "print(json.dumps([r['mesh']['decode_rows'], "
+            "r['replicas']['decode_rows'], r['replica_devices']]))")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.strip().splitlines()
     assert any(line.startswith("phase D mesh 1x4") for line in lines)
-    assert json.loads(lines[-1]) == [[0], [1], [2], [3]]
+    mesh_decode, fleet_decode, devices = json.loads(lines[-1])
+    assert mesh_decode > 0 and fleet_decode > 0      # decode steps compared
+    assert devices == [[0], [1], [2], [3]]

@@ -254,38 +254,23 @@ def test_paged_attention_pallas_matches_ref(window, S):
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("S", [1, 4])
-def test_paged_attention_pallas_head_tiled_matches_ref(S):
-    """The large-H*hd variant (grid over KV-head tiles) is numerically
-    the untiled kernel; forced here via head_tile regardless of the
-    auto-select threshold."""
-    q, ka, va, bt, cursor = _attn_case(S=S, H=8, KV=4)
-    ref = paged_attention_ref(q, ka, va, bt, cursor)
-    for tile in (1, 2):
-        pal = paged_attention_pallas(q, ka, va, bt, cursor, interpret=True,
-                                     head_tile=tile)
-        np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-    with pytest.raises(ValueError, match="head_tile"):
-        paged_attention_pallas(q, ka, va, bt, cursor, interpret=True,
-                               head_tile=3)
-
-
 @pytest.mark.parametrize("window", [None, 10])
-@pytest.mark.parametrize("head_tile", [None, 2])
-def test_paged_attention_pallas_query_tiled_matches_ref(head_tile, window,
+@pytest.mark.parametrize("S,H,KV", [(8, 4, 4), (8, 8, 4), (4, 8, 4),
+                                    (8, 8, 2)])
+def test_paged_attention_pallas_query_tiled_matches_ref(S, H, KV, window,
                                                         monkeypatch):
     """Chunks longer than one query tile walk query tiles, each at its own
-    position offset; forced to 2-query tiles here by shrinking the row
-    budget (the chip's budget splits 512-token chunks)."""
+    position offset, for MHA and GQA head groupings; forced to small tiles
+    here by shrinking the row budget (the chip's budget splits 512-token
+    chunks)."""
     import importlib
     pa = importlib.import_module("repro.serving.paged.paged_attention")
     monkeypatch.setattr(pa, "_Q_ROWS", 8)
-    q, ka, va, bt, cursor = _attn_case(S=8, H=4, KV=4)
-    assert pa._q_tile(8, 4 if head_tile is None else 2) < 8
+    q, ka, va, bt, cursor = _attn_case(S=S, H=H, KV=KV)
+    assert pa._q_tile(S, H) < S
     ref = paged_attention_ref(q, ka, va, bt, cursor, window=window)
     pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
-                                 interpret=True, head_tile=head_tile)
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
@@ -297,24 +282,19 @@ def test_query_tile_sizes():
     assert _q_tile(384, 16) == 128 and _q_tile(4096, 8) == 512
 
 
-def test_head_tile_autoselect_threshold(monkeypatch):
-    """Dispatch picks the head-tiled kernel above the H*hd threshold and
-    honors the REPRO_PAGED_HEAD_TILE override."""
-    import importlib
-    pa = importlib.import_module("repro.serving.paged.paged_attention")
-    monkeypatch.delenv("REPRO_PAGED_HEAD_TILE", raising=False)
-    assert pa._head_tile(4, 2, 16) is None              # tiny: untiled
-    big = pa._head_tile(64, 8, 128)                     # 8192 lanes: tiled
-    assert big is not None and 8 % big == 0 and big < 8
-    monkeypatch.setenv("REPRO_PAGED_HEAD_TILE", "0")
-    assert pa._head_tile(64, 8, 128) is None            # forced off
-    monkeypatch.setenv("REPRO_PAGED_HEAD_TILE", "2")
-    assert pa._head_tile(8, 4, 16) == 2                 # forced on
-    # an override that cannot tile this model's KV heads falls back to
-    # the untiled kernel instead of crashing the serving path
-    assert pa._head_tile(4, 2, 16) is None              # t >= KV
-    monkeypatch.setenv("REPRO_PAGED_HEAD_TILE", "3")
-    assert pa._head_tile(8, 4, 16) is None              # KV % t != 0
+@pytest.mark.parametrize("S", [1, 5])
+def test_paged_attention_dispatch_matches_ref(S, monkeypatch):
+    """The ``pallas`` backend of the dispatch entry point (the kernel the
+    chip serves from, interpreted here) agrees with the reference, with
+    and without an explicit backend."""
+    from repro.serving.paged import paged_attention
+    q, ka, va, bt, cursor = _attn_case(S=S, H=8, KV=2)
+    ref = paged_attention(q, ka, va, bt, cursor, backend="reference")
+    monkeypatch.setenv("REPRO_PAGED_BACKEND", "pallas")
+    for backend in ("pallas", None):
+        pal = paged_attention(q, ka, va, bt, cursor, backend=backend)
+        np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
 
 
 # --------------------------------------------------------------------------

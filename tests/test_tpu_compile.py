@@ -3,9 +3,9 @@
 Interpret mode checks what a kernel computes; only the chip's compiler
 checks that its blocks are tile-aligned and that it fits scoped VMEM.  Each
 test lowers one kernel variant that the TPU dispatch can choose, at
-internlm2-1.8b widths (the head-tiled paged variant at qwen3-8b widths,
-the smallest zoo model that selects it), and compiles it for one chip of
-a described ``v5e:2x2``.  Nothing runs.
+internlm2-1.8b widths (the paged kernel also at qwen3-8b widths, whose
+32 q heads make the widest query tiles of the zoo's attention configs),
+and compiles it for one chip of a described ``v5e:2x2``.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -21,8 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs
 from repro.kernels.fused_sparse_linear import fused_sparse_linear
 from repro.kernels.nm_spmm import nm_spmm
-from repro.serving.paged.paged_attention import (_head_tile,
-                                                 paged_attention_pallas)
+from repro.serving.paged.paged_attention import paged_attention_pallas
 
 N, M, O_N = 8, 16, 16                       # 8:16 + 16:256 outliers
 BLOCK = 16                                  # paged KV block size
@@ -117,30 +116,16 @@ def _paged_shapes(cfg, S, quantized, batch=8, n_blocks=256, per_row=40):
     return shapes
 
 
-def _paged_fn(head_tile):
-    def fn(q, k, v, bt, cursor, *scales):
-        ks, vs = scales if scales else (None, None)
-        return paged_attention_pallas(q, k, v, bt, cursor, interpret=False,
-                                      head_tile=head_tile, k_scale=ks,
-                                      v_scale=vs)
-    return fn
+def _paged_fn(q, k, v, bt, cursor, *scales):
+    ks, vs = scales if scales else (None, None)
+    return paged_attention_pallas(q, k, v, bt, cursor, interpret=False,
+                                  k_scale=ks, v_scale=vs)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("S", [1, 512], ids=["decode", "chunk512"])
-def test_paged_attention_untiled_compiles(one_chip, S, quantized):
-    cfg = _INTERNLM2
-    assert _head_tile(cfg.n_heads, cfg.n_kv_heads, cfg.hd) is None
-    text = _compile(one_chip, _paged_fn(None),
-                    *_paged_shapes(cfg, S, quantized))
-    assert "tpu_custom_call" in text
-
-
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_paged_attention_head_tiled_compiles(one_chip, quantized):
-    cfg = configs.get("qwen3-8b")
-    tile = _head_tile(cfg.n_heads, cfg.n_kv_heads, cfg.hd)
-    assert tile is not None and tile < cfg.n_kv_heads
-    text = _compile(one_chip, _paged_fn(tile),
-                    *_paged_shapes(cfg, 512, quantized))
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "qwen3-8b"])
+def test_paged_attention_untiled_compiles(one_chip, arch, S, quantized):
+    text = _compile(one_chip, _paged_fn,
+                    *_paged_shapes(configs.get(arch), S, quantized))
     assert "tpu_custom_call" in text
