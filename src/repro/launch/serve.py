@@ -57,11 +57,16 @@ is PER REPLICA and replicas take disjoint device slices
 own when there are at least N.  Token streams are identical to a single
 engine serving the same requests.
 
-``--trace-out trace.json`` turns on the observability substrate
-(serving/observe.py): a Chrome/Perfetto ``trace_event`` JSON of every
-request lifecycle, engine step, jitted call and preemption (load the file
-in ui.perfetto.dev), plus a Prometheus counter snapshot written next to
-it.  Without the flag the engine runs with the no-op NULL_TRACER.
+``--trace-out trace.json`` turns on the engine's ServingTracer
+(serving/observe.py): a Chrome/Perfetto ``trace_event`` JSON on the
+engine's clock of every request lifecycle, engine phase (the ``serve.*``
+spans), compile and preemption (load the file in ui.perfetto.dev), plus a
+Prometheus counter snapshot written next to it.  Without the flag the
+engine runs with the no-op NULL_TRACER.  Either way the ``serve.*`` phase
+spans are also profiler annotations: a ``jax.profiler`` trace taken around
+the engine holds them on the device trace's clock, beside the step
+programs (``jit_serve_step``) and the sparse linear's device ops (scope
+``sparse_linear`` in their op-name metadata).
 
 Example (CPU, the reduced smoke preset of the default arch):
   PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \

@@ -28,9 +28,9 @@ Greedy speculative streams are token-identical to non-speculative ones.
 
 ``tracer=ServingTracer()`` turns on the observability substrate
 (serving/observe.py): Perfetto trace spans for every request lifecycle and
-engine step, a Prometheus-text counter registry, and per-jitted-variant
-step-time attribution.  The default NULL_TRACER is a no-op with zero
-per-step cost.
+engine phase, and a Prometheus-text counter registry.  The default
+NULL_TRACER is a no-op; the engine's ``serve.*`` phase spans are profiler
+annotations either way, recorded only while a ``jax.profiler`` trace runs.
 """
 
 from .cache_pool import (CachePoolError, CapacityError, DoubleFree,

@@ -113,7 +113,7 @@ import numpy as np
 
 from . import families
 from .cache_pool import CachePoolError
-from .observe import NULL_TRACER
+from .observe import NULL_TRACER, span
 from .paged import OutOfBlocks
 from .placement import ServingPlacement
 from .request import Request, SamplingParams, Status
@@ -217,14 +217,12 @@ class ServingEngine:
         self.finished: list[Request] = []
         self._clock = clock
         # observability: NULL_TRACER is a no-op singleton and every hot-path
-        # call site is guarded by ``tracer.enabled``, so the disabled engine
-        # does zero observability work per step (serving/observe.py)
+        # call site is guarded by ``tracer.enabled``; the phase spans are
+        # profiler annotations that record only while a profile is taken
+        # (serving/observe.py)
         self.tracer = NULL_TRACER if tracer is None else tracer
         if self.tracer.enabled:
             self.tracer.attach(self)
-            self.adapter.tracer = self.tracer
-            if self.spec is not None:
-                self.spec.set_tracer(self.tracer)
         self._next_id = 0
         self.n_steps = 0
         self.n_preemptions = 0
@@ -344,40 +342,44 @@ class ServingEngine:
         """One token-budgeted iteration: evict -> prefill chunks under the
         budget (in-flight cursors first, then admissions) -> fused decode
         of every prefill-complete request."""
-        now = self._clock()
         tr = self.tracer
-        if tr.enabled:
-            tr.begin_step(self.n_steps, now)
-        stats = {"evicted": 0, "admitted": 0, "finished": 0, "decoded": 0,
-                 "preempted": 0, "prefill_tokens": 0, "prefill_chunks": 0}
+        with span("serve.step", tr):
+            now = self._clock()
+            stats = {"evicted": 0, "admitted": 0, "finished": 0,
+                     "decoded": 0, "preempted": 0, "prefill_tokens": 0,
+                     "prefill_chunks": 0}
+            with span("serve.schedule", tr):
+                for req in self.queue.evict_expired(now):
+                    req._finish(Status.EVICTED, now)
+                    self.finished.append(req)
+                    stats["evicted"] += 1
+                    if tr.enabled:
+                        tr.on_evict(req)
 
-        for req in self.queue.evict_expired(now):
-            req._finish(Status.EVICTED, now)
-            self.finished.append(req)
-            stats["evicted"] += 1
+                # speculative decoding charges each decoding request's k+1
+                # verify tokens against the step's token budget before
+                # prefill planning (scheduler.spec_verify_reserve) — the
+                # fused verify runs through the same chunk pipeline prefill
+                # does, so the budget stays an honest bound on the step's
+                # total token work
+                budget = self.token_budget
+                if self.spec is not None:
+                    reserve = spec_verify_reserve(self.running,
+                                                  self.spec.cfg.k)
+                    budget = max(budget - reserve, 0)
+                    stats["spec_reserved"] = min(reserve, self.token_budget)
+                groups = self._plan_prefill(stats, now, budget)
+
+            self._run_chunks(groups, stats)
+
+            self.max_running = max(self.max_running, len(self.running))
+            if any(r.status is Status.RUNNING
+                   for r in self.running.values()):
+                stats["finished"] += self._decode_once(stats)
+
+            self.n_steps += 1
             if tr.enabled:
-                tr.on_evict(req)
-
-        # speculative decoding charges each decoding request's k+1 verify
-        # tokens against the step's token budget before prefill planning
-        # (scheduler.spec_verify_reserve) — the fused verify runs through
-        # the same chunk pipeline prefill does, so the budget stays an
-        # honest bound on the step's total token work
-        budget = self.token_budget
-        if self.spec is not None:
-            reserve = spec_verify_reserve(self.running, self.spec.cfg.k)
-            budget = max(budget - reserve, 0)
-            stats["spec_reserved"] = min(reserve, self.token_budget)
-
-        self._prefill_phase(stats, now, budget)
-
-        self.max_running = max(self.max_running, len(self.running))
-        if any(r.status is Status.RUNNING for r in self.running.values()):
-            stats["finished"] += self._decode_once(stats)
-
-        self.n_steps += 1
-        if tr.enabled:
-            tr.end_step(self, stats)
+                tr.end_step(self, stats)
         return stats
 
     def run(self, max_steps: int | None = None) -> list[Request]:
@@ -451,17 +453,13 @@ class ServingEngine:
         return seq[:-1] if req.tokens else seq
 
     # -------------------------------------------------------- prefill phase
-    def _prefill_phase(self, stats: dict, now: float,
-                       budget: int | None = None) -> None:
-        """Spend up to ``budget`` (default: the full token budget) prompt
-        tokens: advance in-flight prefill cursors first (admission order),
-        then admit new requests from the queue head, FIFO, with
-        layout-aware placement."""
-        if budget is None:
-            budget = self.token_budget
+    def _plan_prefill(self, stats: dict, now: float,
+                      budget: int) -> list[tuple[tuple[int, int], list]]:
+        """Plan up to ``budget`` prompt tokens: advance in-flight prefill
+        cursors first (admission order), then admit new requests from the
+        queue head, FIFO, with layout-aware placement.  Returns the chunk
+        groups, ((cursor, bucket), [(req, take)]), in the order they run."""
         tr = self.tracer
-        if tr.enabled:
-            tr.begin_phase("plan")
         in_flight = sorted(
             (r for r in self.running.values()
              if r.status is Status.PREFILLING),
@@ -522,9 +520,12 @@ class ServingEngine:
         for req, take in runnable:
             by_shape.setdefault((req.prefill_cursor, _bucket(take)),
                                 []).append((req, take))
-        if tr.enabled:
-            tr.end_phase(planned=len(runnable))
-        for (cursor, bucket), group in sorted(by_shape.items()):
+        return sorted(by_shape.items())
+
+    def _run_chunks(self, groups: list, stats: dict) -> None:
+        """Run the planned chunk groups, each one batched step, and emit a
+        first token for every row whose prompt they completed."""
+        for (cursor, bucket), group in groups:
             # a LATER plan entry's capacity loop may have preempted a
             # request after it was validated into runnable (its slot is
             # None and its cursor reset) — re-check liveness per group
@@ -532,14 +533,11 @@ class ServingEngine:
                      if self.running.get(r.slot) is r
                      and r.prefill_cursor == cursor]
             if group:
-                if tr.enabled:
-                    tr.begin_phase("chunk", cursor=cursor, bucket=bucket,
-                                   n_rows=len(group),
-                                   tokens=sum(t for _, t in group))
-                stats["finished"] += self._run_chunk_group(group, cursor,
-                                                           bucket, stats)
-                if tr.enabled:
-                    tr.end_phase()
+                with span("serve.chunk", self.tracer):
+                    done = self._run_chunk_group(group, cursor, bucket,
+                                                 stats)
+                if done:
+                    stats["finished"] += self._emit_tokens(done)
 
     def _ensure_chunk_capacity(self, req: Request, take: int,
                                stats: dict) -> bool:
@@ -586,13 +584,13 @@ class ServingEngine:
             self.spec.on_admit(slot)
 
     def _run_chunk_group(self, group: list[tuple], cursor: int, bucket: int,
-                         stats: dict) -> int:
+                         stats: dict) -> list[int]:
         """Run one batched step for rows sharing (cursor, bucket): write
         tokens [cursor, cursor+take) straight into the arena and attend in
         place against the already-written context (``unified_step`` — at
-        cursor 0 this IS the one-shot prefill), then emit a first token
-        for every row whose cursor reached its sequence end.  Returns the
-        number of requests that finished immediately."""
+        cursor 0 this IS the one-shot prefill), then gather the last
+        logits of every row whose cursor reached its sequence end into
+        the per-slot logits.  Returns those rows' slots."""
         n = len(group)
         B = _bucket(n, 1)                   # batch pad, power-of-two ladder
         rows = [req.slot for req, _ in group]
@@ -633,12 +631,12 @@ class ServingEngine:
                 done_idx.append(i)
                 done_rows.append(req.slot)
                 done_last.append(take - 1)
-        if not done_rows:
-            return 0
-        last_logits = logits[jnp.asarray(done_idx), jnp.asarray(done_last)]
-        self._slot_logits = self._slot_logits.at[jnp.asarray(done_rows)].set(
-            last_logits.astype(jnp.float32))
-        return self._emit_tokens(done_rows)
+        if done_rows:
+            last_logits = logits[jnp.asarray(done_idx),
+                                 jnp.asarray(done_last)]
+            self._slot_logits = self._slot_logits.at[
+                jnp.asarray(done_rows)].set(last_logits.astype(jnp.float32))
+        return done_rows
 
     # -------------------------------------------------------------- decode
     def _preempt_one(self, stats: dict, exclude: Request | None = None,
@@ -690,36 +688,33 @@ class ServingEngine:
         stats = stats if stats is not None else {"preempted": 0}
         if self.spec is not None:
             return self._speculative_decode(stats)
-        tr = self.tracer
-        active = self._decode_rows()
-        if self.kv_layout == "paged":
-            while True:
-                try:
-                    self.pool.prepare_decode(active)
-                    break
-                except OutOfBlocks:
-                    if len(self.running) <= 1:
-                        # cannot happen for admissible requests (submit
-                        # bounds prompt+gen by pool capacity), so this is
-                        # an accounting bug, not workload pressure
-                        raise CachePoolError(
-                            "sole running request cannot grow its KV")
-                    self._preempt_one(stats, reason=PREEMPT_DECODE_PRESSURE)
-                    active = self._decode_rows()
-            if not active:
-                return 0
-        stats["decoded"] = len(active)
-        if tr.enabled:
-            tr.begin_phase("decode", n_active=len(active))
-        tokens = jnp.asarray(self._last_token[:, None])
-        logits = self.adapter.step_decode(tokens, active)
-        self._slot_logits = logits[:, 0].astype(jnp.float32)
+        with span("serve.decode", self.tracer):
+            active = self._decode_rows()
+            if self.kv_layout == "paged":
+                while True:
+                    try:
+                        self.pool.prepare_decode(active)
+                        break
+                    except OutOfBlocks:
+                        if len(self.running) <= 1:
+                            # cannot happen for admissible requests (submit
+                            # bounds prompt+gen by pool capacity), so this
+                            # is an accounting bug, not workload pressure
+                            raise CachePoolError(
+                                "sole running request cannot grow its KV")
+                        self._preempt_one(stats,
+                                          reason=PREEMPT_DECODE_PRESSURE)
+                        active = self._decode_rows()
+                if not active:
+                    return 0
+            stats["decoded"] = len(active)
+            tokens = jnp.asarray(self._last_token[:, None])
+            logits = self.adapter.step_decode(tokens, active)
+            self._slot_logits = logits[:, 0].astype(jnp.float32)
         n_finished = self._emit_tokens(active)
         advanced = np.zeros((self.pool.n_slots,), bool)
         advanced[[s for s in active if s in self.running]] = True
         self.pool.advance_decode(advanced)
-        if tr.enabled:
-            tr.end_phase(finished=n_finished)
         return n_finished
 
     def _speculative_decode(self, stats: dict) -> int:
@@ -752,158 +747,152 @@ class ServingEngine:
                     cap - len(seqs[s]))
             ks.append(max(k, 0))
 
-        if tr.enabled:
-            tr.begin_phase("draft", n_rows=len(active))
-        proposals = spec.propose(active, [seqs[s] for s in active], ks)
+        with span("serve.draft", tr):
+            proposals = spec.propose(active, [seqs[s] for s in active], ks)
         drafts = dict(zip(active, proposals))
-        if tr.enabled:
-            tr.end_phase(drafted=sum(len(d) for d in proposals))
 
-        if self.kv_layout == "paged":
-            while True:
-                try:
-                    self.pool.prepare_decode(
-                        active, [len(drafts[s]) + 1 for s in active])
-                    break
-                except OutOfBlocks:
-                    if len(self.running) <= 1:
-                        raise CachePoolError(
-                            "sole running request cannot grow its KV")
-                    self._preempt_one(stats, reason=PREEMPT_DECODE_PRESSURE)
-                    active = self._decode_rows()
-            if not active:
-                return 0
+        with span("serve.decode", tr):
+            if self.kv_layout == "paged":
+                while True:
+                    try:
+                        self.pool.prepare_decode(
+                            active, [len(drafts[s]) + 1 for s in active])
+                        break
+                    except OutOfBlocks:
+                        if len(self.running) <= 1:
+                            raise CachePoolError(
+                                "sole running request cannot grow its KV")
+                        self._preempt_one(stats,
+                                          reason=PREEMPT_DECODE_PRESSURE)
+                        active = self._decode_rows()
+                if not active:
+                    return 0
 
-        n = len(active)
-        nds = [len(drafts[s]) for s in active]
-        # Verify always runs the full lane complement (like fused decode):
-        # a constant B keeps the compiled-variant count linear in the S
-        # ladder instead of B x S, so a trickle of arrivals can't hit
-        # batch shapes the warmup never saw.
-        B = _bucket(self.pool.n_slots, 1)
-        S = verify_bucket(max(nds) + 1, self.spec.cfg.k)
-        tokens = np.zeros((B, S), np.int32)
-        cur = np.zeros((B,), np.int32)
-        n_new = np.zeros((B,), np.int32)
-        draft_arr = np.zeros((B, S), np.int32)
-        n_draft = np.zeros((B,), np.int32)
-        lane_slot = np.zeros((B,), np.int64)     # pad lanes borrow slot 0
-        for i, s in enumerate(active):
-            nd = nds[i]
-            cur[i] = len(seqs[s]) - 1
-            tokens[i, 0] = self._last_token[s]
-            if nd:
-                tokens[i, 1:1 + nd] = drafts[s]
-                draft_arr[i, :nd] = drafts[s]
-            n_new[i] = nd + 1
-            n_draft[i] = nd
-            lane_slot[i] = s
-        if self.kv_layout == "paged":
-            lanes = self.pool.lane_tables(active, B)
-        else:
-            lanes = self.pool.lane_rows(active, B)
-        if tr.enabled:
-            tr.begin_phase("verify", n_rows=n, s_bucket=S,
-                           drafted=int(n_draft.sum()))
-        logits = self.adapter.step_chunk(
-            active, jnp.asarray(lanes), jnp.asarray(cur), jnp.asarray(n_new),
-            jnp.asarray(tokens))
-        # leave-one-in verification over the whole (bucketed) batch — pad
-        # lanes verify slot 0's parameters against garbage and are never
-        # read, keeping verify_draft's compiled shapes on the same ladder
-        n_acc, v_toks, v_lps = verify_draft(
-            logits.astype(jnp.float32), jnp.asarray(draft_arr),
-            jnp.asarray(n_draft), jnp.asarray(self._temps[lane_slot]),
-            jnp.asarray(self._topks[lane_slot]),
-            jnp.asarray(self._seeds[lane_slot]),
-            jnp.asarray(self._gen_count[lane_slot]))
-        n_acc = np.asarray(n_acc)
-        v_toks = np.asarray(v_toks)
-        v_lps = np.asarray(v_lps)
-        if tr.enabled:
-            tr.end_phase(accepted=int(n_acc[:n].sum()))
+            n = len(active)
+            nds = [len(drafts[s]) for s in active]
+            # Verify always runs the full lane complement (like fused
+            # decode): a constant B keeps the compiled-variant count linear
+            # in the S ladder instead of B x S, so a trickle of arrivals
+            # can't hit batch shapes the warmup never saw.
+            B = _bucket(self.pool.n_slots, 1)
+            S = verify_bucket(max(nds) + 1, self.spec.cfg.k)
+            tokens = np.zeros((B, S), np.int32)
+            cur = np.zeros((B,), np.int32)
+            n_new = np.zeros((B,), np.int32)
+            draft_arr = np.zeros((B, S), np.int32)
+            n_draft = np.zeros((B,), np.int32)
+            lane_slot = np.zeros((B,), np.int64)  # pad lanes borrow slot 0
+            for i, s in enumerate(active):
+                nd = nds[i]
+                cur[i] = len(seqs[s]) - 1
+                tokens[i, 0] = self._last_token[s]
+                if nd:
+                    tokens[i, 1:1 + nd] = drafts[s]
+                    draft_arr[i, :nd] = drafts[s]
+                n_new[i] = nd + 1
+                n_draft[i] = nd
+                lane_slot[i] = s
+            if self.kv_layout == "paged":
+                lanes = self.pool.lane_tables(active, B)
+            else:
+                lanes = self.pool.lane_rows(active, B)
+            logits = self.adapter.step_chunk(
+                active, jnp.asarray(lanes), jnp.asarray(cur),
+                jnp.asarray(n_new), jnp.asarray(tokens))
+            # leave-one-in verification over the whole (bucketed) batch —
+            # pad lanes verify slot 0's parameters against garbage and are
+            # never read, keeping verify_draft's compiled shapes on the same
+            # ladder
+            n_acc, v_toks, v_lps = verify_draft(
+                logits.astype(jnp.float32), jnp.asarray(draft_arr),
+                jnp.asarray(n_draft), jnp.asarray(self._temps[lane_slot]),
+                jnp.asarray(self._topks[lane_slot]),
+                jnp.asarray(self._seeds[lane_slot]),
+                jnp.asarray(self._gen_count[lane_slot]))
+        with span("serve.emit", tr):
+            with span("serve.sync", tr):
+                n_acc = np.asarray(n_acc)
+                v_toks = np.asarray(v_toks)
+                v_lps = np.asarray(v_lps)
 
-        # cursor rollback/advance BEFORE emission releases any slot: each
-        # row's written positions become exactly len(seq) - 1 again once
-        # its accepted+1 tokens are appended (the engine invariant)
-        self.pool.advance_prefill(
-            active, [int(cur[i]) + 1 + int(n_acc[i]) for i in range(n)])
+            # cursor rollback/advance BEFORE emission releases any slot:
+            # each row's written positions become exactly len(seq) - 1
+            # again once its accepted+1 tokens are appended (the engine
+            # invariant)
+            self.pool.advance_prefill(
+                active,
+                [int(cur[i]) + 1 + int(n_acc[i]) for i in range(n)])
 
-        if tr.enabled:
-            tr.begin_phase("emit", n_rows=n)
-        now = self._clock()
-        n_finished = 0
-        drafted = accepted = emitted = 0
-        for i, s in enumerate(active):
-            req = self.running[s]
-            nd, a = nds[i], int(n_acc[i])
-            drafted += nd
-            accepted += a
-            req.metrics.spec_drafted += nd
-            req.metrics.spec_accepted += a
-            if spec.cfg.adaptive and nd > 0:
-                if a == nd:
-                    req.draft_k = min(req.draft_k + 1, spec.cfg.max_k)
-                elif 2 * a < nd:
-                    req.draft_k = max(req.draft_k - 1, spec.cfg.min_k)
-            spec.rollback(s, nd, a)
-            sp = req.sampling
-            for j in range(a + 1):
-                tok = int(v_toks[i, j])
-                req._emit(tok, now, logprob=float(v_lps[i, j]))
-                self._last_token[s] = tok
-                self._gen_count[s] += 1
-                emitted += 1
-                if (len(req.tokens) >= sp.max_new_tokens
-                        or (sp.eos_id is not None and tok == sp.eos_id)):
-                    req._finish(Status.FINISHED, now)
-                    self.finished.append(req)
-                    del self.running[s]
-                    self.pool.release(s)
-                    n_finished += 1
-                    if tr.enabled:
-                        tr.on_finish(req)
-                    break
-        self.n_spec_steps += 1
-        self.n_drafted += drafted
-        self.n_accepted += accepted
-        stats["decoded"] = n
-        stats["spec_drafted"] = drafted
-        stats["spec_accepted"] = accepted
-        stats["spec_emitted"] = emitted
-        if tr.enabled:
-            tr.end_phase(finished=n_finished)
+            now = self._clock()
+            n_finished = 0
+            drafted = accepted = emitted = 0
+            for i, s in enumerate(active):
+                req = self.running[s]
+                nd, a = nds[i], int(n_acc[i])
+                drafted += nd
+                accepted += a
+                req.metrics.spec_drafted += nd
+                req.metrics.spec_accepted += a
+                if spec.cfg.adaptive and nd > 0:
+                    if a == nd:
+                        req.draft_k = min(req.draft_k + 1, spec.cfg.max_k)
+                    elif 2 * a < nd:
+                        req.draft_k = max(req.draft_k - 1, spec.cfg.min_k)
+                spec.rollback(s, nd, a)
+                sp = req.sampling
+                for j in range(a + 1):
+                    tok = int(v_toks[i, j])
+                    req._emit(tok, now, logprob=float(v_lps[i, j]))
+                    self._last_token[s] = tok
+                    self._gen_count[s] += 1
+                    emitted += 1
+                    if (len(req.tokens) >= sp.max_new_tokens
+                            or (sp.eos_id is not None
+                                and tok == sp.eos_id)):
+                        req._finish(Status.FINISHED, now)
+                        self.finished.append(req)
+                        del self.running[s]
+                        self.pool.release(s)
+                        n_finished += 1
+                        if tr.enabled:
+                            tr.on_finish(req)
+                        break
+            self.n_spec_steps += 1
+            self.n_drafted += drafted
+            self.n_accepted += accepted
+            stats["decoded"] = n
+            stats["spec_drafted"] = drafted
+            stats["spec_accepted"] = accepted
+            stats["spec_emitted"] = emitted
         return n_finished
 
     def _emit_tokens(self, slots: list[int]) -> int:
         """Sample one token for ``slots`` from _slot_logits, stream it, and
         retire requests that hit max_new_tokens / EOS.  Returns retirements."""
         tr = self.tracer
-        if tr.enabled:
-            tr.begin_phase("emit", n_rows=len(slots))
-        toks, lps = sample_tokens_logprobs(
-            self._slot_logits, jnp.asarray(self._temps),
-            jnp.asarray(self._topks), jnp.asarray(self._seeds),
-            jnp.asarray(self._gen_count))
-        toks, lps = np.asarray(toks), np.asarray(lps)
-        now = self._clock()
-        n_finished = 0
-        for slot in slots:
-            req = self.running[slot]
-            tok = int(toks[slot])
-            req._emit(tok, now, logprob=float(lps[slot]))
-            self._last_token[slot] = tok
-            self._gen_count[slot] += 1
-            sp = req.sampling
-            if (len(req.tokens) >= sp.max_new_tokens
-                    or (sp.eos_id is not None and tok == sp.eos_id)):
-                req._finish(Status.FINISHED, now)
-                self.finished.append(req)
-                del self.running[slot]
-                self.pool.release(slot)
-                n_finished += 1
-                if tr.enabled:
-                    tr.on_finish(req)
-        if tr.enabled:
-            tr.end_phase(finished=n_finished)
+        with span("serve.emit", tr):
+            toks, lps = sample_tokens_logprobs(
+                self._slot_logits, jnp.asarray(self._temps),
+                jnp.asarray(self._topks), jnp.asarray(self._seeds),
+                jnp.asarray(self._gen_count))
+            with span("serve.sync", tr):
+                toks, lps = np.asarray(toks), np.asarray(lps)
+            now = self._clock()
+            n_finished = 0
+            for slot in slots:
+                req = self.running[slot]
+                tok = int(toks[slot])
+                req._emit(tok, now, logprob=float(lps[slot]))
+                self._last_token[slot] = tok
+                self._gen_count[slot] += 1
+                sp = req.sampling
+                if (len(req.tokens) >= sp.max_new_tokens
+                        or (sp.eos_id is not None and tok == sp.eos_id)):
+                    req._finish(Status.FINISHED, now)
+                    self.finished.append(req)
+                    del self.running[slot]
+                    self.pool.release(slot)
+                    n_finished += 1
+                    if tr.enabled:
+                        tr.on_finish(req)
         return n_finished

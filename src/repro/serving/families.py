@@ -71,7 +71,6 @@ from ..models import xlstm as xls
 from ..models import zamba as zam
 from ..parallel import policy as pol
 from .cache_pool import CachePoolError, SlotKVPool, SlotPoolView
-from .observe import NULL_TRACER
 from .paged import PagedKVPool, PagedPoolView
 from .state_pool import (EncDecPoolView, EncoderContextPool, HybridPoolView,
                          RecurrentStatePool, RecurrentStateView)
@@ -81,11 +80,12 @@ def _suspend(fn):
     """Trace ``fn`` under a suspended activation-sharding policy (see the
     engine docstring: an ambient policy would flip MoE onto the
     capacity-bounded expert-parallel path where pad tokens evict real
-    ones)."""
-    def traced(*args):
+    ones).  Every step program lowers as ``jit_serve_step``: profiler
+    traces and the benchmark find the programs by that name."""
+    def serve_step(*args):
         with pol.suspended():
             return fn(*args)
-    return traced
+    return serve_step
 
 
 def _jit(placement, fn, donate=(), in_shardings=None, out_shardings=None):
@@ -102,22 +102,6 @@ class FamilyAdapter:
     params = None
     pool = None
     kv_layout = "slot"
-    # observability: the engine installs its ServingTracer here at
-    # construction when tracing is on; the default NULL_TRACER keeps
-    # ``_traced`` a direct call with no per-step work (serving/observe.py)
-    tracer = NULL_TRACER
-    # distinguishes co-resident adapters sharing one tracer: the
-    # speculative draft model's adapter sets "draft_" so its jit variants
-    # attribute as draft_step/draft_decode, separate from the target's
-    trace_kind_prefix = ""
-
-    def _traced(self, kind: str, fn, args: tuple):
-        """Run a jitted step function, attributed when tracing is on:
-        wall-clock + compile/retrace detection + cost model per shape
-        variant (``ServingTracer.jit_call``)."""
-        if not self.tracer.enabled:
-            return fn(*args)
-        return self.tracer.jit_call(self.trace_kind_prefix + kind, fn, args)
 
     def on_admit(self, req, slot: int) -> int:
         return 0
@@ -221,22 +205,19 @@ class TransformerAdapter(FamilyAdapter):
         return (p.k, p.v)
 
     def step_chunk(self, rows, lanes, cur, n_new, tokens):
-        logits, arenas = self._traced(
-            "step", self._step_fn,
-            (self.params, *self._arena_args(), lanes, cur, n_new, tokens))
+        logits, arenas = self._step_fn(self.params, *self._arena_args(),
+                                       lanes, cur, n_new, tokens)
         self.pool.adopt(*arenas)
         return logits
 
     def step_decode(self, tokens, active):
         if self.kv_layout == "paged":
-            logits, arenas = self._traced(
-                "decode", self._decode_fn,
-                (self.params, *self._arena_args(),
-                 self.pool.block_tables, self.pool.pos, tokens))
+            logits, arenas = self._decode_fn(
+                self.params, *self._arena_args(), self.pool.block_tables,
+                self.pool.pos, tokens)
         else:
-            logits, arenas = self._traced(
-                "decode", self._decode_fn,
-                (self.params, *self._arena_args(), self.pool.pos, tokens))
+            logits, arenas = self._decode_fn(
+                self.params, *self._arena_args(), self.pool.pos, tokens)
         self.pool.adopt(*arenas)
         return logits
 
@@ -270,9 +251,8 @@ class RecurrentAdapter(FamilyAdapter):
             out_shardings=(rep, ssh))
 
     def step_chunk(self, rows, lanes, cur, n_new, tokens):
-        logits, states = self._traced(
-            "step", self._step_fn,
-            (self.params, self.pool.states, lanes, cur, n_new, tokens))
+        logits, states = self._step_fn(self.params, self.pool.states, lanes,
+                                       cur, n_new, tokens)
         self.pool.adopt(states)
         return logits
 
@@ -283,10 +263,9 @@ class RecurrentAdapter(FamilyAdapter):
         # overwrite-before-read safety net for a recurrence
         act = np.zeros((self.pool.n_slots,), np.int32)
         act[active] = 1
-        logits, states = self._traced(
-            "decode", self._decode_fn,
-            (self.params, self.pool.states, self.pool.pos, jnp.asarray(act),
-             tokens))
+        logits, states = self._decode_fn(self.params, self.pool.states,
+                                         self.pool.pos, jnp.asarray(act),
+                                         tokens)
         self.pool.adopt(states)
         return logits
 
@@ -424,15 +403,13 @@ class HybridAdapter(FamilyAdapter):
         kv, st = self.pool.kv, self.pool.state
         if self.kv_layout == "paged":
             srows = jnp.asarray(st.lane_rows(rows, tokens.shape[0]))
-            logits, (k, v), states = self._traced(
-                "step", self._step_fn,
-                (self.params, kv.k, kv.v, st.states, lanes, srows, cur,
-                 n_new, tokens))
+            logits, (k, v), states = self._step_fn(
+                self.params, kv.k, kv.v, st.states, lanes, srows, cur,
+                n_new, tokens)
         else:
-            logits, (k, v), states = self._traced(
-                "step", self._step_fn,
-                (self.params, kv.k, kv.v, st.states, lanes, cur, n_new,
-                 tokens))
+            logits, (k, v), states = self._step_fn(
+                self.params, kv.k, kv.v, st.states, lanes, cur, n_new,
+                tokens)
         kv.adopt(k, v)
         st.adopt(states)
         return logits
@@ -442,15 +419,13 @@ class HybridAdapter(FamilyAdapter):
         act = np.zeros((kv.n_slots,), np.int32)
         act[active] = 1
         if self.kv_layout == "paged":
-            logits, (k, v), states = self._traced(
-                "decode", self._decode_fn,
-                (self.params, kv.k, kv.v, st.states, kv.block_tables,
-                 kv.pos, jnp.asarray(act), tokens))
+            logits, (k, v), states = self._decode_fn(
+                self.params, kv.k, kv.v, st.states, kv.block_tables,
+                kv.pos, jnp.asarray(act), tokens)
         else:
-            logits, (k, v), states = self._traced(
-                "decode", self._decode_fn,
-                (self.params, kv.k, kv.v, st.states, kv.pos,
-                 jnp.asarray(act), tokens))
+            logits, (k, v), states = self._decode_fn(
+                self.params, kv.k, kv.v, st.states, kv.pos,
+                jnp.asarray(act), tokens)
         kv.adopt(k, v)
         st.adopt(states)
         return logits
@@ -517,19 +492,18 @@ class EncDecAdapter(FamilyAdapter):
     def step_chunk(self, rows, lanes, cur, n_new, tokens):
         pool, ctx = self.pool, self.ctx
         clen = jnp.asarray(ctx.lane_lens(rows, tokens.shape[0]))
-        logits, (k, v) = self._traced(
-            "step", self._step_fn,
-            (self.params, pool.k, pool.v, ctx.ck, ctx.cv, clen, lanes, cur,
-             n_new, tokens))
+        logits, (k, v) = self._step_fn(self.params, pool.k, pool.v, ctx.ck,
+                                       ctx.cv, clen, lanes, cur, n_new,
+                                       tokens)
         pool.adopt(k, v)
         return logits
 
     def step_decode(self, tokens, active):
         pool, ctx = self.pool, self.ctx
-        logits, (k, v) = self._traced(
-            "decode", self._decode_fn,
-            (self.params, pool.k, pool.v, ctx.ck, ctx.cv,
-             jnp.asarray(ctx.lens), pool.pos, tokens))
+        logits, (k, v) = self._decode_fn(self.params, pool.k, pool.v,
+                                         ctx.ck, ctx.cv,
+                                         jnp.asarray(ctx.lens), pool.pos,
+                                         tokens)
         pool.adopt(k, v)
         return logits
 
@@ -553,7 +527,7 @@ class EncDecAdapter(FamilyAdapter):
                        pool.v.at[:, slot].set(blob["v"].astype(pool.v.dtype)))
             return blob["pos"]
         emb = jnp.asarray(req.embeds, self.cfg.dtype)[None]    # [1, Se, d]
-        ck, cv = self._traced("encode", self._encode_fn, (self.params, emb))
+        ck, cv = self._encode_fn(self.params, emb)
         self.ctx.write(slot, ck[:, 0], cv[:, 0])
         return 0
 

@@ -1,22 +1,47 @@
-"""Serving observability: request/step tracing, the serving counter set,
-and step-time attribution — zero-cost when disabled.
+"""Serving observability: the engine's phase spans, request/step tracing
+and the serving counter set — free when no profile is being taken.
 
-The engine takes a ``tracer=`` at construction.  The default is
-``NULL_TRACER``, a singleton whose every hook is a no-op and whose
-``enabled`` flag is False; every call site in the hot path is guarded by
-``if tracer.enabled`` so the disabled engine allocates NOTHING for
-observability per step (tests/test_observe.py pins both the identity and
-the token-identity of traced vs untraced runs).  Pass a ``ServingTracer``
-and the same hooks populate three artifacts:
+**Phase spans** (``span``).  The engine marks its phases with
+``span(name, tracer)``, one context manager per phase.  It always opens
+a ``jax.profiler.TraceAnnotation(name)``, so a profiler trace that is
+running (``jax.profiler.start_trace``) holds the phase on the host thread
+that drives the engine, on the device trace's clock, beside the device
+programs the phase launched.  When no profile is being taken the
+annotation records nothing: a span does no host sync, writes no buffer
+and formats no string.  The names, with their nesting:
+
+  ``serve.step``          the whole of ``engine.step``
+    ``serve.schedule``    evict expired requests, plan and admit
+    ``serve.chunk``       one chunk-group dispatch and its last-logit gather
+    ``serve.draft``       the speculative drafter's proposals
+    ``serve.decode``      prepare the decode rows, build the inputs and
+                          dispatch the decode (the speculative verify)
+    ``serve.emit``        the sampler call, streaming tokens, retiring
+                          requests
+      ``serve.sync``      exactly the blocking reads of sampled tokens and
+                          logprobs (the drafter's reads of its proposals
+                          stay in ``serve.draft``)
+
+The step programs themselves lower as ``jit_serve_step``
+(``families._suspend``), and the sparse linear's device ops carry the
+scope ``sparse_linear`` in their op-name metadata, which a device profile
+keeps as each op's ``tf_op`` (``models/sparse_serving.sparse_apply``).
+
+**The engine's tracer.**  The engine takes a ``tracer=``.  The default
+is ``NULL_TRACER``, a singleton whose every hook is a no-op and whose
+``enabled`` flag is False; every other call site in the hot path is
+guarded by ``if tracer.enabled`` (tests/test_observe.py pins both the
+identity and the token-identity of traced vs untraced runs).  Pass a
+``ServingTracer`` (``launch/serve.py --trace-out``) and it also writes:
 
 1. **Spans** (``runtime/telemetry.py`` ``TraceBuffer``, Chrome/Perfetto
-   ``trace_event`` JSON — load the written file in ``ui.perfetto.dev``):
+   ``trace_event`` JSON — load the written file in ``ui.perfetto.dev``),
+   on the engine's clock:
 
-   - an *engine* process: one "step" span per ``engine.step()`` with
-     child spans for the plan / chunk / decode / emit phases and each
-     jitted call ("jit:step", "jit:decode", ...), plus instants for
-     compiles and retraces (a new ``_step_fn`` shape bucket), preemptions
-     (victim + reason), and prefix-cache lookups (matched-block depth);
+   - an *engine* process: every ``span`` above as a "phase" span (the
+     ``serve.step`` span carries the step's stats), plus instants for
+     compiles, preemptions (victim + reason), and prefix-cache lookups
+     (matched-block depth);
    - a *requests* process: one thread per request id carrying its
      lifecycle spans — "queued" (arrival -> admitted, re-opened on
      preemption), "prefill" (admitted -> prefill complete, with "chunk"
@@ -27,22 +52,18 @@ and the same hooks populate three artifacts:
      and can be cross-checked exactly.
 
 2. **Counters/gauges** (``MetricsRegistry``): tokens prefilled/decoded,
-   requests finished/evicted, preemptions by reason, compiles/retraces
-   per jitted function, prefix-cache lookups/hit-tokens, speculative
-   draft/accept/emit token counters (plus a per-step acceptance-rate
-   gauge — the live Divergent-Token probe), and per-step
+   requests finished/evicted, preemptions by reason, compiles by the
+   compiled function's name and the ``serve.*`` phase that dispatched
+   them (JAX's own ``backend_compile_duration`` events, heard through
+   ``jax.monitoring`` while this tracer's engine is inside a step: no
+   blocking call and no extra compile; the drafter's compiles fall under
+   ``serve.draft``), prefix-cache lookups/hit-tokens,
+   speculative draft/accept/emit token counters (plus a per-step
+   acceptance-rate gauge — the live Divergent-Token probe), and per-step
    gauges (queue depth, running, pool occupancy, budget utilization) —
-   all labelled by model family — rendered as a Prometheus text snapshot
-   (``counters_text()``) and sampled into the trace as "C" counter
-   events every ``sample_every`` steps.
-
-3. **Step-time attribution** (``jit_call``): every jitted step call is
-   wall-clocked (blocking on its outputs) and keyed by its argument
-   shapes — the exact retrace key, params aside — and each new variant
-   is costed once through ``launch/hlo_analysis.cost_summary`` (compiled
-   FLOPs / bytes-accessed), so a tok/s regression decomposes into
-   compute (flops/bytes grew), scheduling (more steps, lower budget
-   utilization), or recompilation (retrace instants in the window).
+   all labelled by engine and model family — rendered as a Prometheus
+   text snapshot (``counters_text()``) and sampled into the trace as "C"
+   counter events every ``sample_every`` steps.
 
 Multiple engines can share one ``TraceBuffer`` and one
 ``MetricsRegistry`` (the benchmark traces dense/sparse x slot/paged runs
@@ -57,46 +78,24 @@ tests produce exact, deterministic traces.
 from __future__ import annotations
 
 import time
+import weakref
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from ..runtime.telemetry import MetricsRegistry, TraceBuffer
 
 
-class _NullSpan:
-    """Inert context manager; one shared instance, never allocates."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """The disabled tracer: every hook is a no-op returning a shared
-    singleton.  ``enabled`` is False so engine call sites skip even
-    argument construction; the hooks still exist so an unguarded call is
-    harmless rather than fatal."""
+    """The disabled tracer: every hook is a no-op.  ``enabled`` is False
+    so engine call sites skip even argument construction; the hooks still
+    exist so an unguarded call is harmless rather than fatal."""
     enabled = False
 
     def attach(self, engine, name=""):
         return self
 
-    def begin_step(self, n_step, now):
-        return NULL_SPAN
-
     def end_step(self, engine, stats):
-        pass
-
-    def begin_phase(self, name, **args):
-        return NULL_SPAN
-
-    def end_phase(self, **args):
         pass
 
     def instant(self, name, **args):
@@ -126,13 +125,74 @@ class NullTracer:
     def on_withdraw(self, req):
         pass
 
-    def jit_call(self, kind, fn, args):
-        return fn(*args)
-
 
 NULL_TRACER = NullTracer()
 
 _ENGINE_TID = 0
+
+
+def span(name: str, tracer=NULL_TRACER):
+    """Context manager marking one engine phase: a profiler annotation
+    ``name`` (recorded only while a profile is being taken), and with an
+    enabled ``tracer`` also a "phase" span in its buffer."""
+    if tracer.enabled:
+        return _Phase(tracer, name)
+    return TraceAnnotation(name)
+
+
+class _Phase:
+    """An enabled tracer's span: the profiler annotation, and the phase
+    written to the tracer's buffer on exit (with any args ``end_step``
+    left on it)."""
+    __slots__ = ("tracer", "name", "ann", "t0", "args")
+
+    def __init__(self, tracer, name):
+        self.tracer, self.name = tracer, name
+        self.ann = TraceAnnotation(name)
+        self.args = None
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = self.tracer.clock()
+        self.tracer._phases.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        tr = self.tracer
+        tr._phases.pop()
+        ts0 = tr._ts(self.t0)
+        tr.buffer.complete(self.name, ts0, tr._ts() - ts0,
+                           pid=tr._pid_engine, tid=_ENGINE_TID, cat="phase",
+                           args=self.args)
+        self.ann.__exit__(*exc)
+        return False
+
+
+# JAX reports each XLA compile through jax.monitoring; the event wraps
+# its compile_or_get_cached, so a persistent-cache load reports too.  One
+# process-wide listener hands the event to the tracer whose engine is
+# inside a step: compiles run synchronously in the phase that dispatched
+# them, and the engines of one process step one at a time.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_TRACERS: weakref.WeakSet = weakref.WeakSet()
+_compile_listener_on = False
+
+
+def _on_duration_event(event: str, duration: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        for tracer in list(_COMPILE_TRACERS):
+            if tracer._phases:
+                tracer._on_compile(str(kw.get("fun_name", "")),
+                                   tracer._phases[-1].name, duration)
+
+
+def _hear_compiles(tracer) -> None:
+    global _compile_listener_on
+    if not _compile_listener_on:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _compile_listener_on = True
+    _COMPILE_TRACERS.add(tracer)
 
 
 class ServingTracer:
@@ -161,15 +221,10 @@ class ServingTracer:
         self.family = ""
         self._pid_engine: int | None = None
         self._pid_requests: int | None = None
-        # open spans: engine-phase stack, per-step state, per-request state
-        self._phase_stack: list[tuple[str, float, dict]] = []
-        self._step_t0: float | None = None
-        self._step_n: int = 0
+        # open spans: engine phases (innermost last), per-request state
+        self._phases: list[_Phase] = []
         self._req_open: dict[int, dict[str, float]] = {}
         self._req_cached: dict[int, int] = {}
-        # jit variants: shape-key -> attribution record
-        self._variants: dict[tuple, dict] = {}
-        self._kind_counts: dict[str, int] = {}
         self._counters_made = False
 
     # --------------------------------------------------------------- setup
@@ -194,6 +249,7 @@ class ServingTracer:
                                      f"requests {self.name}")
         self.buffer.set_thread_name(self._pid_engine, _ENGINE_TID, "steps")
         self._make_counters()
+        _hear_compiles(self)
         return self
 
     def _make_counters(self):
@@ -211,10 +267,9 @@ class ServingTracer:
             "requests preempted back to the queue, by pressure source")
         self.c_compiles = r.counter(
             "serving_jit_compiles_total",
-            "first-time compilations of a jitted step variant")
-        self.c_retraces = r.counter(
-            "serving_jit_retraces_total",
-            "additional shape-bucket variants of an already-compiled fn")
+            "XLA compiles (persistent compile-cache loads included) JAX "
+            "reported inside this engine's steps, by compiled function "
+            "and the innermost serve.* phase that dispatched them")
         self.c_cache_lookups = r.counter(
             "serving_prefix_cache_lookups_total",
             "prefix-cache lookups at admission")
@@ -263,12 +318,10 @@ class ServingTracer:
         t = self.clock() if t is None else t
         return (t - self.t0) * 1e6
 
-    # -------------------------------------------------------- step spans
-    def begin_step(self, n_step: int, now: float) -> None:
-        self._step_t0 = now
-        self._step_n = n_step
-
+    # ------------------------------------------------------- step counters
     def end_step(self, engine, stats: dict) -> None:
+        """Count a finished step (called inside its ``serve.step`` span,
+        which then carries ``stats``)."""
         now = self.clock()
         lb = self._labels()
         self.c_steps.inc(**lb)
@@ -300,28 +353,16 @@ class ServingTracer:
             if pool.prefix_cache is not None:
                 self.g_cache_entries.set(len(pool.prefix_cache), **lb)
                 sample["prefix_cache_entries"] = len(pool.prefix_cache)
-        ts0 = self._ts(self._step_t0)
-        if self._step_n % self.sample_every == 0:
+        if (engine.n_steps - 1) % self.sample_every == 0:
             self.buffer.counter("engine", self._ts(now), sample,
                                 pid=self._pid_engine, tid=_ENGINE_TID)
-        self.buffer.complete("step", ts0, self._ts(now) - ts0,
-                             pid=self._pid_engine, tid=_ENGINE_TID,
-                             cat="step", args=dict(stats))
-        self._step_t0 = None
+        if self._phases:
+            self._phases[-1].args = dict(stats)
 
-    def begin_phase(self, name: str, **args) -> None:
-        self._phase_stack.append((name, self.clock(), args))
-
-    def end_phase(self, **args) -> None:
-        if not self._phase_stack:
-            return
-        name, t0, a = self._phase_stack.pop()
-        if args:
-            a.update(args)
-        ts0 = self._ts(t0)
-        self.buffer.complete(name, ts0, self._ts() - ts0,
-                             pid=self._pid_engine, tid=_ENGINE_TID,
-                             cat="phase", args=a or None)
+    def _on_compile(self, fun_name: str, phase: str, seconds: float) -> None:
+        self.c_compiles.inc(fn=fun_name, phase=phase, engine=self.name)
+        self.instant("compile", fn=fun_name, phase=phase,
+                     seconds=round(seconds, 6))
 
     def instant(self, name: str, **args) -> None:
         self.buffer.instant(name, self._ts(), pid=self._pid_engine,
@@ -432,82 +473,6 @@ class ServingTracer:
         self.buffer.instant("withdrawn", self._ts(t),
                             pid=self._pid_requests, tid=req.request_id,
                             cat="request")
-
-    # --------------------------------------------- jitted-call attribution
-    def jit_call(self, kind: str, fn, args):
-        """Run ``fn(*args)`` timed and attributed.
-
-        The variant key is the tuple of top-level array argument shapes
-        and dtypes — exactly what can trigger a retrace once the params
-        pytree is fixed.  A new variant is costed (lower + compile +
-        ``hlo_analysis.cost_summary``) BEFORE the real call, both because
-        donation invalidates the buffers afterwards and so the compile
-        instant lands at the moment the stall happens.  The call blocks
-        on its outputs so the recorded wall time is the device time plus
-        dispatch, not just the async enqueue.
-        """
-        shapes = tuple((tuple(a.shape), str(a.dtype)) for a in args
-                       if hasattr(a, "shape"))
-        key = (kind, shapes)
-        rec = self._variants.get(key)
-        if rec is None:
-            n = self._kind_counts.get(kind, 0)
-            self._kind_counts[kind] = n + 1
-            rec = self._variants[key] = {
-                "kind": kind, "variant": f"{kind}#{n}",
-                "shapes": [list(s) for s, _ in shapes],
-                "calls": 0, "total_s": 0.0, "first_call_s": None,
-                "cost": self._variant_cost(fn, args)}
-            is_retrace = n > 0
-            (self.c_retraces if is_retrace else self.c_compiles).inc(
-                fn=kind, engine=self.name)
-            self.instant("retrace" if is_retrace else "compile",
-                         fn=kind, variant=rec["variant"],
-                         flops=rec["cost"].get("flops"),
-                         bytes_accessed=rec["cost"].get("bytes_accessed"))
-        t0 = self.clock()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        t1 = self.clock()
-        dt = t1 - t0
-        rec["calls"] += 1
-        rec["total_s"] += dt
-        if rec["first_call_s"] is None:
-            rec["first_call_s"] = dt     # includes compilation
-        ts0 = self._ts(t0)
-        self.buffer.complete(f"jit:{kind}", ts0, self._ts(t1) - ts0,
-                             pid=self._pid_engine, tid=_ENGINE_TID,
-                             cat="jit", args={"variant": rec["variant"]})
-        return out
-
-    @staticmethod
-    def _variant_cost(fn, args) -> dict:
-        """Compiled cost model of one variant (per-device in SPMD); {} when
-        the backend or function shape defeats AOT lowering."""
-        try:
-            from ..launch.hlo_analysis import cost_summary
-            return cost_summary(fn.lower(*args).compile())
-        except Exception:
-            return {}
-
-    def attribution(self) -> dict:
-        """Per-variant wall-clock and cost-model table, JSON-embeddable:
-        tok/s regressions decompose into compute (flops/bytes), schedule
-        (calls), and recompilation (variants, first_call_s)."""
-        out = {}
-        for rec in self._variants.values():
-            steady_calls = max(rec["calls"] - 1, 0)
-            steady_s = rec["total_s"] - (rec["first_call_s"] or 0.0)
-            out[rec["variant"]] = {
-                "kind": rec["kind"], "shapes": rec["shapes"],
-                "calls": rec["calls"], "total_s": rec["total_s"],
-                "first_call_s": rec["first_call_s"],
-                "steady_mean_s": (steady_s / steady_calls
-                                  if steady_calls else None),
-                "flops": rec["cost"].get("flops"),
-                "bytes_accessed": rec["cost"].get("bytes_accessed"),
-            }
-        return out
 
     # -------------------------------------------------------------- export
     def counters_text(self) -> str:

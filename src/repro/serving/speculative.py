@@ -166,12 +166,12 @@ class ModelDrafter:
     belongs to whichever request holds target slot i) and the engine's
     placement — draft params are committed with the same out-dim
     tensor-parallel shardings as the target's, so both models are
-    co-resident on one mesh.  Jitted draft calls are attributed as
-    ``draft_step``/``draft_decode`` variants in traces
-    (``trace_kind_prefix``).  Proposals are greedy (argmax): for a
-    deterministic proposer the leave-one-in verification in sampling.py
-    preserves the target distribution regardless, and greedy maximizes
-    acceptance for the low-temperature traffic speculation targets.
+    co-resident on one mesh.  Its calls, and its reads of the proposals,
+    run inside the engine's ``serve.draft`` span (serving/observe.py).
+    Proposals are greedy (argmax): for a deterministic proposer the
+    leave-one-in verification in sampling.py preserves the target
+    distribution regardless, and greedy maximizes acceptance for the
+    low-temperature traffic speculation targets.
     """
 
     def __init__(self, cfg, params, placement, *, n_slots: int,
@@ -184,7 +184,6 @@ class ModelDrafter:
             max_len=max_len, block_size=16, n_blocks=None,
             prefix_caching=False, paged_attn_backend=None,
             kv_dtype=kv_dtype)
-        self.adapter.trace_kind_prefix = "draft_"
         self.max_len = max_len
         # dpos[slot]: draft-arena positions holding the slot's TRUE
         # sequence prefix (the draft cursor); _from[slot]: the sequence
@@ -284,10 +283,6 @@ class Speculator:
                                         kv_dtype=kv_dtype)
         else:
             self.ngram = NGramProposer(spec.ngram)
-
-    def set_tracer(self, tracer) -> None:
-        if self.drafter is not None:
-            self.drafter.adapter.tracer = tracer
 
     def on_admit(self, slot: int) -> None:
         if self.drafter is not None:

@@ -1,20 +1,25 @@
 """Observability substrate tests.
 
-Two load-bearing properties:
+Three load-bearing properties:
 
 1. Disabled is free AND invisible: the default engine holds the
-   NULL_TRACER singleton, whose hooks return shared objects (no per-step
-   allocation), and a traced run emits exactly the same tokens as an
-   untraced one — for slot+paged layouts, dense+8:16-sparse params.
+   NULL_TRACER singleton, writes no buffer, and a traced run emits
+   exactly the same tokens as an untraced one — for slot+paged layouts,
+   dense+8:16-sparse params.
 2. The trace IS the metrics: every request's "request_summary" event in
    the written Perfetto trace restates its ``RequestMetrics`` exactly —
    including preemption/resume and prefix-cache-hit lifecycles.
+3. The engine's phase spans land in a profiler trace on the host thread,
+   nested as ``serving/observe.py`` documents, and the step programs
+   lower as ``jit_serve_step`` with the sparse linear's ops named.
 """
 import dataclasses
+import glob
 import json
 import math
 
 import jax
+import numpy as np
 import pytest
 
 from repro import configs
@@ -26,7 +31,7 @@ from repro.runtime.telemetry import (Counter, MetricsRegistry, TraceBuffer,
                                      validate_trace_events)
 from repro.serving import (NULL_TRACER, NullTracer, SamplingParams,
                            ServingEngine, ServingTracer, Status)
-from repro.serving.observe import NULL_SPAN
+from repro.serving.observe import span
 
 CFG = dataclasses.replace(configs.get_smoke("llama-paper"),
                           name="observe-test", n_layers=2, d_model=128,
@@ -54,6 +59,14 @@ def _prompts(n, length, seed=1):
     key = jax.random.PRNGKey(seed)
     return [t.tolist() for t in
             jax.random.randint(key, (n, length), 0, CFG.vocab)]
+
+
+def _step_compiles(engine) -> dict[str, int]:
+    """Executables each of the engine's step programs holds, by the phase
+    that dispatches it."""
+    a = engine.adapter
+    return {"serve.chunk": a._step_fn._cache_size(),
+            "serve.decode": a._decode_fn._cache_size()}
 
 
 def _run(params, prompts, gen, tracer=None, **kw):
@@ -91,26 +104,36 @@ def _check_lifecycle_agreement(tracer, reqs):
 # disabled tracing: free, and invisible in the token stream
 # --------------------------------------------------------------------------
 
-def test_null_tracer_is_default_and_allocation_free(dense_params):
+def test_null_tracer_is_default_and_allocation_free(dense_params,
+                                                    monkeypatch):
     engine = ServingEngine(CFG, dense_params, n_slots=2, max_len=32)
     assert engine.tracer is NULL_TRACER
-    assert engine.adapter.tracer is NULL_TRACER
-    # every hook returns a shared singleton or None — nothing per call
+    # every hook returns None and holds no state
     t = NullTracer()
     assert t.enabled is False
-    assert t.begin_step(3, 0.0) is NULL_SPAN
-    assert t.begin_phase("plan", tokens=7) is NULL_SPAN
     assert t.attach(engine) is t
-    for hook in (t.end_step, t.end_phase, t.instant, t.on_submit,
-                 t.on_admit, t.on_chunk, t.on_prefill_complete,
-                 t.on_preempt, t.on_finish, t.on_evict):
-        pass  # existence; no-arg-shape enforcement below via real run
-    assert t.end_phase() is None
+    assert t.end_step(engine, {}) is None
     assert t.instant("x", a=1) is None
-    # jit_call is a bare passthrough
-    assert t.jit_call("step", lambda a, b: a + b, (2, 3)) == 5
-    with NULL_SPAN:
-        pass  # usable as an inert context manager
+    assert not hasattr(t, "buffer")
+    # a span under the null tracer is the bare profiler annotation, which
+    # records nothing while no profile is being taken
+    sp = span("serve.step")
+    assert type(sp) is jax.profiler.TraceAnnotation
+    with sp:
+        pass
+    # an untraced run never writes a trace buffer or a counter
+    from repro.runtime import telemetry
+
+    def refuse(*a, **k):
+        raise AssertionError("the untraced engine wrote observability")
+    for cls, names in ((telemetry.TraceBuffer,
+                        ("complete", "instant", "counter")),
+                       (telemetry.Counter, ("inc",))):
+        for name in names:
+            monkeypatch.setattr(cls, name, refuse)
+    engine.submit(_prompts(1, 8)[0], SamplingParams(max_new_tokens=3))
+    engine.run()
+    assert engine.finished[0].status is Status.FINISHED
 
 
 @pytest.mark.parametrize("which", ["dense", "sparse"])
@@ -134,8 +157,13 @@ def test_traced_tokens_identical_and_trace_agrees(which, layout, tmp_path,
     tracer.write_trace(str(path))
     events = validate_trace_events(json.loads(path.read_text()))
     names = {e["name"] for e in events}
-    assert {"step", "plan", "decode", "emit", "queued", "prefill",
-            "request_summary"} <= names
+    assert {"serve.step", "serve.schedule", "serve.decode", "serve.emit",
+            "serve.sync", "queued", "prefill", "request_summary"} <= names
+    # the step span carries the step's stats
+    steps = [e for e in events if e["name"] == "serve.step"]
+    assert len(steps) == engine.n_steps
+    assert sum(e["args"]["decoded"] for e in steps) == \
+        sum(len(r.tokens) for r in reqs) - len(reqs)
     # per-step spans live in the engine process, lifecycles in requests
     assert any(e["pid"] == tracer._pid_engine for e in events)
     assert any(e["pid"] == tracer._pid_requests for e in events)
@@ -199,6 +227,9 @@ def test_prefix_cache_hit_lifecycle(dense_params):
 
 
 def test_counters_and_attribution(dense_params):
+    """Counters restate the run; compiles come from JAX's own compile
+    events, by compiled function, with no extra compile or blocking call
+    (the step programs all lower as ``jit_serve_step``)."""
     prompts = _prompts(3, 16)
     tracer = ServingTracer()
     engine, reqs = _run(dense_params, prompts, GEN, tracer=tracer,
@@ -212,36 +243,128 @@ def test_counters_and_attribution(dense_params):
     assert reg.counter("serving_requests_finished_total").get(
         status="finished", **lb) == len(reqs)
     assert reg.counter("serving_steps_total").get(**lb) == engine.n_steps
-    # jit attribution: at least the prefill-step and decode variants, each
-    # wall-clocked; compiles counted once per variant
-    attr = tracer.attribution()
-    kinds = {v["kind"] for v in attr.values()}
-    assert {"step", "decode"} <= kinds
-    for v in attr.values():
-        assert v["calls"] > 0 and v["total_s"] > 0
-        assert "flops" in v and "bytes_accessed" in v
-    n_variants = len(attr)
-    compiles = sum(reg.counter("serving_jit_compiles_total")
-                   .series().values())
-    retraces = sum(reg.counter("serving_jit_retraces_total")
-                   .series().values())
-    assert compiles + retraces == n_variants
+    # one compile per executable the step programs hold, under the phase
+    # that dispatched it: the chunk program's (batch, bucket) variants
+    # and the decode program
+    n_exec = _step_compiles(engine)
+    assert n_exec["serve.chunk"] >= 1 and n_exec["serve.decode"] == 1
+    compiles = reg.counter("serving_jit_compiles_total")
+    for phase, n in n_exec.items():
+        assert compiles.get(fn="jit(serve_step)", phase=phase,
+                            engine=tracer.name) == n
+    assert sum(compiles.series().values()) >= sum(n_exec.values())
+    instants = [e for e in tracer.buffer.events if e.get("name") == "compile"
+                and e["args"]["fn"] == "jit(serve_step)"]
+    assert sorted(e["args"]["phase"] for e in instants) == sorted(
+        p for p, n in n_exec.items() for _ in range(n))
+    assert "serving_jit_retraces_total" not in reg.snapshot()
     # prometheus text renders every family with HELP/TYPE headers
     text = tracer.counters_text()
     assert "# TYPE serving_tokens_decoded_total counter" in text
     assert f'engine="{tracer.name}"' in text
 
 
+# --------------------------------------------------------------------------
+# phase spans on the profiler's clock; the step programs' names
+# --------------------------------------------------------------------------
+
+def _profiled_serve_line(logdir) -> list[tuple[str, int, int]]:
+    """(name, start, end) in ns of every event on the host line that
+    holds the engine's ``serve.step`` spans."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True))[-1]
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events]
+            if any(n == "serve.step" for n, _, _ in evs):
+                return evs
+    return []
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_phase_spans_nest_in_a_profiler_trace(traced, sparse_params,
+                                              tmp_path):
+    """A few engine steps under ``jax.profiler``: every ``serve.*`` span
+    sits on one host line inside one ``serve.step``, ``serve.sync`` inside
+    ``serve.emit``, and no name can be taken for the paged-attention
+    kernel.  With a ServingTracer the same spans also reach its buffer."""
+    tracer = ServingTracer() if traced else None
+    engine = ServingEngine(CFG, sparse_params, n_slots=3, max_len=32,
+                           kv_layout="paged", block_size=8, tracer=tracer)
+    for p in _prompts(3, 16):
+        engine.submit(p, SamplingParams(max_new_tokens=GEN))
+    engine.step()                       # compiles before the profile
+    engine.step()
+    n_phases = len(tracer.buffer.events) if traced else 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            engine.step()
+    finally:
+        jax.profiler.stop_trace()
+    spans = [ev for ev in _profiled_serve_line(tmp_path)
+             if ev[0].startswith("serve.")]
+    names = [n for n, _, _ in spans]
+    assert {"serve.step", "serve.schedule", "serve.decode", "serve.emit",
+            "serve.sync"} <= set(names)
+    assert not any("paged_attention" in n for n in names)
+    assert names.count("serve.step") == 3
+
+    def within(name, s, e):
+        return [1 for n, ps, pe in spans if n == name and ps <= s
+                and e <= pe]
+    for n, s, e in spans:
+        if n != "serve.step":
+            assert len(within("serve.step", s, e)) == 1, n
+        if n == "serve.sync":
+            assert len(within("serve.emit", s, e)) == 1
+    if traced:
+        phases = [e["name"] for e in tracer.buffer.events[n_phases:]
+                  if e.get("cat") == "phase"]
+        assert sorted(phases) == sorted(names)
+
+
+def test_step_programs_lower_as_serve_step_with_sparse_linear(
+        sparse_params):
+    """Both step programs lower under one stable name, and the sparse
+    linear's ops carry its scope in their locations only (metadata)."""
+    engine = ServingEngine(CFG, sparse_params, n_slots=2, max_len=32,
+                           kv_layout="paged", block_size=8)
+    a = engine.adapter
+    assert a._step_fn.__name__ == a._decode_fn.__name__ == "serve_step"
+    lowered = a._decode_fn.lower(
+        a.params, *a._arena_args(), a.pool.block_tables, a.pool.pos,
+        jax.numpy.zeros((2, 1), np.int32))
+    text = lowered.as_text(debug_info=True)
+    assert "jit_serve_step" in text
+    assert "sparse_linear" in text
+    assert "sparse_linear" not in lowered.as_text()
+
+
 def test_shared_buffer_multi_engine(dense_params):
     """Two engines share one buffer+registry: disjoint pid pairs, distinct
-    engine labels (dense and sparse engines share family=dense)."""
+    engine labels (dense and sparse engines share family=dense), and each
+    engine's compile counter counts its own compiles only, though both
+    tracers are alive while the second engine compiles."""
     buf, reg = TraceBuffer(), MetricsRegistry()
     prompts = _prompts(2, 8, seed=5)
     t1 = ServingTracer(buffer=buf, registry=reg, name="a/slot")
     t2 = ServingTracer(buffer=buf, registry=reg, name="b/paged")
-    _run(dense_params, prompts, 4, tracer=t1, n_slots=2, max_len=16)
-    _run(dense_params, prompts, 4, tracer=t2, n_slots=2, max_len=16,
-         kv_layout="paged", block_size=8, n_blocks=8)
+    e1, _ = _run(dense_params, prompts, 4, tracer=t1, n_slots=2,
+                 max_len=16)
+    e2, _ = _run(dense_params, prompts, 4, tracer=t2, n_slots=2,
+                 max_len=16, kv_layout="paged", block_size=8, n_blocks=8)
+    compiles = reg.counter("serving_jit_compiles_total")
+    for tracer, engine in ((t1, e1), (t2, e2)):
+        n_exec = _step_compiles(engine)
+        for phase, n in n_exec.items():
+            assert compiles.get(fn="jit(serve_step)", phase=phase,
+                                engine=tracer.name) == n >= 1
+        own = [v for k, v in compiles.series().items()
+               if dict(k)["engine"] == tracer.name
+               and dict(k)["fn"] == "jit(serve_step)"]
+        assert sum(own) == sum(n_exec.values())
     assert {t1._pid_engine, t1._pid_requests}.isdisjoint(
         {t2._pid_engine, t2._pid_requests})
     decoded = reg.counter("serving_tokens_decoded_total")
