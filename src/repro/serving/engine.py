@@ -707,6 +707,9 @@ class ServingEngine:
                         active = self._decode_rows()
                 if not active:
                     return 0
+                if self.tracer.enabled:
+                    self.tracer.on_decode_kv(*self.pool.decode_kv_steps(
+                        self.cfg.n_heads, self.cfg.window))
             stats["decoded"] = len(active)
             tokens = jnp.asarray(self._last_token[:, None])
             logits = self.adapter.step_decode(tokens, active)

@@ -57,7 +57,9 @@ identity and the token-identity of traced vs untraced runs).  Pass a
    them (JAX's own ``backend_compile_duration`` events, heard through
    ``jax.monitoring`` while this tracer's engine is inside a step: no
    blocking call and no extra compile; the drafter's compiles fall under
-   ``serve.draft``), prefix-cache lookups/hit-tokens,
+   ``serve.draft``), prefix-cache lookups/hit-tokens, the paged-attention
+   kernel's live and skipped KV steps per decode step (the kernel's own
+   geometry over the positions mirror, ``paged_attention.kv_steps``),
    speculative draft/accept/emit token counters (plus a per-step
    acceptance-rate gauge — the live Divergent-Token probe), and per-step
    gauges (queue depth, running, pool occupancy, budget utilization) —
@@ -108,6 +110,9 @@ class NullTracer:
         pass
 
     def on_chunk(self, req, cursor, take):
+        pass
+
+    def on_decode_kv(self, walked, grid):
         pass
 
     def on_prefill_complete(self, req):
@@ -289,6 +294,11 @@ class ServingTracer:
         self.c_spec_emitted = r.counter(
             "serving_spec_tokens_emitted_total",
             "tokens emitted by speculative steps (accepted + bonus)")
+        self.c_kv_steps = r.counter(
+            "serving_paged_attn_kv_steps_total",
+            "KV steps of one layer's paged-attention kernel per decode "
+            "step (every layer walks the same): live ones it walks, "
+            "skipped ones past what each row can see")
         self.g_spec_accept = r.gauge(
             "serving_spec_acceptance_rate",
             "per-step draft acceptance rate (accepted / drafted)")
@@ -409,6 +419,13 @@ class ServingTracer:
         self.buffer.instant("chunk", self._ts(), pid=self._pid_requests,
                             tid=req.request_id, cat="request",
                             args={"cursor": cursor, "take": take})
+
+    def on_decode_kv(self, walked: int, grid: int) -> None:
+        """Count a decode step's paged-attention KV steps, from the
+        positions mirror (``PagedKVPool.decode_kv_steps``)."""
+        lb = self._labels()
+        self.c_kv_steps.inc(walked, kind="live", **lb)
+        self.c_kv_steps.inc(grid - walked, kind="skipped", **lb)
 
     def on_prefill_complete(self, req) -> None:
         t = self.clock()

@@ -19,16 +19,23 @@ Two implementations behind one entry point:
   index ``p`` exactly, so the mask carries over unchanged — XLA fuses the
   gather, so this is also the portable CPU/GPU path.
 - ``pallas``: a TPU kernel (interpret-mode fallback off-TPU) that never
-  materializes the gathered view.  The block table rides in as a
-  scalar-prefetch operand, the grid is ``(B, query tiles, nb)`` with
-  blocks innermost, and each step DMAs exactly one physical KV block —
-  the index map reads ``block_tables[b, j]`` — accumulating flash-style
-  (running max / sum / weighted value in VMEM scratch) over one query
-  tile of the row.  Query tiles (``_q_tile``) cap the q/out/accumulator
+  materializes the gathered view.  The block tables and cursors ride in
+  as scalar-prefetch operands and the arena stays in HBM.  The grid is
+  ``(B, query tiles)``; each grid step walks only the table blocks its
+  query tile can see (``visible_blocks``: up to its last query's
+  position, from its first query's window start), ``P`` blocks per walk
+  step (``kv_walk``: 256 tokens at decode, fewer for large chunk tiles),
+  each block DMA'd by table lookup into a double-buffered VMEM scratch
+  while the previous step computes, the next tile's first step included.
+  Query heads are grouped by their KV head against K and V as stored, and
+  the tile accumulates flash-style (running max / sum / weighted value in
+  VMEM scratch).  Query tiles (``_q_tile``) cap the q/out/accumulator
   tiles at ``_Q_ROWS`` query-head rows, so every model width and chunk
-  size fits scoped VMEM with the same kernel.  HBM traffic is therefore
-  proportional to the tokens a request has actually written, not to a
-  reserved ``max_len``.
+  size fits scoped VMEM with the same kernel.  Both the kernel's work and
+  its HBM traffic are therefore proportional to the tokens each query
+  tile can see, not to the table width or a reserved ``max_len``: a
+  decode call over rows of about 800 tokens in 2560-token tables walks
+  4 steps per row.  ``kv_steps`` counts the walk on the host.
 
 All paths mask with a finite ``-1e30`` (exp underflows to exactly 0.0
 against any real row max), so masked positions contribute exactly nothing
@@ -42,6 +49,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -50,9 +58,17 @@ from ...kernels import resolve_interpret
 _NEG_INF = -1e30
 
 # query x q-head rows of one grid step's q/out/accumulator tiles: 4096
-# rows of hd=128 keep them, double-buffered, inside the 16 MiB of scoped
-# VMEM on a v5e
+# rows of hd=128 take about 20 MiB of scoped VMEM on a v5e with their
+# running max and sum, double-buffered where they are pipelined
 _Q_ROWS = 4096
+
+# KV tokens one walk step covers at most (16 blocks of 16), and the VMEM
+# a step's KV buffers, their f32 upcasts and its f32 scores may take
+# (``kv_walk``): 256 tokens at decode, 128 for a 4096-row chunk tile
+_STEP_TOKENS = 256
+_STEP_VMEM = 8 << 20
+# the scoped VMEM the kernel may use (a v5e core has 128 MiB)
+_VMEM_LIMIT = 32 << 20
 
 
 def _default_backend() -> str:
@@ -108,56 +124,198 @@ def _q_tile(S: int, H: int) -> int:
     return S if S <= cap else math.gcd(S, cap)
 
 
-def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, *refs,
-                       bs, nb, n_rep, window, quantized):
+def kv_walk(S: int, H: int, KV: int, hd: int, bs: int, nb: int,
+            itemsize: int) -> tuple[int, int]:
+    """(query tile ``st``, table blocks per walk step ``P``) of the kernel
+    for a [.., S, H, hd] query over a ``KV``-head arena of ``bs``-token
+    blocks of ``itemsize``-byte elements, in tables ``nb`` wide.  A step
+    covers up to ``_STEP_TOKENS`` tokens, halved while its VMEM (the
+    double-buffered K and V blocks, their f32 upcasts, and the tile's f32
+    scores and probabilities) would pass ``_STEP_VMEM``."""
+    st = _q_tile(S, H)
+    per_token = 4 * KV * hd * itemsize + 2 * KV * hd * 4 + 2 * st * H * 4
+    P = max(min(_STEP_TOKENS // bs, nb), 1)
+    while P > 1 and P * bs * per_token > _STEP_VMEM:
+        P //= 2
+    return st, P
+
+
+def visible_blocks(cursor, qi, st: int, bs: int, nb: int,
+                   window: int | None, xp=np):
+    """First and last table block (inclusive) that query tile ``qi`` of a
+    row at ``cursor`` can see: its queries sit at ``cursor + qi*st ..
+    cursor + qi*st + st - 1`` and see positions up to their own,
+    window-limited.  ``xp`` is numpy on the host, ``jax.numpy`` inside the
+    kernel; both sides walk exactly these bounds.  Both stay inside the
+    table whatever the cursor, so no lookup leaves the row's entries."""
+    last = xp.clip((cursor + (qi + 1) * st - 1) // bs, 0, nb - 1)
+    if window is None:
+        return last * 0, last
+    first = xp.maximum(cursor + qi * st - window + 1, 0) // bs
+    return xp.minimum(first, last), last
+
+
+def kv_steps(cursor, S: int, H: int, KV: int, hd: int, bs: int, nb: int,
+             itemsize: int, window: int | None) -> tuple[int, int]:
+    """(walked, grid) KV steps of one kernel call over rows at ``cursor``
+    [B] (host numpy): the steps the kernel walks, and the
+    ``B x query tiles x ceil(nb / P)`` it would walk with no bound."""
+    st, P = kv_walk(S, H, KV, hd, bs, nb, itemsize)
+    cursor = np.asarray(cursor, np.int64)[:, None]
+    qi = np.arange(S // st)[None, :]
+    first, last = visible_blocks(cursor, qi, st, bs, nb, window)
+    walked = int(np.sum(last // P - first // P + 1))
+    return walked, cursor.shape[0] * (S // st) * -(-nb // P)
+
+
+def _scores(q, k_ref):
+    """[KV, R, T] f32 scores of q [KV, R, hd] against the [P, bs, KV, hd]
+    keys in ``k_ref``, each query head against its own KV head.  int8
+    keys are exact in bf16 (their scales multiply the scores); keys and
+    queries meet the MXU in their stored dtype when the two agree, else
+    in f32."""
+    k = k_ref[...]
+    k = jnp.swapaxes(k.reshape(-1, *k.shape[2:]), 0, 1)       # [KV, T, hd]
+    if k.dtype == jnp.int8:
+        k = k.astype(jnp.bfloat16)
+    if k.dtype != q.dtype:
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    return jnp.einsum("grd,gtd->grt", q, k,
+                      preferred_element_type=jnp.float32)
+
+
+def _weighted(p, v_ref):
+    """[KV, R, hd] f32: probabilities p [KV, R, T] times the [P, bs, KV,
+    hd] values in ``v_ref``, in f32."""
+    v = v_ref[...]
+    v = jnp.swapaxes(v.reshape(-1, *v.shape[2:]), 0, 1)       # [KV, T, hd]
+    return jnp.einsum("grt,gtd->grd", p, v.astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
+
+
+def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_hbm, v_hbm, *refs,
+                       bs, nb, P, st, n_rep, window, quantized, scale):
     if quantized:
-        # int8 arenas: per-position scale tiles ride the same block-table
-        # index map as the KV tiles and dequantize in-register, inside the
-        # online softmax — the gathered bf16 KV never exists in HBM
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        (ks_hbm, vs_hbm, o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf,
+         ks_buf, vs_buf, sems, slot_ref) = refs
+        hbm = (k_hbm, v_hbm, ks_hbm, vs_hbm)
+        bufs = (k_buf, v_buf, ks_buf, vs_buf)
     else:
-        o_ref, m_ref, l_ref, acc_ref = refs
-    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sems, slot_ref = refs
+        hbm, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
+    b, qi = pl.program_id(0), pl.program_id(1)
+    n_rows, n_qt = pl.num_programs(0), pl.num_programs(1)
+    T = P * bs
+    KV = k_buf.shape[3]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def bounds(b, qi):
+        return visible_blocks(cur_ref[b], qi, st, bs, nb, window, jnp)
 
-    S, hd = q_ref.shape[1], q_ref.shape[-1]
-    qf = q_ref[0].astype(jnp.float32) / math.sqrt(hd)         # [S, H, hd]
-    k = k_ref[0].astype(jnp.float32)                          # [bs, KV, hd]
-    v = v_ref[0].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0][..., None]                          # [bs, KV, 1]
-        v = v * vs_ref[0][..., None]
-    if n_rep > 1:
-        k = jnp.repeat(k, n_rep, axis=1)                      # [bs, H, hd]
-        v = jnp.repeat(v, n_rep, axis=1)
-    s = jnp.einsum("qhd,thd->hqt", qf, k)                     # [H, S, bs]
+    def dmas(b, qi, step, slot, method):
+        """Start or wait for the DMAs of walk step ``step`` of tile (b,
+        qi) into ``slot``: one per array for each of its P table blocks
+        that the tile can see.  A block it cannot see is never read."""
+        first, last = bounds(b, qi)
 
-    qpos = cur_ref[b] + qi * S + jax.lax.iota(jnp.int32, S)   # [S]
-    idx = j * bs + jax.lax.iota(jnp.int32, bs)                # [bs]
-    valid = idx[None, :] <= qpos[:, None]                     # [S, bs]
-    if window is not None:
-        valid &= idx[None, :] > qpos[:, None] - window
-    s = jnp.where(valid[None], s, _NEG_INF)
+        def block(p, carry):
+            j = step * P + p
+            blk = bt_ref[b * nb + jnp.minimum(j, nb - 1)]
 
-    m_prev, l_prev = m_ref[...], l_ref[...]                   # [H, S]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[..., None])                         # [H, S, bs]
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=2)
-    acc_ref[...] = (acc_ref[...] * alpha[..., None]
-                    + jnp.einsum("hqt,thd->hqd", p, v))
+            @pl.when((j >= first) & (j <= last))
+            def _():
+                for a, (src, buf) in enumerate(zip(hbm, bufs)):
+                    getattr(pltpu.make_async_copy(
+                        src.at[blk], buf.at[slot, p], sems.at[a, slot]),
+                        method)()
+            return carry
 
-    @pl.when(j == nb - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)                # [H, S]
-        out = acc_ref[...] / denom[..., None]                 # [H, S, hd]
-        o_ref[...] = out.transpose(1, 0, 2)[None].astype(o_ref.dtype)
+        jax.lax.fori_loop(0, P, block, 0)
+
+    first, last = bounds(b, qi)
+    s0, s1 = first // P, last // P
+
+    @pl.when((b == 0) & (qi == 0))
+    def _prologue():
+        # a block a step does not fetch keeps what its buffer held: zeros
+        # or KV that some tile could see, finite either way, so the masked
+        # probabilities (exactly 0) never multiply a NaN
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        dmas(b, qi, s0, 0, "start")
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0]                                              # [KV, R, hd]
+    R = q.shape[1]
+    # row r of a KV head's group is query r // n_rep of the tile
+    qpos = (cur_ref[b] + qi * st
+            + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // n_rep)
+    # the tile after this one in grid order, whose first step is in
+    # flight during this tile's last
+    nxt_b = jnp.where(qi + 1 < n_qt, b, b + 1)
+    nxt_qi = jnp.where(qi + 1 < n_qt, qi + 1, 0)
+    nxt_s0 = bounds(jnp.minimum(nxt_b, n_rows - 1), nxt_qi)[0] // P
+
+    def step_scales(buf, slot):
+        """[KV, T] scales of the step's tokens from its P rows of
+        ``bs * KV`` (lane-padded) scales, token-major: each row repeats
+        for its bs tokens, each token keeps its own KV lanes, and a 0/1
+        matmul moves them into place.  The MXU takes bf16, so the f32
+        scales go as three bf16 parts, which sum back exactly."""
+        W = buf.shape[-1]
+        rows = jnp.broadcast_to(buf[slot][:, None, :], (P, bs, W))
+        rows = rows.reshape(T, W)                             # [T, W]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (T, W), 1)
+        t = jax.lax.broadcasted_iota(jnp.int32, (T, W), 0) % bs
+        rows = jnp.where(lane // KV == t, rows, 0.0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (KV, W), 1)
+        g = jax.lax.broadcasted_iota(jnp.int32, (KV, W), 0)
+        pick = jnp.where((lane % KV == g) & (lane < bs * KV), 1.0, 0.0)
+        pick = pick.astype(jnp.bfloat16)
+        out = 0.0
+        for _ in range(3):
+            part = rows.astype(jnp.bfloat16)
+            rows = rows - part.astype(jnp.float32)
+            out = out + jnp.einsum("gl,tl->gt", pick, part,
+                                   preferred_element_type=jnp.float32)
+        return out
+
+    def body(step, slot):
+        @pl.when((step < s1) | (nxt_b < n_rows))
+        def _():
+            more = step < s1
+            dmas(jnp.where(more, b, nxt_b), jnp.where(more, qi, nxt_qi),
+                 jnp.where(more, step + 1, nxt_s0), 1 - slot, "start")
+
+        dmas(b, qi, step, slot, "wait")
+        s = _scores(q, k_buf.at[slot]) * scale                # [KV, R, T]
+        if quantized:
+            s = s * step_scales(ks_buf, slot)[:, None, :]
+        tok = step * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        valid = tok <= qpos                                   # [R, T]
+        if window is not None:
+            valid &= tok > qpos - window
+        if nb % P:
+            valid &= tok < nb * bs          # the last step runs past the table
+        s = jnp.where(valid[None], s, _NEG_INF)
+
+        m_prev = m_ref[...]                                   # [KV, R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if quantized:
+            p = p * step_scales(vs_buf, slot)[:, None, :]
+        acc_ref[...] = acc_ref[...] * alpha + _weighted(p, v_buf.at[slot])
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(s0, s1 + 1, body, slot_ref[0])
+    denom = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -165,53 +323,66 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
                            *, window: int | None = None,
                            interpret: bool | None = None,
                            k_scale=None, v_scale=None) -> jax.Array:
-    """Same contract as ``paged_attention_ref``; one grid step per
-    (row, query tile, block), KV blocks DMA'd by table lookup via scalar
-    prefetch.  Long chunks walk query tiles (``_q_tile``).
-    ``k_scale``/``v_scale`` [n_blocks, bs, KV] ride as two extra operands
-    whose index map is the same block-table lookup; the kernel
-    dequantizes in-register.  ``interpret=None`` compiles on a TPU backend
-    and interprets elsewhere."""
+    """Same contract as ``paged_attention_ref``.  One grid step per (row,
+    query tile); each walks only the table blocks the tile can see
+    (``visible_blocks``), ``P`` blocks per walk step (``kv_walk``), DMA'd
+    by table lookup from the arena left in HBM into a double-buffered
+    VMEM scratch, the next step's copies in flight during this step's
+    compute.  Query heads are grouped by their KV head, so K and V are
+    never repeated.  ``k_scale``/``v_scale`` [n_blocks, bs, KV] come by
+    the same lookups, one lane-dense row per block; the kernel
+    dequantizes in-register.  ``interpret=None`` compiles on a TPU
+    backend and interprets elsewhere."""
     B, S, H, hd = q.shape
     n_blocks, bs, KV, _ = k_arena.shape
     nb = block_tables.shape[1]
+    n_rep = H // KV
     quantized = k_scale is not None
 
-    st = _q_tile(S, H)
-    kern = functools.partial(_paged_attn_kernel, bs=bs, nb=nb,
-                             n_rep=H // KV, window=window,
-                             quantized=quantized)
-    q_spec = pl.BlockSpec((1, st, H, hd),
-                          lambda b, i, j, bt, cu: (b, i, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, KV, hd),
-                           lambda b, i, j, bt, cu: (bt[b, j], 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, bs, KV),
-                           lambda b, i, j, bt, cu: (bt[b, j], 0, 0))
-
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [q, k_arena, v_arena]
+    st, P = kv_walk(S, H, KV, hd, bs, nb, k_arena.dtype.itemsize)
+    R = st * n_rep
+    kern = functools.partial(_paged_attn_kernel, bs=bs, nb=nb, P=P, st=st,
+                             n_rep=n_rep, window=window, quantized=quantized,
+                             scale=1.0 / math.sqrt(hd))
+    # [B, KV, S * n_rep, hd]: the query heads of each KV head, query-major
+    qg = (q.reshape(B, S, KV, n_rep, hd).transpose(0, 2, 1, 3, 4)
+          .reshape(B, KV, S * n_rep, hd))
+    q_spec = pl.BlockSpec((1, KV, R, hd), lambda b, i, bt, cu: (b, 0, i, 0))
+    arenas = [k_arena, v_arena]
     if quantized:
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        # the chip's DMAs slice only lane-dense rows: one row of bs * KV
+        # scales per block, padded to whole lanes
+        w = -(-bs * KV // 128) * 128
+        arenas += [jnp.pad(s.reshape(n_blocks, bs * KV),
+                           ((0, 0), (0, w - bs * KV)))
+                   for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                  # block tables, cursors
-        grid=(B, S // st, nb),
-        in_specs=in_specs,
+        grid=(B, S // st),
+        in_specs=[q_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * len(
+            arenas),
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((H, st), jnp.float32),      # running max
-            pltpu.VMEM((H, st), jnp.float32),      # running sum
-            pltpu.VMEM((H, st, hd), jnp.float32),  # weighted-value acc
+            pltpu.VMEM((KV, R, 1), jnp.float32),     # running max
+            pltpu.VMEM((KV, R, 1), jnp.float32),     # running sum
+            pltpu.VMEM((KV, R, hd), jnp.float32),    # weighted-value acc
+        ] + [pltpu.VMEM((2, P) + a.shape[1:], a.dtype) for a in arenas] + [
+            pltpu.SemaphoreType.DMA((len(arenas), 2)),
+            pltpu.SMEM((1,), jnp.int32),             # slot of the next step
         ],
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=resolve_interpret(interpret),
-    )(block_tables.astype(jnp.int32), cursor.astype(jnp.int32),
-      *operands)
-    return out
+    )(block_tables.astype(jnp.int32).reshape(-1), cursor.astype(jnp.int32),
+      qg, *arenas)
+    return (out.reshape(B, KV, S, n_rep, hd).transpose(0, 2, 1, 3, 4)
+            .reshape(B, S, H, hd))
 
 
 # --------------------------------------------------------------------------

@@ -380,6 +380,18 @@ class PagedKVPool:
                     self._bt_np[row, bi] = fresh
                     self._bt_dirty = True
 
+    def decode_kv_steps(self, n_heads: int,
+                        window: int | None) -> tuple[int, int]:
+        """(walked, grid) KV steps of one layer's paged-attention kernel
+        in the decode step about to run, from the positions mirror: every
+        row attends at its position over its table (``kv_steps``, the
+        kernel's own geometry)."""
+        from .paged_attention import kv_steps
+        _, _, bs, KV, hd = self.blocks.k.shape
+        return kv_steps(self._pos_np, 1, n_heads, KV, hd, bs,
+                        self.max_blocks_per_row,
+                        self.blocks.k.dtype.itemsize, window)
+
     def fork(self, row: int) -> int:
         """Fork ``row`` copy-on-write into a fresh row: the new row's
         table shares every parent block read-only (incref only — no KV

@@ -264,6 +264,58 @@ def test_counters_and_attribution(dense_params):
     assert f'engine="{tracer.name}"' in text
 
 
+def _kv_steps_by_visibility(cursor, nb, bs, P, window=None):
+    """KV steps of P*bs tokens that hold a position each row's decode
+    query sees (position <= cursor, window-limited, inside the table)."""
+    pos = np.arange(nb * bs)
+    walked = 0
+    for c in cursor:
+        seen = pos <= c
+        if window is not None:
+            seen &= pos > c - window
+        walked += len(np.unique(pos[seen] // (P * bs)))
+    return walked
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_paged_attn_kv_steps_counter(dense_params, window):
+    """Hand-built decode batch over tables two walk steps wide: rows whose
+    positions cross the first step's edge, a short row and a free row.
+    The counter's live and skipped KV steps equal, step by step, what
+    the kernel walks at the positions it is handed: the steps holding a
+    visible position, of ``rows x ceil(nb / P)``."""
+    from repro.serving.paged.paged_attention import kv_walk
+    cfg = dataclasses.replace(CFG, window=window)
+    params = get_model(cfg).init(jax.random.PRNGKey(0))
+    tracer = ServingTracer()
+    engine = ServingEngine(cfg, params, tracer=tracer, n_slots=4,
+                           max_len=320, kv_layout="paged", block_size=8)
+    pool = engine.pool
+    nb, bs = pool.max_blocks_per_row, pool.block_size
+    _, P = kv_walk(1, cfg.n_heads, cfg.n_kv_heads, cfg.hd, bs, nb,
+                   pool.k.dtype.itemsize)
+    assert P * bs <= 256 < nb * bs and nb % P
+    handed = []
+    decode = engine.adapter.step_decode
+
+    def record(tokens, active):
+        handed.append(np.asarray(pool.pos))
+        return decode(tokens, active)
+
+    engine.adapter.step_decode = record
+    for n in (250, 10, 255):
+        engine.submit(_prompts(1, n)[0], SamplingParams(max_new_tokens=8))
+    engine.run()
+    assert handed and max(p.max() for p in handed) > 256
+    lb = dict(engine=tracer.name, family="dense")
+    steps = tracer.registry.counter("serving_paged_attn_kv_steps_total")
+    live = sum(_kv_steps_by_visibility(p, nb, bs, P, window)
+               for p in handed)
+    grid = sum(len(p) * -(-nb // P) for p in handed)
+    assert steps.get(kind="live", **lb) == live
+    assert steps.get(kind="skipped", **lb) == grid - live > 0
+
+
 # --------------------------------------------------------------------------
 # phase spans on the profiler's clock; the step programs' names
 # --------------------------------------------------------------------------

@@ -216,6 +216,55 @@ def _attn_case(seed=0, B=3, S=1, H=4, KV=2, hd=16, bs=8, n_blocks=10, nb=4):
     return q, ka, va, bt, cursor
 
 
+TRASH = 0
+
+
+def _wide_case(S=1, H=4, KV=2, hd=16, bs=8, nb=37, seed=0):
+    """Rows on the kernel's edges in tables wider than one walk step that
+    its ``P`` does not divide: cursors 0, bs-1, bs, one either side of
+    P*bs, a row whose last query sits at the table's last position, and
+    two free rows (cursor 0, all-trash tables).  A row's table entries
+    past its last written block are the trash block, as the pool leaves
+    them.  Returns the case and each row's count of visible tokens."""
+    from repro.serving.paged.paged_attention import kv_walk
+    _, P = kv_walk(S, H, KV, hd, bs, nb, 4)
+    assert nb % P and P * bs + 1 + S <= nb * bs
+    cursor = np.array([0, bs - 1, bs, P * bs - 1, P * bs + 1,
+                       nb * bs - S, 0, 0], np.int32)
+    free = [6, 7]
+    B = len(cursor)
+    perm = np.random.default_rng(seed).permutation(
+        np.arange(1, 1 + B * nb))
+    bt = np.full((B, nb), TRASH, np.int32)
+    for b in range(B):
+        if b not in free:
+            n = -(-(cursor[b] + S) // bs)
+            bt[b, :n] = perm[b * nb:b * nb + n]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+    shape = (1 + B * nb, bs, KV, hd)
+    ka = jax.random.normal(ks[1], shape, jnp.float32)
+    va = jax.random.normal(ks[2], shape, jnp.float32)
+    return (q, ka, va, jnp.asarray(bt), jnp.asarray(cursor)), cursor + S
+
+
+def _quantize(a):
+    """int8 values and per-position f32 scales of an f32 arena."""
+    sc = jnp.max(jnp.abs(a), axis=-1) / 127.0
+    return jnp.round(a / sc[..., None]).astype(jnp.int8), sc
+
+
+def _case(which, S, H=4, KV=2):
+    """(q, k, v, tables, cursor, k_scale, v_scale) of a named case."""
+    if which == "narrow":
+        return _attn_case(S=S, H=H, KV=KV) + (None, None)
+    q, ka, va, bt, cursor = _wide_case(S=S, H=H, KV=KV)[0]
+    if which == "wide":
+        return q, ka, va, bt, cursor, None, None
+    (ka, ks), (va, vs) = _quantize(ka), _quantize(va)
+    return q, ka, va, bt, cursor, ks, vs
+
+
 def test_paged_attention_ref_matches_contiguous():
     q, ka, va, bt, cursor = _attn_case()
     ref = paged_attention_ref(q, ka, va, bt, cursor)
@@ -243,10 +292,39 @@ def test_paged_attention_ref_chunk_matches_masked_contiguous():
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ctg))
 
 
+@pytest.mark.parametrize("case", ["narrow", "wide", "wide-int8"])
 @pytest.mark.parametrize("S", [1, 5])
 @pytest.mark.parametrize("window", [None, 10])
-def test_paged_attention_pallas_matches_ref(window, S):
-    q, ka, va, bt, cursor = _attn_case(S=S)
+def test_paged_attention_pallas_matches_ref(window, S, case):
+    """The kernel against the gather reference: three-row tables within
+    one walk step, and wide tables (``_wide_case``) with rows on every
+    edge of the walk, f32 and int8 arenas; the window makes
+    later rows start their walk past block 0."""
+    q, ka, va, bt, cursor, ks, vs = _case(case, S)
+    ref = paged_attention_ref(q, ka, va, bt, cursor, window=window,
+                              k_scale=ks, v_scale=vs)
+    pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
+                                 interpret=True, k_scale=ks, v_scale=vs)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["narrow", "wide"])
+@pytest.mark.parametrize("window", [None, 10])
+@pytest.mark.parametrize("S,H,KV", [(8, 4, 4), (8, 8, 4), (4, 8, 4),
+                                    (8, 8, 2)])
+def test_paged_attention_pallas_query_tiled_matches_ref(S, H, KV, window,
+                                                        case, monkeypatch):
+    """Chunks longer than one query tile walk query tiles, each at its own
+    position offset and over its own visible blocks, for MHA and GQA head
+    groupings (1, 2 and 4 query heads per KV head); forced to small tiles
+    here by shrinking the row budget (the chip's budget splits 512-token
+    chunks)."""
+    import importlib
+    pa = importlib.import_module("repro.serving.paged.paged_attention")
+    monkeypatch.setattr(pa, "_Q_ROWS", 8)
+    q, ka, va, bt, cursor, _, _ = _case(case, S, H=H, KV=KV)
+    assert pa._q_tile(S, H) < S
     ref = paged_attention_ref(q, ka, va, bt, cursor, window=window)
     pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
                                  interpret=True)
@@ -254,23 +332,30 @@ def test_paged_attention_pallas_matches_ref(window, S):
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("window", [None, 10])
-@pytest.mark.parametrize("S,H,KV", [(8, 4, 4), (8, 8, 4), (4, 8, 4),
-                                    (8, 8, 2)])
-def test_paged_attention_pallas_query_tiled_matches_ref(S, H, KV, window,
-                                                        monkeypatch):
-    """Chunks longer than one query tile walk query tiles, each at its own
-    position offset, for MHA and GQA head groupings; forced to small tiles
-    here by shrinking the row budget (the chip's budget splits 512-token
-    chunks)."""
-    import importlib
-    pa = importlib.import_module("repro.serving.paged.paged_attention")
-    monkeypatch.setattr(pa, "_Q_ROWS", 8)
-    q, ka, va, bt, cursor = _attn_case(S=S, H=H, KV=KV)
-    assert pa._q_tile(S, H) < S
+@pytest.mark.parametrize("S,window", [(1, None), (5, None), (5, 10)])
+def test_paged_attention_pallas_never_reads_past_visible(S, window):
+    """Every table entry past a row's visible length points at a block
+    no row can see, and every such block is NaN: the kernel must neither
+    fetch nor weigh them (a masked probability of 0 times a NaN value is
+    NaN), as one that walks the whole table does.  The reference runs on
+    the same tables with those blocks finite."""
+    (q, ka, va, bt, cursor), n_visible = _wide_case(S=S)
+    bs = ka.shape[1]
+    bt = np.asarray(bt).copy()
+    n_blocks = -(-n_visible // bs)
+    seen = np.zeros(ka.shape[0], bool)
+    for b, n in enumerate(n_blocks):
+        seen[bt[b, :n]] = True
+    unseen = np.flatnonzero(~seen)
+    for b, n in enumerate(n_blocks):
+        bt[b, n:] = unseen[np.arange(bt.shape[1] - n) % len(unseen)]
+    bt = jnp.asarray(bt)
+    hidden = jnp.asarray(~seen)[:, None, None, None]
     ref = paged_attention_ref(q, ka, va, bt, cursor, window=window)
-    pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
-                                 interpret=True)
+    pal = paged_attention_pallas(q, jnp.where(hidden, jnp.nan, ka),
+                                 jnp.where(hidden, jnp.nan, va), bt, cursor,
+                                 window=window, interpret=True)
+    assert np.isfinite(np.asarray(pal)).all()
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
