@@ -25,10 +25,12 @@ the per-request cursor as a length mask.  Nothing ever gathers a copy of
 the already-written prefix, so a prefill chunk's HBM traffic is
 independent of how much prefix the request has written — O(P) total over
 a P-token prompt instead of the O(P^2/budget) the old gather-based
-chunk path paid.  ``decode_lockstep`` and ``block_decode`` are thin
-adapters over the same primitive for the legacy lock-step loop (and
-zamba's shared-attention block), so there is exactly one masking /
-RoPE-offset / write implementation.
+chunk path paid.  ``decode_lockstep`` is a thin adapter over the same
+primitive for the legacy lock-step loop, and zamba's shared-attention
+block runs ``_block_step`` itself, so there is exactly one masking /
+RoPE-offset / write implementation.  The arena is never copied whole:
+it rides the layer loop as carried state and each layer writes into it
+at its own index.
 
 Speculative verify is the same entry with q_len = k+1: the engine feeds
 ``[last_token, d1..dk]`` at cursor = written-prefix length and reads a
@@ -186,77 +188,67 @@ def _pool_positions(cursor, S: int, cfg):
     return base
 
 
-def attend_over_pool(q, pool_view, *, cursor=None, q_offset=None,
-                     window: int | None = None, backend: str | None = None):
+def attend_over_pool(q, pool_view, layer, *, window: int | None = None,
+                     backend: str | None = None):
     """THE serving attention primitive: ``q`` [B, S, H, hd] attends
-    directly against a KV pool arena — slot rows or paged blocks — with
-    the per-request cursor as a length mask.
+    directly against layer ``layer`` of a KV pool arena — slot rows or
+    paged blocks — with the per-request cursor as a length mask.
 
-    ``pool_view`` is a per-layer ``SlotPoolView`` / ``PagedPoolView``
+    ``pool_view`` is a ``SlotPoolView`` / ``PagedPoolView``
     (serving/cache_pool.py, serving/paged/pool.py) whose ``k``/``v`` hold
-    ONE layer's arena slice and whose addressing fields say where each
+    the whole [L, ...] arenas and whose addressing fields say where each
     batch lane's sequence lives.  Query i of lane b sits at absolute
-    position ``q_offset[b] + i`` and sees arena positions
-    ``j <= q_offset[b] + i`` (window-limited); ``q_offset`` defaults to
-    ``cursor`` (both default to ``pool_view.cursor``), which is exactly
-    right when the step's fresh KV was scattered at the cursor before
-    attending — causality then hides this step's not-yet-visible writes,
-    stale tokens of previous slot/block occupants, and padding, so
-    chunked prefill (S = chunk), one-shot prefill (S = prompt, cursor =
-    0), and fused decode (S = 1) are all the same computation.
+    position ``cursor[b] + i`` and sees arena positions ``j <= cursor[b]
+    + i`` (window-limited), which is exactly right because the step's
+    fresh KV was scattered at the cursor before attending — causality
+    then hides this step's not-yet-visible writes, stale tokens of
+    previous slot/block occupants, and padding, so chunked prefill (S =
+    chunk), one-shot prefill (S = prompt, cursor = 0), and fused decode
+    (S = 1) are all the same computation.
 
     Never materializes gathered prefix context: per-step prefix HBM
     traffic is bounded by the arena rows/blocks touched, independent of
     how much prefix each lane has already written.
     """
-    cursor = pool_view.cursor if cursor is None else cursor
-    q_offset = cursor if q_offset is None else q_offset
-    if pool_view.block_tables is not None:
+    pv = pool_view
+    if pv.block_tables is not None:
         from ..serving.paged.paged_attention import paged_attention
-        return paged_attention(q, pool_view.k, pool_view.v,
-                               pool_view.block_tables, q_offset,
-                               window=window, backend=backend,
-                               k_scale=pool_view.k_scale,
-                               v_scale=pool_view.v_scale)
-    k_rows, v_rows = pool_view.lane_kv(pool_view.k, pool_view.v)
+        return paged_attention(q, pv.k, pv.v, pv.block_tables, pv.cursor,
+                               layer=layer, window=window, backend=backend,
+                               k_scale=pv.k_scale, v_scale=pv.v_scale)
+    k_rows, v_rows = pv.lane_kv(pv.k, pv.v, layer)
     ks = vs = None
-    if pool_view.k_scale is not None:
-        ks, vs = pool_view.lane_kv(pool_view.k_scale, pool_view.v_scale)
-    return attend_length_masked(q, k_rows, v_rows, q_offset, window=window,
+    if pv.k_scale is not None:
+        ks, vs = pv.lane_kv(pv.k_scale, pv.v_scale, layer)
+    return attend_length_masked(q, k_rows, v_rows, pv.cursor, window=window,
                                 k_scale=ks, v_scale=vs)
 
 
-def _block_step(lp, x, k_l, v_l, view, positions, cfg, attn_backend,
-                ks_l=None, vs_l=None):
+def _arenas(view) -> tuple:
+    """The arenas a pool view carries: (k, v, k_scale, v_scale), the
+    scales None for a bf16 arena."""
+    return view.k, view.v, view.k_scale, view.v_scale
+
+
+def _block_step(lp, x, view, layer, positions, cfg, attn_backend):
     """One block of the unified step: project q/k/v at the lane cursor
-    positions, scatter the fresh KV into the layer's arena slice (in
-    place under donation), and attend over the pool.  Returns
-    (y, k_l, v_l[, ks_l, vs_l]) with the updated arena slices.  With
-    scale slices (int8 arena) the fresh KV is quantized on scatter and
-    attention dequantizes in place — the quantized path's extra state
-    is just the two [.., KV] scale slices riding alongside."""
+    positions, scatter the fresh KV into layer ``layer`` of the view's
+    whole arenas (in place under donation; an int8 arena quantizes on
+    scatter), and attend over the pool.  Returns (y, view) with the view
+    holding the updated arenas."""
     from ..parallel import policy as pol
     B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = _project_qkv(lp, h, cfg, positions)
     q = pol.shard(q, ("fsdp", None, "model", None))
-    if ks_l is not None:
-        k_l, v_l, ks_l, vs_l = view.write_layer_quantized(
-            k_l, v_l, ks_l, vs_l, k, v)
-        attn = attend_over_pool(
-            q, dataclasses.replace(view, k=k_l, v=v_l, k_scale=ks_l,
-                                   v_scale=vs_l),
-            window=cfg.window, backend=attn_backend)
-    else:
-        k_l, v_l = view.write_layer(k_l, v_l, k, v)
-        attn = attend_over_pool(q, dataclasses.replace(view, k=k_l, v=v_l),
-                                window=cfg.window, backend=attn_backend)
+    with jax.named_scope("kv_write"):
+        view = view.write_layer(layer, k, v)
+    attn = attend_over_pool(q, view, layer, window=cfg.window,
+                            backend=attn_backend)
     x = x + linear(lp["wo"], attn.reshape(B, S, -1))
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     x = x + _mlp(lp, h, cfg)
-    if ks_l is not None:
-        return x, k_l, v_l, ks_l, vs_l
-    return x, k_l, v_l
+    return x, view
 
 
 def unified_step(params, view, batch, cfg, *, attn_backend=None,
@@ -266,18 +258,19 @@ def unified_step(params, view, batch, cfg, *, attn_backend=None,
 
     ``batch["tokens"]`` [B, S] are the next S tokens of each lane,
     starting at ``view.cursor`` (per-lane RoPE/mask offset — chunk token
-    i sits at absolute position cursor + i).  Fresh KV is written into
-    the view's arenas layer by layer (the engine donates them, so the
-    multi-GB buffers update in place), and attention reads the arena
-    directly with the cursor as a length mask.  Covers every serving
-    shape: S = prompt & cursor = 0 is one-shot prefill, S = chunk is
-    chunked prefill (numerically the one-shot prefill it replaces), and
-    S = 1 over all lanes is the fused decode.
+    i sits at absolute position cursor + i).  The whole [L, ...] arenas
+    ride the layer loop as carried state: each layer scatters its fresh
+    KV at (layer, slot) and attention reads the arena directly with the
+    cursor as a length mask.  The engine donates the arenas, so the
+    multi-GB buffers update in place; no layer of them is sliced out or
+    stacked back.  Covers every serving shape: S = prompt & cursor = 0 is
+    one-shot prefill, S = chunk is chunked prefill (numerically the
+    one-shot prefill it replaces), and S = 1 over all lanes is the fused
+    decode.
 
     Returns (logits [B, S, V], (k, v)) — the updated [L, ...] arenas —
     or (logits, (k, v, k_scale, v_scale)) when the view carries an int8
-    arena's scale tensors (they join the per-layer scan as two more
-    donated-through leaves).
+    arena's scale tensors.
     """
     from ..parallel import policy as pol
     tokens = batch["tokens"]
@@ -285,65 +278,30 @@ def unified_step(params, view, batch, cfg, *, attn_backend=None,
     x = jnp.take(params["embed"], tokens, axis=0)
     positions = _pool_positions(view.cursor, S, cfg)
     x = pol.shard(x, ("fsdp", None, None))
-    quantized = view.k_scale is not None
 
+    def layer_step(x, arenas, lp, i):
+        k, v, ks, vs = arenas
+        x, out = _block_step(lp, x, dataclasses.replace(
+            view, k=k, v=v, k_scale=ks, v_scale=vs), i, positions, cfg,
+            attn_backend)
+        return x, _arenas(out)
+
+    arenas = _arenas(view)
     if unroll:
-        ks, vs, kss, vss = [], [], [], []
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda p: p[i], params["layers"])
-            if quantized:
-                x, k_l, v_l, ks_l, vs_l = _block_step(
-                    lp, x, view.k[i], view.v[i], view, positions, cfg,
-                    attn_backend, view.k_scale[i], view.v_scale[i])
-                kss.append(ks_l)
-                vss.append(vs_l)
-            else:
-                x, k_l, v_l = _block_step(lp, x, view.k[i], view.v[i], view,
-                                          positions, cfg, attn_backend)
-            ks.append(k_l)
-            vs.append(v_l)
-        k, v = jnp.stack(ks), jnp.stack(vs)
-        if quantized:
-            ksc, vsc = jnp.stack(kss), jnp.stack(vss)
-    elif quantized:
-        def body(h, xs):
-            lp, k_l, v_l, ks_l, vs_l = xs
-            h, k_l, v_l, ks_l, vs_l = _block_step(
-                lp, h, k_l, v_l, view, positions, cfg, attn_backend,
-                ks_l, vs_l)
-            return h, (k_l, v_l, ks_l, vs_l)
-        x, (k, v, ksc, vsc) = jax.lax.scan(
-            body, x, (params["layers"], view.k, view.v, view.k_scale,
-                      view.v_scale))
+            x, arenas = layer_step(x, arenas, lp, i)
     else:
-        def body(h, xs):
-            lp, k_l, v_l = xs
-            h, k_l, v_l = _block_step(lp, h, k_l, v_l, view, positions,
-                                      cfg, attn_backend)
-            return h, (k_l, v_l)
-        x, (k, v) = jax.lax.scan(body, x, (params["layers"], view.k, view.v))
+        def body(carry, xs):
+            return layer_step(*carry, *xs), None
+        (x, arenas), _ = jax.lax.scan(
+            body, (x, arenas),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = pol.shard(linear(head, x), ("fsdp", None, "model"))
-    if quantized:
-        return logits, (k, v, ksc, vsc)
-    return logits, (k, v)
-
-
-def block_decode(lp, x, k_cache, v_cache, pos, cfg):
-    """One-token block over contiguous caches. x: [B,1,d]; caches
-    [B,Smax,KV,hd]; ``pos`` a scalar (lock-step batch) or [B] vector of
-    filled lengths.  A thin adapter over the unified in-place block for
-    lock-step callers outside the engine (zamba's shared-attention
-    block)."""
-    from ..serving.cache_pool import SlotPoolView
-    B = x.shape[0]
-    cursor = _cursor_vec(pos, B)
-    view = SlotPoolView(k=None, v=None, rows=None, cursor=cursor,
-                        n_new=jnp.ones((B,), jnp.int32))
-    return _block_step(lp, x, k_cache, v_cache, view,
-                       _pool_positions(cursor, 1, cfg), cfg, None)
+    return logits, tuple(a for a in arenas if a is not None)
 
 
 def decode_lockstep(params, caches, batch, cfg, unroll: bool = False):

@@ -216,16 +216,16 @@ def unified_step(params, view, batch, cfg, *, attn_backend=None,
     positions = tfm._pool_positions(view.cursor, S, cfg)
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
 
-    def block(lp, h, k_l, v_l, ck_l, cv_l):
+    def block(lp, h, k, v, i, ck_l, cv_l):
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         q = linear(lp["wq"], hn).reshape(B, S, H, hd)
-        k = linear(lp["wk"], hn).reshape(B, S, KV, hd)
-        v = linear(lp["wv"], hn).reshape(B, S, KV, hd)
+        k_new = linear(lp["wk"], hn).reshape(B, S, KV, hd)
+        v_new = linear(lp["wv"], hn).reshape(B, S, KV, hd)
         q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        k_l, v_l = view.write_layer(k_l, v_l, k, v)
-        attn = tfm.attend_over_pool(q, _dc.replace(view, k=k_l, v=v_l),
-                                    backend=attn_backend)
+        k_new = apply_rope(k_new, positions, cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            lv = _dc.replace(view, k=k, v=v).write_layer(i, k_new, v_new)
+        attn = tfm.attend_over_pool(q, lv, i, backend=attn_backend)
         h = h + linear(lp["wo"], attn.reshape(B, S, -1))
         cn = rms_norm(h, lp["cross_norm"], cfg.norm_eps)
         cq = linear(lp["c_wq"], cn).reshape(B, S, H, hd)
@@ -234,26 +234,24 @@ def unified_step(params, view, batch, cfg, *, attn_backend=None,
         h = h + linear(lp["c_wo"], c.reshape(B, S, -1))
         m = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
         h = h + linear(lp["w_down"], activation(cfg.act, linear(lp["w_up"], m)))
-        return h, k_l, v_l
+        return h, lv.k, lv.v
 
+    # the self-attention arenas ride the layer loop whole (each layer
+    # writes its own index in place); the read-only context goes per layer
+    k, v = view.k, view.v
     if unroll:
-        ks, vs = [], []
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda p: p[i], params["dec"])
-            x, k_l, v_l = block(lp, x, view.k[i], view.v[i],
-                                view.ck[i], view.cv[i])
-            ks.append(k_l)
-            vs.append(v_l)
-        k, v = jnp.stack(ks), jnp.stack(vs)
+            x, k, v = block(lp, x, k, v, i, view.ck[i], view.cv[i])
     else:
-        def scan_body(h, xs):
-            lp, k_l, v_l, ck_l, cv_l = xs
-            h, k_l, v_l = block(lp, h, k_l, v_l, ck_l, cv_l)
-            return h, (k_l, v_l)
+        def scan_body(carry, xs):
+            lp, i, ck_l, cv_l = xs
+            return block(lp, *carry, i, ck_l, cv_l), None
 
-        x, (k, v) = jax.lax.scan(
-            scan_body, x,
-            (params["dec"], view.k, view.v, view.ck, view.cv))
+        (x, k, v), _ = jax.lax.scan(
+            scan_body, (x, k, v),
+            (params["dec"], jnp.arange(cfg.n_layers, dtype=jnp.int32),
+             view.ck, view.cv))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = linear(params["lm_head"], x)
     return logits, (k, v)

@@ -211,7 +211,7 @@ def unified_step(params, view, batch, cfg, *, attn_backend=None,
     scfg = _shared_block_cfg(cfg)
     positions = tfm._pool_positions(kview.cursor, S, scfg) \
         if cfg.attn_every else None
-    new_states, ks, vs = [], [], []
+    new_states = []
     ai = 0
     for i in range(cfg.n_layers):
         lp = jax.tree.map(lambda p: p[i], params["mamba"])
@@ -224,15 +224,12 @@ def unified_step(params, view, batch, cfg, *, attn_backend=None,
             s, lane_st)
         new_states.append(sview.scatter_layer(i, s))
         if cfg.attn_every and (i % cfg.attn_every) == (cfg.attn_every - 1):
-            x, k_l, v_l = tfm._block_step(params["shared_attn"], x,
-                                          kview.k[ai], kview.v[ai], kview,
-                                          positions, scfg, attn_backend)
-            ks.append(k_l)
-            vs.append(v_l)
+            x, kview = tfm._block_step(params["shared_attn"], x, kview, ai,
+                                       positions, scfg, attn_backend)
             ai += 1
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = linear(params["lm_head"], x)
-    kv = (jnp.stack(ks), jnp.stack(vs)) if ks else None
+    kv = (kview.k, kview.v) if ai else None
     return logits, kv, new_states
 
 

@@ -71,18 +71,27 @@ def arena_nbytes(*arrays) -> int:
     return sum(a.size * a.dtype.itemsize for a in arrays if a is not None)
 
 
-def _flat_scatter(flat_idx, n_rows: int, n_vals: int):
-    """Scatter closure over a flattened arena: works for value arenas
-    ([rows, KV, hd] trailing dims) and scale arenas ([rows, KV]) alike —
-    the same indices route both, which is what keeps scales glued to
-    their positions through every write path."""
+def write_arenas(view, index: tuple, fresh_k, fresh_v):
+    """``view`` with fresh [B, S, KV, hd] KV set at ``index`` of its
+    whole arenas: a tuple of a layer and [B*S] indices into the arenas'
+    next axes, one entry per (lane, token).  An int8 arena quantizes on
+    scatter, its values and scales routed by the same indices — which is
+    what keeps scales glued to their positions through every write path.
+    Indices past an axis drop their write."""
+    B, S = fresh_k.shape[:2]
+
     def scat(arena, vals):
-        flat = arena.reshape(n_rows, *arena.shape[2:])
-        flat = flat.at[flat_idx].set(
-            vals.reshape(n_vals, *vals.shape[2:]).astype(arena.dtype),
+        return arena.at[index].set(
+            vals.reshape(B * S, *vals.shape[2:]).astype(arena.dtype),
             mode="drop")
-        return flat.reshape(arena.shape)
-    return scat
+    if view.k_scale is None:
+        return dataclasses.replace(view, k=scat(view.k, fresh_k),
+                                   v=scat(view.v, fresh_v))
+    qk, sk = quantize_kv(fresh_k)
+    qv, sv = quantize_kv(fresh_v)
+    return dataclasses.replace(view, k=scat(view.k, qk), v=scat(view.v, qv),
+                               k_scale=scat(view.k_scale, sk),
+                               v_scale=scat(view.v_scale, sv))
 
 
 @runtime_checkable
@@ -124,9 +133,9 @@ class SlotPoolView:
     context.  Constructed inside the engine's traced step functions, so
     every field is a tracer at use time.
 
-    ``k``/``v`` are the full [L, n_slots, max_len, KV, hd] arenas at step
-    level; inside the per-layer scan the transformer rebinds them to one
-    layer's [n_slots, max_len, KV, hd] slice (``dataclasses.replace``).
+    ``k``/``v`` are the whole [L, n_slots, max_len, KV, hd] arenas; the
+    per-layer loop carries them and each layer writes and reads its own
+    index (``write_layer``, ``lane_kv``), so no layer is sliced out.
     ``rows`` [B] maps each batch lane to its arena slot (values >=
     n_slots are padding lanes whose writes drop); ``rows=None`` means the
     batch IS the arena, lane i == slot i (the fused decode).  ``cursor``
@@ -139,7 +148,7 @@ class SlotPoolView:
     bf16 arenas) are the per-position dequantization scales of an int8
     arena; they ride the view through the jitted step exactly like the
     arenas (donated in, scattered in place, adopted out) and share the
-    arenas' flat write indices.
+    arenas' write indices.
     """
     k: Any
     v: Any
@@ -153,48 +162,31 @@ class SlotPoolView:
     def block_tables(self):
         return None                       # duck-type marker: slot layout
 
-    def lane_kv(self, k_l, v_l):
-        """Per-lane [B, max_len, KV, hd] arena rows for attention.  With
-        ``rows=None`` the arena batch dim is used directly (no gather on
-        the fused-decode hot path); otherwise a B-row gather whose cost is
-        independent of how much prefix the rows have written."""
+    def lane_kv(self, k, v, layer):
+        """Per-lane [B, max_len, ...] rows of layer ``layer`` of the
+        arenas ``k``/``v`` (values or scales), for attention.  With
+        ``rows=None`` the arena's slot dim is used directly; otherwise a
+        B-row gather whose cost is independent of how much prefix the
+        rows have written."""
         if self.rows is None:
-            return k_l, v_l
-        return k_l[self.rows], v_l[self.rows]
+            return k[layer], v[layer]
+        return k[layer, self.rows], v[layer, self.rows]
 
-    def _flat_write_idx(self, ns, ml, S):
-        """Flat (slot*max_len + pos) scatter index per (lane, i) pair;
-        padding/overflow maps to ns*ml (one past the arena) and drops."""
+    def write_layer(self, layer, fresh_k, fresh_v):
+        """Scatter fresh [B, S, KV, hd] KV into layer ``layer`` of the
+        arenas at each lane's cursor, in place under donation; returns
+        the view holding the updated arenas.  Real (lane, i<n_new) pairs
+        land at slot ``rows[b]``, position ``cursor[b] + i``; padding
+        maps past the arena's slots and is dropped, so the compiled
+        scatter depends only on (B, S)."""
+        ns, ml = self.k.shape[1], self.k.shape[2]
+        S = fresh_k.shape[1]
         rows = jnp.arange(ns) if self.rows is None else self.rows
         p = self.cursor[:, None] + jnp.arange(S)[None]        # [B,S]
-        oob = ns * ml
-        flat_idx = rows[:, None] * ml + p
         valid = (jnp.arange(S)[None] < self.n_new[:, None]) & (p < ml)
-        return jnp.where(valid, flat_idx, oob).reshape(-1)
-
-    def write_layer(self, k_l, v_l, fresh_k, fresh_v):
-        """Scatter fresh [B, S, KV, hd] KV into one layer's arena slice at
-        each lane's cursor, in place under donation.  Real (lane, i<n_new)
-        pairs land at flat slot ``rows[b] * max_len + cursor[b] + i``;
-        padding maps past the arena extent and is dropped, so the compiled
-        scatter depends only on (B, S)."""
-        ns, ml = k_l.shape[0], k_l.shape[1]
-        B, S = fresh_k.shape[:2]
-        flat_idx = self._flat_write_idx(ns, ml, S)
-        scat = _flat_scatter(flat_idx, ns * ml, B * S)
-        return scat(k_l, fresh_k), scat(v_l, fresh_v)
-
-    def write_layer_quantized(self, k_l, v_l, ks_l, vs_l, fresh_k, fresh_v):
-        """Quantize-on-scatter: int8-quantize the fresh KV per position and
-        scatter values + scales with the SAME flat indices — the bf16
-        projections never touch HBM as an arena copy."""
-        ns, ml = k_l.shape[0], k_l.shape[1]
-        B, S = fresh_k.shape[:2]
-        flat_idx = self._flat_write_idx(ns, ml, S)
-        scat = _flat_scatter(flat_idx, ns * ml, B * S)
-        qk, sk = quantize_kv(fresh_k)
-        qv, sv = quantize_kv(fresh_v)
-        return scat(k_l, qk), scat(v_l, qv), scat(ks_l, sk), scat(vs_l, sv)
+        r = jnp.where(valid, rows[:, None], ns)
+        return write_arenas(self, (layer, r.reshape(-1), p.reshape(-1)),
+                            fresh_k, fresh_v)
 
 
 class SlotKVPool:

@@ -14,13 +14,15 @@ reproduces the old decode contract (visible count ``pos + 1``).
 Two implementations behind one entry point:
 
 - ``reference``: gather the row's blocks into a contiguous
-  ``[B, nb*block_size, KV, hd]`` view with ``arena[block_tables]`` and run
-  the masked softmax.  A table maps sequence position ``p`` to gathered
+  ``[B, nb*block_size, KV, hd]`` view with ``arena[layer, block_tables]``
+  and run the masked softmax.  A table maps sequence position ``p`` to gathered
   index ``p`` exactly, so the mask carries over unchanged — XLA fuses the
   gather, so this is also the portable CPU/GPU path.
 - ``pallas``: a TPU kernel (interpret-mode fallback off-TPU) that never
-  materializes the gathered view.  The block tables and cursors ride in
-  as scalar-prefetch operands and the arena stays in HBM.  The grid is
+  materializes the gathered view.  The block tables, cursors and layer
+  index ride in as scalar-prefetch operands and the whole [L, ...] arena
+  stays in HBM: the kernel reads ``arena[layer, block]``, so the layer
+  loop never slices a layer out of it.  The grid is
   ``(B, query tiles)``; each grid step walks only the table blocks its
   query tile can see (``visible_blocks``: up to its last query's
   position, from its first query's window start), ``P`` blocks per walk
@@ -85,28 +87,31 @@ def _default_backend() -> str:
 # --------------------------------------------------------------------------
 
 def paged_attention_ref(q, k_arena, v_arena, block_tables, cursor,
-                        *, window: int | None = None,
+                        *, layer, window: int | None = None,
                         k_scale=None, v_scale=None) -> jax.Array:
-    """q [B,S,H,hd]; arenas [n_blocks, bs, KV, hd]; block_tables [B, nb]
-    int32; cursor [B] (tokens visible per row before this step's S fresh
-    ones).  Returns [B,S,H,hd].
+    """q [B,S,H,hd]; arenas [L, n_blocks, bs, KV, hd], of which layer
+    ``layer`` is read; block_tables [B, nb] int32; cursor [B] (tokens
+    visible per row before this step's S fresh ones).  Returns
+    [B,S,H,hd].
 
     A table maps sequence position ``p`` to gathered index ``p`` exactly,
     so after the gather this IS the contiguous length-masked attention —
     delegated to ``models/layers.attend_length_masked`` so the masking
-    rule lives in one place.  ``k_scale``/``v_scale`` [n_blocks, bs, KV]
-    are an int8 arena's per-position scales, gathered through the same
-    tables (XLA fuses gather + dequant — no bf16 arena copy)."""
+    rule lives in one place.  The gather reads the layer's blocks from
+    the whole arena, so no layer is sliced out first.
+    ``k_scale``/``v_scale`` [L, n_blocks, bs, KV] are an int8 arena's
+    per-position scales, gathered through the same tables (XLA fuses
+    gather + dequant — no bf16 arena copy)."""
     from ...models.layers import attend_length_masked
     B, S, H, hd = q.shape
-    _, bs, KV, _ = k_arena.shape
+    _, _, bs, KV, _ = k_arena.shape
     nb = block_tables.shape[1]
-    k = k_arena[block_tables].reshape(B, nb * bs, KV, hd)
-    v = v_arena[block_tables].reshape(B, nb * bs, KV, hd)
+    k = k_arena[layer, block_tables].reshape(B, nb * bs, KV, hd)
+    v = v_arena[layer, block_tables].reshape(B, nb * bs, KV, hd)
     ks = vs = None
     if k_scale is not None:
-        ks = k_scale[block_tables].reshape(B, nb * bs, KV)
-        vs = v_scale[block_tables].reshape(B, nb * bs, KV)
+        ks = k_scale[layer, block_tables].reshape(B, nb * bs, KV)
+        vs = v_scale[layer, block_tables].reshape(B, nb * bs, KV)
     return attend_length_masked(q, k, v, cursor, window=window,
                                 k_scale=ks, v_scale=vs)
 
@@ -193,8 +198,9 @@ def _weighted(p, v_ref):
                       preferred_element_type=jnp.float32)
 
 
-def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_hbm, v_hbm, *refs,
-                       bs, nb, P, st, n_rep, window, quantized, scale):
+def _paged_attn_kernel(bt_ref, cur_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                       *refs, bs, nb, P, st, n_rep, window, quantized,
+                       scale):
     if quantized:
         (ks_hbm, vs_hbm, o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf,
          ks_buf, vs_buf, sems, slot_ref) = refs
@@ -207,6 +213,7 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_hbm, v_hbm, *refs,
     n_rows, n_qt = pl.num_programs(0), pl.num_programs(1)
     T = P * bs
     KV = k_buf.shape[3]
+    layer = layer_ref[0]
 
     def bounds(b, qi):
         return visible_blocks(cur_ref[b], qi, st, bs, nb, window, jnp)
@@ -214,7 +221,9 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_hbm, v_hbm, *refs,
     def dmas(b, qi, step, slot, method):
         """Start or wait for the DMAs of walk step ``step`` of tile (b,
         qi) into ``slot``: one per array for each of its P table blocks
-        that the tile can see.  A block it cannot see is never read."""
+        that the tile can see, from the layer's part of the value arenas
+        (the scale rows hold one layer only).  A block it cannot see is
+        never read."""
         first, last = bounds(b, qi)
 
         def block(p, carry):
@@ -224,9 +233,9 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_hbm, v_hbm, *refs,
             @pl.when((j >= first) & (j <= last))
             def _():
                 for a, (src, buf) in enumerate(zip(hbm, bufs)):
+                    at = src.at[layer, blk] if a < 2 else src.at[blk]
                     getattr(pltpu.make_async_copy(
-                        src.at[blk], buf.at[slot, p], sems.at[a, slot]),
-                        method)()
+                        at, buf.at[slot, p], sems.at[a, slot]), method)()
             return carry
 
         jax.lax.fori_loop(0, P, block, 0)
@@ -320,21 +329,23 @@ def _paged_attn_kernel(bt_ref, cur_ref, q_ref, k_hbm, v_hbm, *refs,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
-                           *, window: int | None = None,
+                           *, layer, window: int | None = None,
                            interpret: bool | None = None,
                            k_scale=None, v_scale=None) -> jax.Array:
     """Same contract as ``paged_attention_ref``.  One grid step per (row,
     query tile); each walks only the table blocks the tile can see
     (``visible_blocks``), ``P`` blocks per walk step (``kv_walk``), DMA'd
-    by table lookup from the arena left in HBM into a double-buffered
-    VMEM scratch, the next step's copies in flight during this step's
-    compute.  Query heads are grouped by their KV head, so K and V are
-    never repeated.  ``k_scale``/``v_scale`` [n_blocks, bs, KV] come by
-    the same lookups, one lane-dense row per block; the kernel
-    dequantizes in-register.  ``interpret=None`` compiles on a TPU
-    backend and interprets elsewhere."""
+    by table lookup (``arena[layer, block]``, the layer a scalar-prefetch
+    operand beside the tables and cursors) from the whole arena left in
+    HBM into a double-buffered VMEM scratch, the next step's copies in
+    flight during this step's compute.  Query heads are grouped by their
+    KV head, so K and V are never repeated.  ``k_scale``/``v_scale`` [L,
+    n_blocks, bs, KV] come by the same lookups, one lane-dense row per
+    block of the layer; the kernel dequantizes in-register.
+    ``interpret=None`` compiles on a TPU backend and interprets
+    elsewhere."""
     B, S, H, hd = q.shape
-    n_blocks, bs, KV, _ = k_arena.shape
+    _, n_blocks, bs, KV, _ = k_arena.shape
     nb = block_tables.shape[1]
     n_rep = H // KV
     quantized = k_scale is not None
@@ -347,17 +358,20 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
     # [B, KV, S * n_rep, hd]: the query heads of each KV head, query-major
     qg = (q.reshape(B, S, KV, n_rep, hd).transpose(0, 2, 1, 3, 4)
           .reshape(B, KV, S * n_rep, hd))
-    q_spec = pl.BlockSpec((1, KV, R, hd), lambda b, i, bt, cu: (b, 0, i, 0))
+    q_spec = pl.BlockSpec((1, KV, R, hd),
+                          lambda b, i, bt, cu, ly: (b, 0, i, 0))
     arenas = [k_arena, v_arena]
+    blocks = [a.shape[2:] for a in arenas]
     if quantized:
         # the chip's DMAs slice only lane-dense rows: one row of bs * KV
-        # scales per block, padded to whole lanes
+        # scales per block of this layer, padded to whole lanes
         w = -(-bs * KV // 128) * 128
-        arenas += [jnp.pad(s.reshape(n_blocks, bs * KV),
+        arenas += [jnp.pad(s[layer].reshape(n_blocks, bs * KV),
                            ((0, 0), (0, w - bs * KV)))
                    for s in (k_scale, v_scale)]
+        blocks += [(w,)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # block tables, cursors
+        num_scalar_prefetch=3,                  # block tables, cursors, layer
         grid=(B, S // st),
         in_specs=[q_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * len(
             arenas),
@@ -366,7 +380,8 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
             pltpu.VMEM((KV, R, 1), jnp.float32),     # running max
             pltpu.VMEM((KV, R, 1), jnp.float32),     # running sum
             pltpu.VMEM((KV, R, hd), jnp.float32),    # weighted-value acc
-        ] + [pltpu.VMEM((2, P) + a.shape[1:], a.dtype) for a in arenas] + [
+        ] + [pltpu.VMEM((2, P) + blk, a.dtype)
+             for a, blk in zip(arenas, blocks)] + [
             pltpu.SemaphoreType.DMA((len(arenas), 2)),
             pltpu.SMEM((1,), jnp.int32),             # slot of the next step
         ],
@@ -380,7 +395,7 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32).reshape(-1), cursor.astype(jnp.int32),
-      qg, *arenas)
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, *arenas)
     return (out.reshape(B, KV, S, n_rep, hd).transpose(0, 2, 1, 3, 4)
             .reshape(B, S, H, hd))
 
@@ -390,14 +405,14 @@ def paged_attention_pallas(q, k_arena, v_arena, block_tables, cursor,
 # --------------------------------------------------------------------------
 
 def paged_attention(q, k_arena, v_arena, block_tables, cursor, *,
-                    window: int | None = None,
+                    layer, window: int | None = None,
                     backend: str | None = None,
                     k_scale=None, v_scale=None) -> jax.Array:
     backend = backend or _default_backend()
     if backend == "pallas":
         return paged_attention_pallas(
-            q, k_arena, v_arena, block_tables, cursor, window=window,
-            k_scale=k_scale, v_scale=v_scale)
+            q, k_arena, v_arena, block_tables, cursor, layer=layer,
+            window=window, k_scale=k_scale, v_scale=v_scale)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, cursor,
-                               window=window, k_scale=k_scale,
+                               layer=layer, window=window, k_scale=k_scale,
                                v_scale=v_scale)

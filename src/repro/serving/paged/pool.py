@@ -46,7 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..cache_pool import CachePoolError, CapacityError, DoubleFree
+from ..cache_pool import (CachePoolError, CapacityError, DoubleFree,
+                          write_arenas)
 from .block_pool import BlockPool, OutOfBlocks
 from .block_table import BlockTable, blocks_needed
 from .prefix_cache import PrefixCache
@@ -59,9 +60,9 @@ class PagedPoolView:
     copy of context.  Constructed inside the engine's traced step
     functions; ``trash`` is the host-known trash-block id (static).
 
-    ``k``/``v`` are [L, n_blocks, block_size, KV, hd] at step level and
-    one layer's [n_blocks, block_size, KV, hd] slice inside the per-layer
-    scan.  ``block_tables`` [B, nb] maps each lane's sequence position p
+    ``k``/``v`` are the whole [L, n_blocks, block_size, KV, hd] arenas,
+    carried through the per-layer loop; each layer writes and reads its
+    own index.  ``block_tables`` [B, nb] maps each lane's sequence position p
     to physical block ``bt[b, p // block_size]`` (padding lanes carry
     all-trash tables).  ``cursor``/``n_new`` as in ``SlotPoolView``.
 
@@ -82,51 +83,28 @@ class PagedPoolView:
     rows = None                           # duck-type marker: paged layout
 
     def _write_slots(self, bs, S):
-        """Flat (block*block_size + offset) scatter index per (lane, i);
+        """(block, offset) scatter indices per (lane, i), flattened;
         padding routes to the trash block."""
         nb = self.block_tables.shape[1]
         p = self.cursor[:, None] + jnp.arange(S)[None]        # [B,S]
         bi = p // bs
         blk = jnp.take_along_axis(self.block_tables,
                                   jnp.clip(bi, 0, nb - 1), axis=1)
-        slot = blk * bs + p % bs
         valid = (jnp.arange(S)[None] < self.n_new[:, None]) & (bi < nb)
-        return jnp.where(valid, slot, self.trash * bs).reshape(-1)
+        blk = jnp.where(valid, blk, self.trash)
+        return blk.reshape(-1), (p % bs).reshape(-1)
 
-    def write_layer(self, k_l, v_l, fresh_k, fresh_v):
-        """Scatter fresh [B, S, KV, hd] KV through the block tables at
-        each lane's cursor, in place under donation.  Every padding
-        element — batch-pad lanes, positions past a lane's ``n_new``, or
-        positions past the table width — routes to the trash block, so
-        the compiled scatter depends only on (B, S)."""
-        bs = k_l.shape[1]
-        B, S = fresh_k.shape[:2]
-        slot = self._write_slots(bs, S)
-        def scat(arena, vals):
-            nblk = arena.shape[0]
-            flat = arena.reshape(nblk * bs, *arena.shape[2:])
-            flat = flat.at[slot].set(
-                vals.reshape(B * S, *vals.shape[2:]).astype(arena.dtype))
-            return flat.reshape(arena.shape)
-        return scat(k_l, fresh_k), scat(v_l, fresh_v)
-
-    def write_layer_quantized(self, k_l, v_l, ks_l, vs_l, fresh_k, fresh_v):
-        """Quantize-on-scatter: int8-quantize fresh KV per position and
-        route values + scales through the same table-derived slots (the
-        bf16 projections never land in HBM as an arena copy)."""
-        from ..cache_pool import quantize_kv
-        bs = k_l.shape[1]
-        B, S = fresh_k.shape[:2]
-        slot = self._write_slots(bs, S)
-        def scat(arena, vals):
-            nblk = arena.shape[0]
-            flat = arena.reshape(nblk * bs, *arena.shape[2:])
-            flat = flat.at[slot].set(
-                vals.reshape(B * S, *vals.shape[2:]).astype(arena.dtype))
-            return flat.reshape(arena.shape)
-        qk, sk = quantize_kv(fresh_k)
-        qv, sv = quantize_kv(fresh_v)
-        return scat(k_l, qk), scat(v_l, qv), scat(ks_l, sk), scat(vs_l, sv)
+    def write_layer(self, layer, fresh_k, fresh_v):
+        """Scatter fresh [B, S, KV, hd] KV into layer ``layer`` of the
+        arenas through the block tables at each lane's cursor, in place
+        under donation; returns the view holding the updated arenas.
+        Every padding element — batch-pad lanes, positions past a lane's
+        ``n_new``, or positions past the table width — routes to the
+        trash block, so the compiled scatter depends only on (B, S).  An
+        int8 arena quantizes on scatter (``cache_pool.write_arenas``):
+        the bf16 projections never land in HBM as an arena copy."""
+        blk, off = self._write_slots(self.k.shape[2], fresh_k.shape[1])
+        return write_arenas(self, (layer, blk, off), fresh_k, fresh_v)
 
 
 class PagedKVPool:

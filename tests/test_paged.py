@@ -248,6 +248,26 @@ def _wide_case(S=1, H=4, KV=2, hd=16, bs=8, nb=37, seed=0):
     return (q, ka, va, jnp.asarray(bt), jnp.asarray(cursor)), cursor + S
 
 
+# the kernel and the reference take whole [L, ...] arenas and a layer
+# index; every case sits at a non-zero layer between layers that hold NaN
+# (int8 values: NaN scales), so reading any other layer shows
+LAYER, N_LAYERS = 1, 3
+
+
+def _at_layer(*arenas):
+    """Each per-layer arena (or None) as layer ``LAYER`` of an
+    ``N_LAYERS``-layer arena whose other layers are NaN (int8 values:
+    zeros, whose NaN scales dequantize them to NaN)."""
+    def one(a):
+        if a is None:
+            return None
+        fill = jnp.zeros_like(a) if a.dtype == jnp.int8 \
+            else jnp.full_like(a, jnp.nan)
+        return jnp.stack([a if i == LAYER else fill
+                          for i in range(N_LAYERS)])
+    return tuple(one(a) for a in arenas)
+
+
 def _quantize(a):
     """int8 values and per-position f32 scales of an f32 arena."""
     sc = jnp.max(jnp.abs(a), axis=-1) / 127.0
@@ -267,7 +287,8 @@ def _case(which, S, H=4, KV=2):
 
 def test_paged_attention_ref_matches_contiguous():
     q, ka, va, bt, cursor = _attn_case()
-    ref = paged_attention_ref(q, ka, va, bt, cursor)
+    ref = paged_attention_ref(q, *_at_layer(ka, va), bt, cursor,
+                              layer=LAYER)
     # contiguous view assembled by the same table; decode masks < len
     B, nb = bt.shape
     bs = ka.shape[1]
@@ -283,7 +304,8 @@ def test_paged_attention_ref_chunk_matches_masked_contiguous():
     over the contiguous view assembled by the same tables."""
     from repro.models.layers import attend_length_masked
     q, ka, va, bt, cursor = _attn_case(S=5)
-    ref = paged_attention_ref(q, ka, va, bt, cursor)
+    ref = paged_attention_ref(q, *_at_layer(ka, va), bt, cursor,
+                              layer=LAYER)
     B, nb = bt.shape
     bs = ka.shape[1]
     kc = ka[bt].reshape(B, nb * bs, *ka.shape[2:])
@@ -299,12 +321,17 @@ def test_paged_attention_pallas_matches_ref(window, S, case):
     """The kernel against the gather reference: three-row tables within
     one walk step, and wide tables (``_wide_case``) with rows on every
     edge of the walk, f32 and int8 arenas; the window makes
-    later rows start their walk past block 0."""
+    later rows start their walk past block 0.  Both read layer ``LAYER``
+    of whole arenas whose other layers are NaN."""
     q, ka, va, bt, cursor, ks, vs = _case(case, S)
-    ref = paged_attention_ref(q, ka, va, bt, cursor, window=window,
-                              k_scale=ks, v_scale=vs)
-    pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
-                                 interpret=True, k_scale=ks, v_scale=vs)
+    ka, va = _at_layer(ka, va)
+    ks, vs = _at_layer(ks, vs)
+    ref = paged_attention_ref(q, ka, va, bt, cursor, layer=LAYER,
+                              window=window, k_scale=ks, v_scale=vs)
+    pal = paged_attention_pallas(q, ka, va, bt, cursor, layer=LAYER,
+                                 window=window, interpret=True,
+                                 k_scale=ks, v_scale=vs)
+    assert np.isfinite(np.asarray(pal)).all()
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
@@ -325,9 +352,11 @@ def test_paged_attention_pallas_query_tiled_matches_ref(S, H, KV, window,
     monkeypatch.setattr(pa, "_Q_ROWS", 8)
     q, ka, va, bt, cursor, _, _ = _case(case, S, H=H, KV=KV)
     assert pa._q_tile(S, H) < S
-    ref = paged_attention_ref(q, ka, va, bt, cursor, window=window)
-    pal = paged_attention_pallas(q, ka, va, bt, cursor, window=window,
-                                 interpret=True)
+    ka, va = _at_layer(ka, va)
+    ref = paged_attention_ref(q, ka, va, bt, cursor, layer=LAYER,
+                              window=window)
+    pal = paged_attention_pallas(q, ka, va, bt, cursor, layer=LAYER,
+                                 window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
@@ -351,10 +380,12 @@ def test_paged_attention_pallas_never_reads_past_visible(S, window):
         bt[b, n:] = unseen[np.arange(bt.shape[1] - n) % len(unseen)]
     bt = jnp.asarray(bt)
     hidden = jnp.asarray(~seen)[:, None, None, None]
-    ref = paged_attention_ref(q, ka, va, bt, cursor, window=window)
-    pal = paged_attention_pallas(q, jnp.where(hidden, jnp.nan, ka),
-                                 jnp.where(hidden, jnp.nan, va), bt, cursor,
-                                 window=window, interpret=True)
+    ref = paged_attention_ref(q, *_at_layer(ka, va), bt, cursor,
+                              layer=LAYER, window=window)
+    pal = paged_attention_pallas(
+        q, *_at_layer(jnp.where(hidden, jnp.nan, ka),
+                      jnp.where(hidden, jnp.nan, va)),
+        bt, cursor, layer=LAYER, window=window, interpret=True)
     assert np.isfinite(np.asarray(pal)).all()
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -374,10 +405,13 @@ def test_paged_attention_dispatch_matches_ref(S, monkeypatch):
     and without an explicit backend."""
     from repro.serving.paged import paged_attention
     q, ka, va, bt, cursor = _attn_case(S=S, H=8, KV=2)
-    ref = paged_attention(q, ka, va, bt, cursor, backend="reference")
+    ka, va = _at_layer(ka, va)
+    ref = paged_attention(q, ka, va, bt, cursor, layer=LAYER,
+                          backend="reference")
     monkeypatch.setenv("REPRO_PAGED_BACKEND", "pallas")
     for backend in ("pallas", None):
-        pal = paged_attention(q, ka, va, bt, cursor, backend=backend)
+        pal = paged_attention(q, ka, va, bt, cursor, layer=LAYER,
+                              backend=backend)
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5)
 
@@ -540,3 +574,43 @@ def test_mixed_arrivals_paged(dense_params):
     for r, prompt, gen in [(reqs[0], early[0], 12), (reqs[2], late[0], 4),
                            (reqs[3], late[1], 4)]:
         assert r.tokens == _solo(dense_params, prompt, gen)
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_step_programs_write_the_arena_in_place(kv_dtype):
+    """The paged step programs carry the whole [L, ...] arenas through the
+    layer loop and write each layer's fresh KV at its own index.
+    Compiled, neither the decode program nor a chunk program holds a
+    temporary as large as one arena, nor a copy, slice or update whose
+    output is the arena or one layer of it: a layer loop that slices each
+    layer out and stacks the results back copies the whole arena on
+    every step.  A float32 model, because the CPU backend widens a bf16
+    scatter's operand to float32 (a chip's compiler scatters bf16 in
+    place; ``tests/test_tpu_compile.py`` checks that at full size)."""
+    import re
+    cfg = dataclasses.replace(CFG, dtype=jnp.float32)
+    params = get_model(cfg).init(jax.random.PRNGKey(0))
+    engine = ServingEngine(cfg, params, n_slots=4, max_len=64,
+                           kv_layout="paged", block_size=BS, n_blocks=2048,
+                           kv_dtype="int8" if kv_dtype == "int8" else "bf16")
+    a, pool = engine.adapter, engine.pool
+    arena_bytes = pool.k.size * pool.k.dtype.itemsize
+    layer_shape = ",".join(map(str, pool.k.shape[1:]))
+    whole = re.compile(r"= \S+\[(\d+,)?%s\]\S* (copy|dynamic-slice|"
+                       r"dynamic-update-slice)\(" % layer_shape)
+    lanes = jnp.asarray(pool.lane_tables([], 2))
+    zeros = jnp.zeros((2,), jnp.int32)
+    lowered = {
+        "decode": a._decode_fn.lower(a.params, *a._arena_args(),
+                                     pool.block_tables, pool.pos,
+                                     jnp.zeros((4, 1), jnp.int32)),
+        "chunk": a._step_fn.lower(a.params, *a._arena_args(), lanes, zeros,
+                                  zeros, jnp.zeros((2, 16), jnp.int32))}
+    for name, low in lowered.items():
+        assert "kv_write" in low.as_text(debug_info=True), name
+        compiled = low.compile()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < arena_bytes, (name, temp, arena_bytes)
+        copies = [ln for ln in compiled.as_text().splitlines()
+                  if whole.search(ln)]
+        assert not copies, (name, copies[:3])

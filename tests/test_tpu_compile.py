@@ -5,7 +5,9 @@ checks that its blocks are tile-aligned and that it fits scoped VMEM.  Each
 test lowers one kernel variant that the TPU dispatch can choose, at
 internlm2-1.8b widths (the paged kernel also at qwen3-8b widths, whose
 32 q heads make the widest query tiles of the zoo's attention configs),
-and compiles it for one chip of a described ``v5e:2x2``.  Nothing runs.
+and compiles it for one chip of a described ``v5e:2x2``.  Two more compile a
+whole step program, Mistral-7B's decode and chunk steps at published
+widths, and read the compiler's memory analysis.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -105,21 +107,23 @@ def test_fused_sparse_linear_compiles(one_chip, batch, quantized):
     assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
 
 
-def _paged_shapes(cfg, S, quantized, batch=8, n_blocks=256, per_row=40):
+def _paged_shapes(cfg, S, quantized, batch=8, n_blocks=256, per_row=40,
+                  n_layers=4):
     kv_dt = jnp.int8 if quantized else cfg.dtype
-    arena = (n_blocks, BLOCK, cfg.n_kv_heads, cfg.hd)
+    arena = (n_layers, n_blocks, BLOCK, cfg.n_kv_heads, cfg.hd)
     shapes = [((batch, S, cfg.n_heads, cfg.hd), cfg.dtype),
               (arena, kv_dt), (arena, kv_dt),
-              ((batch, per_row), jnp.int32), ((batch,), jnp.int32)]
+              ((batch, per_row), jnp.int32), ((batch,), jnp.int32),
+              ((), jnp.int32)]
     if quantized:
-        shapes += [((n_blocks, BLOCK, cfg.n_kv_heads), jnp.float32)] * 2
+        shapes += [(arena[:-1], jnp.float32)] * 2
     return shapes
 
 
-def _paged_fn(q, k, v, bt, cursor, *scales):
+def _paged_fn(q, k, v, bt, cursor, layer, *scales):
     ks, vs = scales if scales else (None, None)
-    return paged_attention_pallas(q, k, v, bt, cursor, interpret=False,
-                                  k_scale=ks, v_scale=vs)
+    return paged_attention_pallas(q, k, v, bt, cursor, layer=layer,
+                                  interpret=False, k_scale=ks, v_scale=vs)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -129,3 +133,55 @@ def test_paged_attention_untiled_compiles(one_chip, arch, S, quantized):
     text = _compile(one_chip, _paged_fn,
                     *_paged_shapes(configs.get(arch), S, quantized))
     assert "tpu_custom_call" in text
+
+
+# the HBM one v5e chip offers to a program (``memory_stats`` on the chip)
+V5E_HBM = 16_909_336_064
+HEADROOM = 0.5e9
+
+
+@pytest.mark.parametrize("S", [1, 2048], ids=["decode", "chunk2048"])
+def test_mistral_7b_step_fits_one_chip(one_chip, S):
+    """Mistral-7B at published widths, 8:16 + 16:256 (the benchmark's
+    ``mistral-7b.s816``), with its whole paged arena (2048 blocks of 16
+    plus the trash block): the decode step over its 8 slots and a
+    2048-token chunk step compile for one chip, leave at least
+    ``HEADROOM`` of its HBM free beside their arguments, temporaries and
+    outputs, and copy no whole arena or layer of it (the arena is written
+    in place)."""
+    import re
+    from bench import harness, registry, weights
+    from repro.models import transformer as tfm
+    from repro.serving import families
+    from repro.serving.paged import PagedPoolView
+    from repro.serving.placement import ServingPlacement
+    conf = registry.config("mistral-7b.s816")
+    m, dep = conf["model"], conf["deployment"]
+    cfg = harness.model_config(conf)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+        weights.params_fn(m, harness.compressor(conf["weights"])),
+        jax.random.PRNGKey(0)))
+    layer = (dep["n_blocks"] + 1, dep["block_size"], m["n_kv_heads"],
+             m["head_dim"])
+    arena = sds((m["n_layers"], *layer), jnp.bfloat16)
+    nb = dep["max_len"] // dep["block_size"]
+    B = dep["n_slots"] if S == 1 else 1
+    step = families._jit(
+        ServingPlacement(),
+        lambda p, k, v, bt, cur, nn, t: tfm.unified_step(
+            p, PagedPoolView(k, v, bt, cur, nn, 0), {"tokens": t}, cfg,
+            attn_backend="pallas"), donate=(1, 2))
+    rows = sds((B,), jnp.int32)
+    compiled = step.lower(params, arena, arena, sds((B, nb), jnp.int32),
+                          rows, rows, sds((B, S), jnp.int32)).compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert used + HEADROOM <= V5E_HBM, used
+    whole = re.compile(r"= \S+\[(\d+,)?%s\]\S* (copy|dynamic-slice|"
+                       r"dynamic-update-slice)\(" % ",".join(map(str, layer)))
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if whole.search(ln)]
