@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ActStats, SparsifyConfig, sparsify_linear
-from repro.kernels import ops
+from repro.kernels import ref
 from .common import emit
 
 V5E_BW = 819e9
@@ -54,8 +54,9 @@ def run():
 
         dense_fn = jax.jit(lambda x, w: x @ w.T)
         us_dense = _time(dense_fn, x, w)
-        sparse_fn = jax.jit(lambda x: ops.sparse_linear_apply(
-            x, sl.nm, sl.outliers, backend="reference"))
+        sparse_fn = jax.jit(lambda x: ref.fused_sparse_linear_ref(
+            x, sl.nm.values, sl.nm.indices, sl.nm.m, sl.outliers.values,
+            sl.outliers.indices, sl.outliers.m))
         us_sparse = _time(sparse_fn, x)
 
         m_dense = _modeled_us(b, out, kdim, sparse=False)

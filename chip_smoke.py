@@ -11,9 +11,14 @@ then ``submit``/``run``), with random weights made from ``--seed``:
      through the compiled Pallas paged-attention kernel, against the same
      compressed params served with slot KV (XLA attention), at the chunk
      step and at every decode step on identical inputs; a control plants a
-     one-block attention fault, which must fail the same bound;
-  C  the ``nm_spmm`` and ``fused_sparse_linear`` kernels, compiled, at the
-     ``w_up`` shape, bf16 and int8 values, against ``sparse_apply``.
+     one-block attention fault, which must fail the same bound; on the
+     chip every projection must take the sparse-linear kernel, by the
+     tracer's ``serving_sparse_projections_total``;
+  C  the sparse linear's kernel branch (``sparse_apply_pallas``, compiled)
+     against its portable branch (``sparse_apply_portable``), by name, at
+     internlm2's ``w_up`` and Mistral-7B's ``w_gate`` shapes, bf16 and int8
+     values, with and without outliers, at 8, 32 and 2048 rows; and the
+     time of one projection on each branch, in the served format.
 
 ``--four-chip`` runs only the multi-chip paths instead, on four chips:
 
@@ -36,6 +41,7 @@ import contextlib
 import dataclasses
 import importlib
 import json
+import os
 import sys
 import time
 from pathlib import Path
@@ -48,11 +54,10 @@ import numpy as np                                             # noqa: E402
 
 from repro import configs                                      # noqa: E402
 from repro.core import SparsifyConfig                          # noqa: E402
-from repro.kernels.fused_sparse_linear import fused_sparse_linear  # noqa: E402
-from repro.kernels.nm_spmm import nm_spmm                      # noqa: E402
 from repro.launch import serve                                 # noqa: E402
 from repro.models.sparse_serving import (_to_sparse_weight,    # noqa: E402
-                                         sparse_apply)
+                                         sparse_apply_pallas,
+                                         sparse_apply_portable)
 from repro.runtime.compile_cache import use_compile_cache      # noqa: E402
 from repro.serving import SamplingParams, Status               # noqa: E402
 
@@ -67,9 +72,9 @@ from repro.serving import SamplingParams, Status               # noqa: E402
 # one-block attention fault and fails unless it exceeds the bound (at
 # internlm2 widths with 2 layers on the CPU it measures 0.26).
 LOGIT_TOL = 0.05
-# Kernel tolerance of phase C, on the same measure: both sides read the
-# same stored values and round the output to bf16 once (2^-8); the int8
-# path of ``sparse_apply`` also rounds each dequantized weight to bf16.
+# Kernel tolerance of phase C, on the same measure: both branches multiply
+# the same bf16 weight (an int8 value scaled and rounded to bf16 once on
+# both) by the same bf16 rows, and round the output to bf16 once (2^-8).
 KERNEL_TOL = 0.02
 
 
@@ -83,8 +88,9 @@ class Preset:
     n_requests: int
     prompt_len: int
     gen: int
-    kernel_shape: tuple[int, int]         # [out, in] of the phase C weight
+    kernel_shapes: tuple[tuple[int, int], ...]   # [out, in] of phase C
     kernel_batches: tuple[int, ...]
+    timed: bool = False                   # time each branch in phase C
 
 
 def full_preset() -> Preset:
@@ -92,15 +98,17 @@ def full_preset() -> Preset:
     512-token prompts keep every engine at one chunk and one decode
     program."""
     cfg = configs.get("internlm2-1.8b")
+    mistral = configs.get("mistral-7b")
     return Preset(cfg, n_requests=8, prompt_len=512, gen=32,
-                  kernel_shape=(cfg.d_ff, cfg.d_model),
-                  kernel_batches=(8, 512))
+                  kernel_shapes=((cfg.d_ff, cfg.d_model),
+                                 (mistral.d_ff, mistral.d_model)),
+                  kernel_batches=(8, 32, 2048), timed=True)
 
 
 def smoke_preset() -> Preset:
     """The registered smoke config, for the CPU test of these phases."""
     return Preset(configs.get_smoke("internlm2-1.8b"), n_requests=4,
-                  prompt_len=16, gen=4, kernel_shape=(256, 512),
+                  prompt_len=16, gen=4, kernel_shapes=((256, 512),),
                   kernel_batches=(8,))
 
 
@@ -348,8 +356,19 @@ def phase_sparse(p: Preset, seed: int = 0, expect_kernel: bool = True) -> dict:
     prompts = _prompts(p, seed)
     max_len = p.prompt_len + p.gen
 
-    paged, _ = serve._build_target(cfg, params, paged_args, max_len=max_len)
+    # traced (the trace is never written) for the sparse-linear counter
+    traced_args = _serve_args(p, seed, "--sparse", "--kv-layout", "paged",
+                              "--trace-out", os.devnull)
+    paged, tracer = serve._build_target(cfg, params, traced_args,
+                                        max_len=max_len)
     got = _serve_recorded(paged, p, prompts)
+    series = tracer.registry.counter(
+        "serving_sparse_projections_total").series()
+    paths = {path: sum(v for key, v in series.items() if ("path", path) in key)
+             for path in ("kernel", "portable")}
+    if expect_kernel and not (paths["portable"] == 0 < paths["kernel"]):
+        raise SmokeFailure(f"B: sparse projections by path {paths}: not "
+                           f"every projection took the kernel")
     a = paged.adapter
     tokens = jnp.zeros((paged.pool.n_slots, 1), jnp.int32)
     kernel = _lowered_has_kernel(a._decode_fn.lower(
@@ -380,12 +399,14 @@ def phase_sparse(p: Preset, seed: int = 0, expect_kernel: bool = True) -> dict:
            "ratio": report["ratio"],
            "matrices": report["n_layers_sparsified"],
            "wall_s_with_compile": got.wall, "kernel_compiled": kernel,
+           "sparse_projections": paths,
            "logits": cmp, "control": control,
            "token_streams_identical": cmp["streams_identical"],
            "peak_bytes_in_use": _peak_bytes()}
     print(f"phase B 8:16+16:256/paged: {res['matrices']} matrices "
           f"{res['dense_bytes']} -> {res['compressed_bytes']} B "
           f"({res['ratio']:.4f}); paged kernel compiled={kernel}; "
+          f"sparse projections by path {paths}; "
           f"{got.wall:.1f}s wall incl. compile; paged vs slot logits "
           f"{_fmt_served(cmp)}; peak {res['peak_bytes_in_use']} B",
           flush=True)
@@ -397,54 +418,70 @@ def phase_sparse(p: Preset, seed: int = 0, expect_kernel: bool = True) -> dict:
     return res
 
 
+def _time_ms(fn, *args, reps: int = 20) -> float:
+    """Least mean device time of ``reps`` back-to-back calls over three
+    tries, after two warm calls (compilation excluded)."""
+    for _ in range(2):
+        jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            y = fn(*args)
+        jax.block_until_ready(y)
+        best = min(best, (time.perf_counter() - t0) / reps)
+    return best * 1e3
+
+
 def phase_kernels(p: Preset, seed: int = 0,
                   expect_kernel: bool = True) -> dict:
-    """C: the weight kernels, compiled, against ``sparse_apply``."""
-    out_dim, in_dim = p.kernel_shape
-    kw, kx = jax.random.split(jax.random.PRNGKey(seed + 2))
-    w = (jax.random.normal(kw, (out_dim, in_dim), jnp.float32)
-         / np.sqrt(in_dim)).astype(jnp.bfloat16)
+    """C: the sparse linear's kernel branch, compiled, against its
+    portable branch, and (``p.timed``) one projection's time on each."""
     scfg = SparsifyConfig(weight_pattern="8:16", outlier_pattern="16:256",
                           scorer="magnitude", use_smoothquant=False)
     nm_only = dataclasses.replace(scfg, outlier_pattern=None)
-    ref_fn = jax.jit(sparse_apply)
-
-    def nm(sw, x):
-        return nm_spmm(x, sw.nm_values, sw.nm_meta, n=sw.n, m=sw.m,
-                       scale=sw.v_scale)
-
-    def fused(sw, x):
-        return fused_sparse_linear(x, sw.nm_values, sw.nm_meta, sw.o_values,
-                                   sw.o_meta, n=sw.n, m=sw.m, o_n=sw.o_n,
-                                   scale=sw.v_scale)
-
-    rows = []
-    for quantize in (False, True):
-        weights = {"nm_spmm": (nm, _to_sparse_weight(w, nm_only,
-                                                     quantize=quantize)),
-                   "fused_sparse_linear": (fused, _to_sparse_weight(
-                       w, scfg, quantize=quantize))}
-        for b in p.kernel_batches:
-            x = jax.random.normal(jax.random.fold_in(kx, b), (b, in_dim),
-                                  jnp.bfloat16)
-            for name, (fn, sw) in weights.items():
-                kernel = jax.jit(fn)
-                if expect_kernel and not _lowered_has_kernel(
-                        kernel.lower(sw, x)):
-                    raise SmokeFailure(f"C: {name} did not compile to a "
-                                       f"Mosaic kernel")
-                tag = f"{name}[{'int8' if quantize else 'bf16'} b={b}]"
-                got = kernel(sw, x)
-                if got.shape != (b, out_dim):
-                    raise SmokeFailure(f"C: {tag} shape {got.shape}")
-                cmp = _compare(got, ref_fn(sw, x), KERNEL_TOL,
-                               f"C {tag} vs sparse_apply")
-                rows.append((tag, cmp))
+    kernel, portable = jax.jit(sparse_apply_pallas), jax.jit(
+        sparse_apply_portable)
+    rows, times = [], []
+    for out_dim, in_dim in p.kernel_shapes:
+        kw, kx = jax.random.split(jax.random.PRNGKey(seed + 2))
+        w = (jax.random.normal(kw, (out_dim, in_dim), jnp.float32)
+             / np.sqrt(in_dim)).astype(jnp.bfloat16)
+        for quantize in (False, True):
+            for fmt, c in (("nm", nm_only), ("nm+outliers", scfg)):
+                sw = _to_sparse_weight(w, c, quantize=quantize)
+                for b in p.kernel_batches:
+                    x = jax.random.normal(jax.random.fold_in(kx, b),
+                                          (b, in_dim), jnp.bfloat16)
+                    if expect_kernel and not _lowered_has_kernel(
+                            kernel.lower(sw, x)):
+                        raise SmokeFailure("C: sparse_apply_pallas did not "
+                                           "compile to a Mosaic kernel")
+                    tag = (f"[{out_dim},{in_dim}] {fmt} "
+                           f"{'int8' if quantize else 'bf16'} b={b}")
+                    got = kernel(sw, x)
+                    if got.shape != (b, out_dim):
+                        raise SmokeFailure(f"C: {tag} shape {got.shape}")
+                    cmp = _compare(got, portable(sw, x), KERNEL_TOL,
+                                   f"C {tag} kernel vs portable")
+                    rows.append((tag, cmp))
+                    if p.timed and fmt == "nm+outliers" and not quantize:
+                        t_k = _time_ms(kernel, sw, x)
+                        t_p = _time_ms(portable, sw, x)
+                        times.append({"shape": [out_dim, in_dim], "rows": b,
+                                      "kernel_ms": t_k, "portable_ms": t_p,
+                                      "speedup": t_p / t_k})
     worst = max(c["rel_err"] for _, c in rows)
-    print(f"phase C kernels at [{out_dim}, {in_dim}]: "
+    print("phase C kernel vs portable: "
           + "; ".join(f"{t} rel {c['rel_err']:.4g}" for t, c in rows)
           + f" (all <= {KERNEL_TOL}, compiled={expect_kernel})", flush=True)
-    return {"cases": {t: c for t, c in rows}, "worst_rel_err": worst}
+    for t in times:
+        print(f"phase C one projection {t['shape']} bf16 8:16+16:256 "
+              f"rows={t['rows']}: kernel {t['kernel_ms']:.4f} ms, portable "
+              f"one-hot {t['portable_ms']:.4f} ms ({t['speedup']:.2f}x)",
+              flush=True)
+    return {"cases": {t: c for t, c in rows}, "worst_rel_err": worst,
+            "timings": times}
 
 
 def phase_four_chip(p: Preset, seed: int = 0) -> dict:

@@ -7,7 +7,8 @@ import jax.numpy as jnp
 
 from repro.core import (ActStats, SparsifyConfig, sparsify_linear,
                         dense_effective_weight, Pattern)
-from repro.kernels import ops
+from repro.core.packing import pack_outlier_meta
+from repro.kernels.fused_sparse_linear import fused_sparse_linear
 
 key = jax.random.PRNGKey(0)
 
@@ -37,7 +38,10 @@ print(f"salient fraction  : {float(sl.salient_mask.mean()):.4f} "
 
 # --- deployment: y = x @ (W_nm + outliers)^T via the fused sparse kernel ---
 x = jax.random.normal(jax.random.PRNGKey(2), (8, 2048))
-y_kernel = ops.sparse_linear_apply(x, sl.nm, sl.outliers, backend="pallas")
+y_kernel = fused_sparse_linear(x, sl.nm.values, sl.nm.packed_metadata(),
+                               sl.outliers.values,
+                               pack_outlier_meta(sl.outliers.indices),
+                               n=8, m=16, o_n=16)
 y_dense = x @ dense_effective_weight(W, sl, cfg).T
 print(f"fused kernel error: {float(jnp.abs(y_kernel - y_dense).max()):.2e}")
 

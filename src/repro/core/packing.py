@@ -7,6 +7,10 @@ The deployable layout (consumed by the Pallas kernels):
   packed   : [out, in/M]    int32  the same indices packed 4 bits each
                                    (valid for M <= 16, N <= 8 -> one word)
 
+and for N:256 structured outliers (``pack_outlier_meta``):
+
+  meta     : [out, in/256, N/4] int32  indices packed 8 bits x4 per word
+
 ``bits_per_element`` accounting reproduces paper Table 1:
   2:4  -> 0.75   (ceil(log2 6)=3 bits / 4)
   4:8  -> 0.8125 (two blocks share a 13-bit code: ceil(2*log2 70)=13 / 16)
@@ -67,6 +71,24 @@ def unpack_metadata(packed: jax.Array, n: int) -> jax.Array:
     """Inverse of PackedNM.packed_metadata: int32 word -> [.., n] indices."""
     shifts = (4 * jnp.arange(n, dtype=jnp.int32))
     return (packed[..., None] >> shifts) & 0xF
+
+
+def pack_outlier_meta(indices: jax.Array) -> jax.Array:
+    """[out, nb, n] int32 (0..255) -> [out, nb, n//4] int32, 8 bits each,
+    index t in bits 8*(t % 4) of word t // 4."""
+    out, nb, n = indices.shape
+    assert n % 4 == 0
+    grouped = indices.reshape(out, nb, n // 4, 4)
+    shifts = 8 * jnp.arange(4, dtype=jnp.int32)
+    return jnp.sum(grouped << shifts[None, None, None, :],
+                   axis=-1).astype(jnp.int32)
+
+
+def unpack_outlier_meta(meta: jax.Array, n: int) -> jax.Array:
+    """Inverse of ``pack_outlier_meta``: [..., n//4] int32 -> [..., n]."""
+    shifts = 8 * jnp.arange(4, dtype=jnp.int32)
+    idx = (meta[..., None] >> shifts) & 0xFF
+    return idx.reshape(*meta.shape[:-1], n)
 
 
 def pack_nm(w_pruned: jax.Array, mask: jax.Array, pattern) -> PackedNM:

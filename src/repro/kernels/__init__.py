@@ -1,6 +1,7 @@
-"""Pallas TPU kernels for N:M-compressed weights (``nm_spmm``,
-``outlier_spmm``, ``fused_sparse_linear``), their jnp oracles (``ref``) and
-the public entry points (``ops``)."""
+"""The Pallas TPU kernel for N:M-compressed weights
+(``fused_sparse_linear``, with or without its outlier stream, served
+through ``models/sparse_serving.sparse_apply``) and the jnp oracles the
+tests hold it to (``ref``)."""
 import jax
 
 
@@ -11,3 +12,4 @@ def resolve_interpret(interpret: bool | None) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
+

@@ -1,8 +1,9 @@
-"""Pure-jnp oracles for the sparse kernels.
+"""Pure-jnp oracles for the sparse-linear kernel.
 
-These are the ground truth the Pallas kernels are validated against
-(tests/test_kernels_*.py sweep shapes & dtypes with assert_allclose), and the
-portable fallback used on backends without Pallas support.
+The ground truth the Pallas kernel is validated against (tests/test_kernels.py
+sweeps shapes and dtypes with assert_allclose), written from the unpacked
+indices so they share no decoding with the kernel.  The served portable path
+is ``models/sparse_serving.sparse_apply_portable``.
 """
 from __future__ import annotations
 

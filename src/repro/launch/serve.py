@@ -2,10 +2,11 @@
 
 The sparse path is the paper's deployment story: linear weights are replaced
 by their 8:16 (+N:256 outlier) compressed form at load time
-(models/sparse_serving.py).  Every backend serves them through
-``sparse_apply``, which rebuilds each dense weight from the compressed
-buffers by a one-hot einsum inside the step; the Pallas kernels in
-``kernels/`` are not on the served path yet.
+(models/sparse_serving.py), and served through ``sparse_apply``: on a
+TPU the fused Pallas kernel (``kernels/fused_sparse_linear.py``)
+decompresses each weight tile in VMEM once per call; elsewhere, and on a
+``--mesh``, the portable path rebuilds each dense weight by a one-hot
+einsum inside the step.
 
 Modes:
   default      continuous-batching engine (serving/) for every zoo family —

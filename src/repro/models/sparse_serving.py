@@ -8,26 +8,31 @@ reads compressed bytes from HBM:
   8:16 + 16:256 outliers, bf16:   1.30 B/elem  vs dense 2 B/elem (1.54x)
 
 ``layers.linear`` dispatches on this type, so every model in the zoo serves
-sparse without code changes.  The served path is ``sparse_apply`` on every
-backend, the TPU included: it unpacks the metadata with bit ops and rebuilds
-the dense weight by a one-hot einsum inside the step.  The fused Pallas
-kernel (``sparse_apply_pallas``) computes the same product from the packed
-buffers and is tested against it, but nothing serves through it yet.
+sparse without code changes.  ``sparse_apply`` is the one entry.  On a TPU
+it computes the product with the fused Pallas kernel
+(``kernels/fused_sparse_linear``), which decompresses each weight tile in
+VMEM once per call; elsewhere, and inside a program partitioned over a
+mesh, it takes the portable path, which unpacks the metadata with bit ops
+and rebuilds the dense weight by a one-hot einsum.  ``kernel_serves``
+alone decides between them.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from ..core.packing import PackedNM, pack_nm, unpack_metadata
+from ..core.packing import (PackedNM, pack_nm, pack_outlier_meta,
+                            unpack_metadata, unpack_outlier_meta)
 from ..core.outliers import StructuredOutliers
 from ..core.pipeline import SparsifyConfig, sparsify_linear
 from ..core.patterns import parse_pattern
 from ..core import scoring
+from ..parallel import policy as pol
 
 
 @jax.tree_util.register_dataclass
@@ -69,17 +74,49 @@ class SparseWeight:
                              self.o_meta, self.v_scale) if v is not None)
 
 
-def _unpack_8bit(meta: jax.Array, n: int) -> jax.Array:
-    shifts = 8 * jnp.arange(4, dtype=jnp.int32)
-    idx = (meta[..., None] >> shifts) & 0xFF
-    return idx.reshape(*meta.shape[:-1], n)
+def kernel_serves(sw: SparseWeight) -> bool:
+    """Whether ``sparse_apply`` computes ``sw`` (one layer's weight, or a
+    stack of them) with the fused kernel: on a TPU backend, where the out
+    dim fills 128-lane tiles and the in dim splits into 256-wide outlier
+    groups, or, without outliers, into whole rows of 8 m-blocks.  Inside a
+    program partitioned over a mesh (``policy.partitioned``: the serving
+    placement's jits, a dry run) it keeps the portable path, since the
+    partitioner would gather a Pallas call's operands onto every device."""
+    if jax.default_backend() != "tpu" or pol.partitioned():
+        return False
+    in_tile = 256 if sw.o_values is not None else 8 * sw.m
+    return sw.nm_values.shape[-2] % 128 == 0 and sw.in_dim % in_tile == 0
+
+
+def projection_paths(params, partitioned_program: bool = False
+                     ) -> dict[str, int]:
+    """Projection applications in one call of a step program over
+    ``params``, by the path ``sparse_apply`` takes for each: every
+    SparseWeight counts once per layer it stacks (layers x kinds)."""
+    counts = {"kernel": 0, "portable": 0}
+    with pol.suspended(partitioned=partitioned_program):
+        for leaf in jax.tree_util.tree_leaves(
+                params, is_leaf=lambda x: isinstance(x, SparseWeight)):
+            if isinstance(leaf, SparseWeight):
+                path = "kernel" if kernel_serves(leaf) else "portable"
+                counts[path] += math.prod(leaf.nm_values.shape[:-2])
+    return counts
 
 
 def sparse_apply(sw: SparseWeight, x: jax.Array) -> jax.Array:
-    """y = x @ W_hat^T from compressed buffers (portable path).  Its ops
-    carry the scope ``sparse_linear`` in their op-name metadata (a device
-    profile's ``tf_op``), so a profile finds them; the compiled code is
-    the same without it."""
+    """y = x @ W_hat^T from compressed buffers: the fused kernel where
+    ``kernel_serves``, the portable one-hot rebuild elsewhere.  The portable
+    path's ops carry the scope ``sparse_linear`` in their op-name metadata
+    (a device profile's ``tf_op``); the kernel is named ``sparse_linear``
+    on the device."""
+    if kernel_serves(sw):
+        return sparse_apply_pallas(sw, x)
+    return sparse_apply_portable(sw, x)
+
+
+def sparse_apply_portable(sw: SparseWeight, x: jax.Array) -> jax.Array:
+    """The portable branch of ``sparse_apply``: unpack the metadata, rebuild
+    the dense weight by one-hot einsums, multiply."""
     with jax.named_scope("sparse_linear"):
         out = sw.nm_values.shape[-2]
         nb = sw.in_dim // sw.m
@@ -93,7 +130,7 @@ def sparse_apply(sw: SparseWeight, x: jax.Array) -> jax.Array:
         onehot = jax.nn.one_hot(idx, sw.m, dtype=vals.dtype)
         w = jnp.einsum("obn,obnm->obm", vals, onehot).reshape(out, sw.in_dim)
         if sw.o_values is not None:
-            o_idx = _unpack_8bit(sw.o_meta, sw.o_n)
+            o_idx = unpack_outlier_meta(sw.o_meta, sw.o_n)
             o_onehot = jax.nn.one_hot(o_idx, 256, dtype=sw.o_values.dtype)
             w = w + jnp.einsum("obn,obnm->obm", sw.o_values, o_onehot
                                ).reshape(out, sw.in_dim)
@@ -101,21 +138,18 @@ def sparse_apply(sw: SparseWeight, x: jax.Array) -> jax.Array:
 
 
 def sparse_apply_pallas(sw: SparseWeight, x: jax.Array) -> jax.Array:
-    """TPU path: fused Pallas kernel on the packed buffers.  int8 values
-    stream quantized all the way into VMEM; the per-row scale rides as a
-    kernel operand and dequantizes in-register after the gather."""
+    """The kernel branch of ``sparse_apply``: the fused Pallas kernel on the
+    packed buffers (compiled on a TPU, interpreted elsewhere).  int8 values
+    stream quantized into VMEM and are scaled there; a weight without
+    outliers runs the same kernel without the outlier stream."""
     from ..kernels.fused_sparse_linear import fused_sparse_linear
-    from ..kernels.nm_spmm import nm_spmm
     scale = None if sw.v_scale is None else sw.v_scale.astype(jnp.float32)
     lead = x.shape[:-1]
-    x2 = x.reshape(-1, sw.in_dim)
-    if sw.o_values is None:
-        y = nm_spmm(x2, sw.nm_values, sw.nm_meta, n=sw.n, m=sw.m,
-                    scale=scale)
-    else:
-        y = fused_sparse_linear(x2, sw.nm_values, sw.nm_meta, sw.o_values,
-                                sw.o_meta, n=sw.n, m=sw.m, o_n=sw.o_n,
-                                scale=scale)
+    out = sw.nm_values.shape[-2]
+    y = fused_sparse_linear(x.reshape(-1, sw.in_dim), sw.nm_values,
+                            sw.nm_meta, sw.o_values, sw.o_meta, n=sw.n,
+                            m=sw.m, o_n=sw.o_n, scale=scale,
+                            block_o=256 if out % 256 == 0 else 128)
     return y.reshape(*lead, -1)
 
 
@@ -145,7 +179,7 @@ def densify(sw: SparseWeight) -> jax.Array:
     w = jnp.einsum("...bn,...bnm->...bm", vals, onehot
                    ).reshape(*lead, sw.in_dim)
     if sw.o_values is not None:
-        o_idx = _unpack_8bit(sw.o_meta, sw.o_n)
+        o_idx = unpack_outlier_meta(sw.o_meta, sw.o_n)
         o_onehot = jax.nn.one_hot(o_idx, 256, dtype=sw.o_values.dtype)
         w = w + jnp.einsum("...bn,...bnm->...bm", sw.o_values, o_onehot
                            ).reshape(*lead, sw.in_dim)
@@ -175,7 +209,6 @@ def _to_sparse_weight(w2d: jax.Array, scfg: SparsifyConfig,
     sl = sparsify_linear(w2d, stats, scfg)
     nm = sl.nm
     o = sl.outliers
-    from ..kernels.outlier_spmm import pack_outlier_meta
     values, v_scale = nm.values, None
     if quantize:
         absmax = jnp.max(jnp.abs(values.astype(jnp.float32)), axis=-1)

@@ -9,6 +9,12 @@ constraint is applied with divisibility checks; with no policy it's a no-op
 
 Symbolic axes: "fsdp" -> ("pod","data") / ("data",), "model" -> "model",
 None -> replicated.
+
+The same state says whether the code being traced belongs to a program
+partitioned over a mesh (``partitioned``): under ``activation_sharding``,
+or under ``suspended(partitioned=True)`` as the serving placement's jits
+trace.  Code with no partitioning rule (a Pallas call, which the
+partitioner would gather onto every device) asks it.
 """
 from __future__ import annotations
 
@@ -30,17 +36,21 @@ def _current():
 def activation_sharding(mesh: Mesh, seq_shard: bool = False):
     """Enable activation constraints for code traced within this context."""
     fsdp = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-    prev = _current()
+    prev = _current(), partitioned()
     _STATE.policy = {"mesh": mesh, "fsdp": fsdp, "seq_shard": seq_shard}
+    _STATE.partitioned = True
     try:
         yield
     finally:
-        _STATE.policy = prev
+        _STATE.policy, _STATE.partitioned = prev
 
 
 @contextlib.contextmanager
-def suspended():
-    """Deactivate any ambient policy for code traced within this context.
+def suspended(partitioned: bool = False):
+    """Deactivate any ambient policy for code traced within this context;
+    ``partitioned`` says whether that code is a program partitioned over a
+    mesh (the serving placement's jits with explicit shardings) or one on a
+    single device.
 
     The serving placement layer (serving/placement.py) shards through
     explicit jit in/out shardings instead of activation constraints, and it
@@ -49,12 +59,18 @@ def suspended():
     path (models/moe.py), where prefill bucket padding competes with real
     tokens for expert capacity and token streams stop being batch-invariant.
     """
-    prev = _current()
-    _STATE.policy = None
+    prev = _current(), getattr(_STATE, "partitioned", False)
+    _STATE.policy, _STATE.partitioned = None, partitioned
     try:
         yield
     finally:
-        _STATE.policy = prev
+        _STATE.policy, _STATE.partitioned = prev
+
+
+def partitioned() -> bool:
+    """Whether the code being traced belongs to a program partitioned over
+    a mesh (see the module docstring)."""
+    return getattr(_STATE, "partitioned", False)
 
 
 def _resolve(mesh, fsdp, axes, shape):

@@ -111,6 +111,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import sparse_serving
 from . import families
 from .cache_pool import CachePoolError
 from .observe import NULL_TRACER, span
@@ -174,6 +175,10 @@ class ServingEngine:
         self.kv_layout = kv_layout
         self.kv_dtype = kv_dtype
         self.pool = self.adapter.pool
+        # projection applications per step-program call, by the path the
+        # sparse linear takes (read by the tracer's counter)
+        self.sparse_paths = sparse_serving.projection_paths(
+            self.params, self.placement.active)
         # kept for introspection and the compiled-cost tests
         self._step_fn = self.adapter._step_fn
         self._decode_fn = self.adapter._decode_fn
@@ -611,6 +616,8 @@ class ServingEngine:
         logits = self.adapter.step_chunk(
             rows, jnp.asarray(lanes), jnp.asarray(cur), jnp.asarray(n_new),
             jnp.asarray(tokens))
+        if self.tracer.enabled:
+            self.tracer.on_step_program(self.sparse_paths)
         self.pool.advance_prefill(rows, [cursor + t for t in takes])
         stats["prefill_tokens"] += sum(takes)
         stats["prefill_chunks"] += n
@@ -713,6 +720,8 @@ class ServingEngine:
             stats["decoded"] = len(active)
             tokens = jnp.asarray(self._last_token[:, None])
             logits = self.adapter.step_decode(tokens, active)
+            if self.tracer.enabled:
+                self.tracer.on_step_program(self.sparse_paths)
             self._slot_logits = logits[:, 0].astype(jnp.float32)
         n_finished = self._emit_tokens(active)
         advanced = np.zeros((self.pool.n_slots,), bool)
@@ -802,6 +811,8 @@ class ServingEngine:
             logits = self.adapter.step_chunk(
                 active, jnp.asarray(lanes), jnp.asarray(cur),
                 jnp.asarray(n_new), jnp.asarray(tokens))
+            if tr.enabled:
+                tr.on_step_program(self.sparse_paths)
             # leave-one-in verification over the whole (bucketed) batch —
             # pad lanes verify slot 0's parameters against garbage and are
             # never read, keeping verify_draft's compiled shapes on the same

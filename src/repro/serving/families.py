@@ -76,14 +76,17 @@ from .state_pool import (EncDecPoolView, EncoderContextPool, HybridPoolView,
                          RecurrentStatePool, RecurrentStateView)
 
 
-def _suspend(fn):
+def _suspend(fn, partitioned=False):
     """Trace ``fn`` under a suspended activation-sharding policy (see the
     engine docstring: an ambient policy would flip MoE onto the
     capacity-bounded expert-parallel path where pad tokens evict real
-    ones).  Every step program lowers as ``jit_serve_step``: profiler
-    traces and the benchmark find the programs by that name."""
+    ones), marked partitioned for a program partitioned over a mesh
+    (``policy.partitioned``: the sparse linear keeps its portable path
+    there, ``sparse_serving.kernel_serves``).  Every step
+    program lowers as ``jit_serve_step``: profiler traces and the benchmark
+    find the programs by that name."""
     def serve_step(*args):
-        with pol.suspended():
+        with pol.suspended(partitioned):
             return fn(*args)
     return serve_step
 
@@ -92,7 +95,7 @@ def _jit(placement, fn, donate=(), in_shardings=None, out_shardings=None):
     """jit with explicit shardings on a mesh, a plain jit otherwise."""
     if not placement.active:
         return jax.jit(_suspend(fn), donate_argnums=donate)
-    return jax.jit(_suspend(fn), donate_argnums=donate,
+    return jax.jit(_suspend(fn, partitioned=True), donate_argnums=donate,
                    in_shardings=in_shardings, out_shardings=out_shardings)
 
 

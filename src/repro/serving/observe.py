@@ -23,9 +23,11 @@ and formats no string.  The names, with their nesting:
                           stay in ``serve.draft``)
 
 The step programs themselves lower as ``jit_serve_step``
-(``families._suspend``), and the sparse linear's device ops carry the
-scope ``sparse_linear`` in their op-name metadata, which a device profile
-keeps as each op's ``tf_op`` (``models/sparse_serving.sparse_apply``).
+(``families._suspend``).  On a TPU the sparse linear is one Pallas kernel
+per projection, named ``sparse_linear`` on the device; its portable path's
+ops carry the scope ``sparse_linear`` in their op-name metadata, which a
+device profile keeps as each op's ``tf_op``
+(``models/sparse_serving.sparse_apply``).
 
 **The engine's tracer.**  The engine takes a ``tracer=``.  The default
 is ``NULL_TRACER``, a singleton whose every hook is a no-op and whose
@@ -60,6 +62,9 @@ identity and the token-identity of traced vs untraced runs).  Pass a
    ``serve.draft``), prefix-cache lookups/hit-tokens, the paged-attention
    kernel's live and skipped KV steps per decode step (the kernel's own
    geometry over the positions mirror, ``paged_attention.kv_steps``),
+   the sparse projections each step-program call applies, by the path
+   the sparse linear takes (``sparse_serving.projection_paths``, counted
+   once at engine build),
    speculative draft/accept/emit token counters (plus a per-step
    acceptance-rate gauge — the live Divergent-Token probe), and per-step
    gauges (queue depth, running, pool occupancy, budget utilization) —
@@ -113,6 +118,9 @@ class NullTracer:
         pass
 
     def on_decode_kv(self, walked, grid):
+        pass
+
+    def on_step_program(self, paths):
         pass
 
     def on_prefill_complete(self, req):
@@ -299,6 +307,11 @@ class ServingTracer:
             "KV steps of one layer's paged-attention kernel per decode "
             "step (every layer walks the same): live ones it walks, "
             "skipped ones past what each row can see")
+        self.c_sparse = r.counter(
+            "serving_sparse_projections_total",
+            "sparse projection applications in the engine's step-program "
+            "calls, by the path the sparse linear took (the fused kernel "
+            "or the portable one-hot rebuild; counted at engine build)")
         self.g_spec_accept = r.gauge(
             "serving_spec_acceptance_rate",
             "per-step draft acceptance rate (accepted / drafted)")
@@ -426,6 +439,13 @@ class ServingTracer:
         lb = self._labels()
         self.c_kv_steps.inc(walked, kind="live", **lb)
         self.c_kv_steps.inc(grid - walked, kind="skipped", **lb)
+
+    def on_step_program(self, paths: dict) -> None:
+        """Count one step-program call's sparse projections by path
+        (``ServingEngine.sparse_paths``)."""
+        lb = self._labels()
+        for path, n in paths.items():
+            self.c_sparse.inc(n, path=path, **lb)
 
     def on_prefill_complete(self, req) -> None:
         t = self.clock()

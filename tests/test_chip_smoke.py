@@ -52,6 +52,9 @@ def test_phase_sparse_runs_the_paged_kernel(cs, preset, monkeypatch):
     # decode steps are compared too, and the planted fault is caught
     assert res["logits"]["decode_rows"] > 0
     assert res["control"]["rel_err"] > cs.LOGIT_TOL
+    # the CPU serves every projection on the portable path
+    assert res["sparse_projections"]["kernel"] == 0
+    assert res["sparse_projections"]["portable"] > 0
 
 
 def test_phase_kernels(cs, preset):

@@ -1,17 +1,17 @@
-"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles."""
+"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
+the kernel against the portable path of ``sparse_apply``, the choice
+between them, and the kernel's traced size."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import (ActStats, SparsifyConfig, sparsify_linear,
                         dense_effective_weight, pack_nm, nm_mask)
+from repro.core.packing import pack_outlier_meta, unpack_outlier_meta
 from repro.kernels import ref
-from repro.kernels.nm_spmm import nm_spmm
-from repro.kernels.outlier_spmm import (outlier_spmm, pack_outlier_meta,
-                                        unpack_outlier_meta)
 from repro.kernels.fused_sparse_linear import fused_sparse_linear
-from repro.kernels import ops
 
 
 def _packed(key, out, kdim, n, m, dtype):
@@ -33,8 +33,8 @@ class TestNmSpmm:
         pk = _packed(jax.random.PRNGKey(0), out, kdim, n, m, dtype)
         x = jax.random.normal(jax.random.PRNGKey(1), (b, kdim)).astype(dtype)
         y_ref = ref.nm_spmm_ref(x, pk.values, pk.indices, m)
-        y = nm_spmm(x, pk.values, pk.packed_metadata(), n=n, m=m,
-                    block_b=64, block_o=64, block_k=256)
+        y = fused_sparse_linear(x, pk.values, pk.packed_metadata(), n=n, m=m,
+                                block_b=64, block_o=64, block_k=256)
         np.testing.assert_allclose(np.asarray(y, np.float32),
                                    np.asarray(y_ref, np.float32), **TOL[dtype])
 
@@ -43,8 +43,8 @@ class TestNmSpmm:
         pk = _packed(jax.random.PRNGKey(2), 64, 512, 8, 16, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(3), (16, 512))
         y_dense = x @ pk.to_dense().T
-        y = nm_spmm(x, pk.values, pk.packed_metadata(), n=8, m=16,
-                    block_b=16, block_o=64, block_k=256)
+        y = fused_sparse_linear(x, pk.values, pk.packed_metadata(), n=8,
+                                m=16, block_b=16, block_o=64, block_k=256)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_dense),
                                    rtol=2e-5, atol=2e-4)
 
@@ -53,15 +53,19 @@ class TestOutlierSpmm:
     @pytest.mark.parametrize("o_n", [4, 8, 16])
     @pytest.mark.parametrize("b,out,kdim", [(8, 64, 256), (16, 128, 512)])
     def test_vs_ref(self, o_n, b, out, kdim):
+        """The kernel's outlier stream alone (its N:M values zeroed)
+        against the outlier oracle."""
         w = jax.random.normal(jax.random.PRNGKey(0), (out, kdim))
         x = jax.random.normal(jax.random.PRNGKey(1), (b, kdim))
         st = ActStats.init(kdim).update(x)
         cfg = SparsifyConfig(weight_pattern="8:16", outlier_pattern=f"{o_n}:256")
         sl = sparsify_linear(w, st, cfg)
         y_ref = ref.outlier_spmm_ref(x, sl.outliers.values, sl.outliers.indices)
-        y = outlier_spmm(x, sl.outliers.values,
-                         pack_outlier_meta(sl.outliers.indices), n=o_n,
-                         block_b=8, block_o=64, block_k=256)
+        y = fused_sparse_linear(x, jnp.zeros_like(sl.nm.values),
+                                sl.nm.packed_metadata(), sl.outliers.values,
+                                pack_outlier_meta(sl.outliers.indices),
+                                n=8, m=16, o_n=o_n, block_b=8, block_o=64,
+                                block_k=256)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=2e-5, atol=2e-4)
 
@@ -95,14 +99,68 @@ class TestFused:
                                    rtol=2e-5, atol=5e-4)
 
     def test_ops_backends_agree(self):
+        """``sparse_apply``'s two branches, the kernel and the portable
+        one-hot rebuild, compute the same product."""
+        from repro.models.sparse_serving import (_to_sparse_weight,
+                                                 sparse_apply_pallas,
+                                                 sparse_apply_portable)
         w = jax.random.normal(jax.random.PRNGKey(0), (64, 512))
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 512))
         st = ActStats.init(512).update(x)
-        sl = sparsify_linear(w, st, SparsifyConfig())
-        y_ref = ops.sparse_linear_apply(x, sl.nm, sl.outliers, backend="reference")
-        y_pl = ops.sparse_linear_apply(x, sl.nm, sl.outliers, backend="pallas")
-        np.testing.assert_allclose(np.asarray(y_pl), np.asarray(y_ref),
+        sw = _to_sparse_weight(w, SparsifyConfig(), stats=st)
+        np.testing.assert_allclose(np.asarray(sparse_apply_pallas(sw, x)),
+                                   np.asarray(sparse_apply_portable(sw, x)),
                                    rtol=2e-5, atol=5e-4)
+
+
+# served projection shapes [out, in]: internlm2-1.8b and Mistral-7B
+SERVED = [(8192, 2048), (2048, 8192), (2048, 2048), (1024, 2048),
+          (14336, 4096), (4096, 14336), (4096, 4096), (1024, 4096)]
+
+
+def _weight_shapes(out, kdim, quantize=False, outliers=True, o_n=16):
+    """A SparseWeight of shapes only, in the stored format."""
+    from repro.models.sparse_serving import SparseWeight
+    sds = jax.ShapeDtypeStruct
+    return SparseWeight(
+        nm_values=sds((out, kdim // 2), jnp.int8 if quantize
+                      else jnp.bfloat16),
+        nm_meta=sds((out, kdim // 16), jnp.int32),
+        o_values=sds((out, kdim // 256, o_n), jnp.bfloat16) if outliers
+        else None,
+        o_meta=sds((out, kdim // 256, o_n // 4), jnp.int32) if outliers
+        else None,
+        v_scale=sds((out,), jnp.float32) if quantize else None,
+        n=8, m=16, o_n=o_n if outliers else 0, in_dim=kdim)
+
+
+def _kernel_eqns(closed) -> int:
+    """Equations of the one Pallas kernel body in a traced program,
+    counted through every nested jaxpr (loops, conditionals)."""
+    from jax.extend import core as jcore
+
+    def subjaxprs(eqn):
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield sub.jaxpr
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield sub
+
+    def count(jaxpr):
+        return sum(1 + sum(count(j) for j in subjaxprs(e))
+                   for e in jaxpr.eqns)
+
+    def kernels(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["jaxpr"]
+            else:
+                for j in subjaxprs(e):
+                    yield from kernels(j)
+
+    (body,) = kernels(closed.jaxpr)
+    return count(body)
 
 
 class TestSparseServing:
@@ -121,6 +179,140 @@ class TestSparseServing:
                                    np.asarray(y_dense), rtol=2e-5, atol=5e-4)
         np.testing.assert_allclose(np.asarray(sparse_apply_pallas(sw, x)),
                                    np.asarray(y_dense), rtol=2e-5, atol=5e-4)
+
+    @pytest.mark.parametrize("quantize", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("outliers", [True, False],
+                             ids=["outliers", "nm"])
+    @pytest.mark.parametrize("rows", [1, 7, 32, 40])
+    def test_kernel_equals_portable(self, rows, outliers, quantize):
+        """The kernel on bf16 rows equals the portable path: over two
+        128-lane slabs of one out tile and two k tiles, at 1, 7, 32 and 40
+        rows in 16-row tiles (1, 7 and 40 padded; 32 and 40 take two and
+        three row tiles, which reuse the tile decompressed for the first)."""
+        from repro.models.sparse_serving import (_to_sparse_weight,
+                                                 sparse_apply_portable)
+        w = jax.random.normal(jax.random.PRNGKey(0), (256, 512)) * 0.05
+        x = jax.random.normal(jax.random.PRNGKey(rows), (rows, 512)
+                              ).astype(jnp.bfloat16)
+        cfg = SparsifyConfig(scorer="magnitude", use_smoothquant=False,
+                             outlier_pattern="16:256" if outliers else None)
+        sw = _to_sparse_weight(w.astype(jnp.bfloat16), cfg,
+                               quantize=quantize)
+        y = fused_sparse_linear(
+            x, sw.nm_values, sw.nm_meta, sw.o_values, sw.o_meta, n=sw.n,
+            m=sw.m, o_n=sw.o_n, scale=sw.v_scale, block_b=16, block_o=256,
+            block_k=256)
+        assert y.shape == (rows, 256) and y.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(y, np.float32),
+            np.asarray(sparse_apply_portable(sw, x), np.float32),
+            **TOL[jnp.bfloat16])
+
+    @pytest.mark.parametrize("quantize", [False, True], ids=["bf16", "int8"])
+    def test_whole_contraction_equals_portable(self, quantize):
+        """A contraction dim that 2048 does not divide is one k tile, with
+        the out and row tiles narrowed (two of each here), and equals the
+        portable path."""
+        from repro.models.sparse_serving import (_to_sparse_weight,
+                                                 sparse_apply_portable)
+        w = jax.random.normal(jax.random.PRNGKey(0), (256, 2304)) * 0.05
+        x = jax.random.normal(jax.random.PRNGKey(1), (512, 2304)
+                              ).astype(jnp.bfloat16)
+        cfg = SparsifyConfig(scorer="magnitude", use_smoothquant=False)
+        sw = _to_sparse_weight(w.astype(jnp.bfloat16), cfg,
+                               quantize=quantize)
+        y = fused_sparse_linear(x, sw.nm_values, sw.nm_meta, sw.o_values,
+                                sw.o_meta, n=sw.n, m=sw.m, o_n=sw.o_n,
+                                scale=sw.v_scale)
+        np.testing.assert_allclose(
+            np.asarray(y, np.float32),
+            np.asarray(sparse_apply_portable(sw, x), np.float32),
+            **TOL[jnp.bfloat16])
+
+    def test_kernel_traced_size_is_constant(self):
+        """Every step program traces and lowers the kernel once per
+        projection in every process, before the compile cache is looked
+        up, so its body must stay small: at internlm2's w_up with outliers
+        it has under 200 equations (an unrolled body had over 2000), and
+        the same count at 32 outliers per group and at either out tile."""
+        def eqns(o_n, block_o, out=8192, kdim=2048):
+            sw = _weight_shapes(out, kdim, o_n=o_n)
+
+            def f(x, v, meta, ov, om):
+                return fused_sparse_linear(x, v, meta, ov, om, n=8, m=16,
+                                           o_n=o_n, block_o=block_o,
+                                           interpret=False)
+            closed = jax.jit(f).trace(
+                jax.ShapeDtypeStruct((32, kdim), jnp.bfloat16),
+                sw.nm_values, sw.nm_meta, sw.o_values, sw.o_meta).jaxpr
+            return _kernel_eqns(closed)
+
+        base = eqns(16, 256)
+        assert base < 200
+        assert eqns(32, 256) == base
+        assert eqns(16, 128) == base
+        assert eqns(16, 256, out=2048, kdim=2048) == base
+
+    @pytest.mark.parametrize("out,kdim,outliers,quantize,serves", [
+        (8192, 2048, True, False, True),       # internlm2 w_up
+        (2048, 8192, True, True, True),        # w_down, int8 values
+        (1024, 2048, False, False, True),      # no outliers
+        (128, 128, False, False, True),        # narrow in, no outliers
+        (128, 64, False, False, False),        # in under 8 m-blocks
+        (64, 512, True, False, False),         # out under one lane tile
+        (1000, 2048, True, False, False),      # out not lane-aligned
+        (256, 384, False, False, True),        # odd in, no outliers
+    ])
+    def test_kernel_serves(self, out, kdim, outliers, quantize, serves,
+                           monkeypatch):
+        """On a TPU the kernel takes weights whose out dim fills 128-lane
+        tiles and whose in dim splits into 256-wide outlier groups (or
+        whole 8-block rows without outliers); the CPU and a program
+        partitioned over a mesh keep the portable path."""
+        from repro.models.sparse_serving import kernel_serves
+        from repro.parallel import policy as pol
+        sw = _weight_shapes(out, kdim, quantize, outliers)
+        assert not kernel_serves(sw)                  # this host is a CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert kernel_serves(sw) == serves
+        with pol.suspended(partitioned=True):         # placement's jits
+            assert not kernel_serves(sw)
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        with pol.activation_sharding(mesh):           # dry runs
+            assert not kernel_serves(sw)
+            with pol.suspended():                     # an engine inside
+                assert kernel_serves(sw) == serves
+
+    @pytest.mark.parametrize("out,kdim", SERVED)
+    def test_served_shapes_take_the_kernel(self, out, kdim, monkeypatch):
+        """Every served projection takes the kernel on a TPU, in every
+        stored format."""
+        from repro.models.sparse_serving import kernel_serves
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        for quantize, outliers in ((False, True), (True, True),
+                                   (False, False)):
+            assert kernel_serves(_weight_shapes(out, kdim, quantize,
+                                                outliers))
+
+    @pytest.mark.parametrize("name", ["internlm2-1.8b.s816",
+                                      "mistral-7b.s816"])
+    def test_projection_paths_of_served_models(self, name, monkeypatch):
+        """Layers x kinds per step-program call, all on the kernel on a TPU
+        and all portable on the CPU or under a mesh."""
+        from bench import harness, registry, weights
+        from repro.models.sparse_serving import projection_paths
+        conf = registry.config(name)
+        m = conf["model"]
+        params = jax.eval_shape(
+            weights.params_fn(m, harness.compressor(conf["weights"])),
+            jax.random.PRNGKey(0))
+        n = 7 * m["n_layers"]
+        assert projection_paths(params) == {"kernel": 0, "portable": n}
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert projection_paths(params) == {"kernel": n, "portable": 0}
+        assert projection_paths(params, partitioned_program=True) == {
+            "kernel": 0, "portable": n}
 
     def test_deployed_bytes_ratio(self):
         from repro.models.sparse_serving import _to_sparse_weight

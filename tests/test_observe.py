@@ -316,6 +316,29 @@ def test_paged_attn_kv_steps_counter(dense_params, window):
     assert steps.get(kind="skipped", **lb) == grid - live > 0
 
 
+def test_sparse_projections_counter(sparse_params):
+    """Each step-program call counts its sparse projections (layers x
+    kinds) by the path the sparse linear takes: the portable one here."""
+    tracer = ServingTracer()
+    engine = ServingEngine(CFG, sparse_params, tracer=tracer, n_slots=2,
+                           max_len=64)
+    calls = []
+    for name in ("step_chunk", "step_decode"):
+        def counted(*args, fn=getattr(engine.adapter, name)):
+            calls.append(1)
+            return fn(*args)
+        setattr(engine.adapter, name, counted)
+    for p in _prompts(2, 12):
+        engine.submit(p, SamplingParams(max_new_tokens=GEN))
+    engine.run()
+    per_call = CFG.n_layers * 7             # wq wk wv wo w_gate w_up w_down
+    assert engine.sparse_paths == {"kernel": 0, "portable": per_call}
+    lb = dict(engine=tracer.name, family="dense")
+    c = tracer.registry.counter("serving_sparse_projections_total")
+    assert c.get(path="portable", **lb) == per_call * len(calls) > 0
+    assert c.get(path="kernel", **lb) == 0
+
+
 # --------------------------------------------------------------------------
 # phase spans on the profiler's clock; the step programs' names
 # --------------------------------------------------------------------------

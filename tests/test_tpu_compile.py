@@ -3,7 +3,8 @@
 Interpret mode checks what a kernel computes; only the chip's compiler
 checks that its blocks are tile-aligned and that it fits scoped VMEM.  Each
 test lowers one kernel variant that the TPU dispatch can choose, at
-internlm2-1.8b widths (the paged kernel also at qwen3-8b widths, whose
+internlm2-1.8b widths (the sparse linear also at Mistral-7B's and
+LLaMA-2-7B/13B's, the paged kernel also at qwen3-8b widths, whose
 32 q heads make the widest query tiles of the zoo's attention configs),
 and compiles it for one chip of a described ``v5e:2x2``.  Two more compile a
 whole step program, Mistral-7B's decode and chunk steps at published
@@ -22,7 +23,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.kernels.fused_sparse_linear import fused_sparse_linear
-from repro.kernels.nm_spmm import nm_spmm
 from repro.serving.paged.paged_attention import paged_attention_pallas
 
 N, M, O_N = 8, 16, 16                       # 8:16 + 16:256 outliers
@@ -81,16 +81,17 @@ def test_nm_spmm_compiles(one_chip, out, kdim, quantized):
         shapes.append(((out,), jnp.float32))
 
     def fn(x, v, meta, *scale):
-        return nm_spmm(x, v, meta, n=N, m=M,
-                       scale=scale[0] if scale else None, interpret=False)
+        return fused_sparse_linear(x, v, meta, n=N, m=M,
+                                   scale=scale[0] if scale else None,
+                                   interpret=False)
 
     assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("batch", [8, 512])
-def test_fused_sparse_linear_compiles(one_chip, batch, quantized):
-    out, kdim = _WEIGHTS[0]
+_MISTRAL = configs.get("mistral-7b")
+
+
+def _compile_fused(one_chip, out, kdim, batch, quantized):
     vdt = jnp.int8 if quantized else jnp.bfloat16
     shapes = ([((batch, kdim), jnp.bfloat16)]
               + _weight_shapes(out, kdim, vdt)
@@ -104,7 +105,48 @@ def test_fused_sparse_linear_compiles(one_chip, batch, quantized):
                                    scale=scale[0] if scale else None,
                                    interpret=False)
 
-    assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
+    return _compile(one_chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("batch", [8, 512, 4096])
+def test_fused_sparse_linear_compiles(one_chip, batch, quantized):
+    out, kdim = _WEIGHTS[0]
+    assert "tpu_custom_call" in _compile_fused(one_chip, out, kdim, batch,
+                                               quantized)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("batch", [8, 4096])
+@pytest.mark.parametrize("out,kdim", [(_MISTRAL.d_ff, _MISTRAL.d_model),
+                                      (_MISTRAL.d_model, _MISTRAL.d_ff)],
+                         ids=["w_gate", "w_down"])
+def test_fused_sparse_linear_compiles_mistral(one_chip, out, kdim, batch,
+                                              quantized):
+    assert "tpu_custom_call" in _compile_fused(one_chip, out, kdim, batch,
+                                               quantized)
+
+
+_LLAMA2_7B, _LLAMA2_13B = configs.get("llama2-7b"), configs.get("llama2-13b")
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("batch", [4096])
+@pytest.mark.parametrize("out,kdim", [
+    (_LLAMA2_7B.d_model, _LLAMA2_7B.d_ff),
+    (_LLAMA2_13B.d_model, _LLAMA2_13B.d_ff)],
+    ids=["7b_w_down", "13b_w_down"])
+def test_fused_sparse_linear_compiles_llama2(one_chip, out, kdim, batch,
+                                             quantized, monkeypatch):
+    """The paper's LLaMA-2 widths, whose contraction dims 2048 does not
+    divide (11008, 13824), so ``k_tile`` takes them whole and the
+    kernel narrows its out and row tiles: each compiles within a third of
+    the scoped VMEM the kernel asks for (the 13B w_down at 4096 rows took
+    86 MiB with the tiles a 2048-wide k tile uses)."""
+    from repro.kernels import fused_sparse_linear as fsl
+    monkeypatch.setattr(fsl, "VMEM_LIMIT", fsl.VMEM_LIMIT // 3)
+    assert "tpu_custom_call" in _compile_fused(one_chip, out, kdim, batch,
+                                               quantized)
 
 
 def _paged_shapes(cfg, S, quantized, batch=8, n_blocks=256, per_row=40,
@@ -141,16 +183,20 @@ HEADROOM = 0.5e9
 
 
 @pytest.mark.parametrize("S", [1, 2048], ids=["decode", "chunk2048"])
-def test_mistral_7b_step_fits_one_chip(one_chip, S):
+def test_mistral_7b_step_fits_one_chip(one_chip, S, monkeypatch):
     """Mistral-7B at published widths, 8:16 + 16:256 (the benchmark's
     ``mistral-7b.s816``), with its whole paged arena (2048 blocks of 16
     plus the trash block): the decode step over its 8 slots and a
     2048-token chunk step compile for one chip, leave at least
     ``HEADROOM`` of its HBM free beside their arguments, temporaries and
-    outputs, and copy no whole arena or layer of it (the arena is written
-    in place)."""
+    outputs, copy no whole arena or layer of it (the arena is written in
+    place), and serve every projection through the ``sparse_linear``
+    kernel with no dense [out, in] weight rebuilt."""
     import re
     from bench import harness, registry, weights
+    # dispatch as on the chip: the kernels compile, the sparse linear
+    # takes its kernel branch (this host's backend is the CPU)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from repro.models import transformer as tfm
     from repro.serving import families
     from repro.serving.paged import PagedPoolView
@@ -183,5 +229,12 @@ def test_mistral_7b_step_fits_one_chip(one_chip, S):
     assert used + HEADROOM <= V5E_HBM, used
     whole = re.compile(r"= \S+\[(\d+,)?%s\]\S* (copy|dynamic-slice|"
                        r"dynamic-update-slice)\(" % ",".join(map(str, layer)))
-    assert not [ln for ln in compiled.as_text().splitlines()
-                if whole.search(ln)]
+    text = compiled.as_text().splitlines()
+    assert not [ln for ln in text if whole.search(ln)]
+    assert [ln for ln in text if re.search(
+        r"%sparse_linear\S* = .*custom-call\(.*tpu_custom_call", ln)]
+    d, f, q, kv = (m["d_model"], m["d_ff"], m["n_heads"] * m["head_dim"],
+                   m["n_kv_heads"] * m["head_dim"])
+    dense = re.compile(r"= \w+\[(%s)\]" % "|".join(
+        f"{o},{i}" for o, i in {(f, d), (d, f), (q, d), (d, q), (kv, d)}))
+    assert not [ln for ln in text if dense.search(ln)]
